@@ -1,7 +1,7 @@
 """Big-regime MFU decomposition (round-4 verdict Next #6): why does
 1.59B sit at ~0.726 and S=8192 at ~0.719 while the 542M flagship
 reaches 0.774-0.778? Per config, by substitution (the flagship's
-methodology, BASELINE.md "Flagship step decomposition"):
+step-decomposition methodology):
 
 - adamw            — the recorded row (bf16 moments; masterless for
                      1.59B where fp32 masters don't fit),
@@ -36,6 +36,7 @@ import json
 import os
 import sys
 
+import jax
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
@@ -44,10 +45,10 @@ import _timing  # noqa: E402  (shared K-differencing timer)
 import paddle_tpu as paddle
 import paddle_tpu.nn.functional as F
 import paddle_tpu.optimizer as popt
+from paddle_tpu.device.peaks import chip_peaks
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.tensor import manipulation as M
-
-PEAK = 197e12  # v5e bf16
+from paddle_tpu.utils.compile_cache import enable_compile_cache
 
 
 VARIANTS = ("adamw", "interleave", "fused_adamw", "fp8", "sgd", "meanloss")
@@ -112,9 +113,16 @@ def probe(name, config, batch, seq, steps, multi_precision,
             _timing.diff_time_ms(compiled, ids, labels, steps), 2)
         del opt, compiled, model_v
 
+    if jax.devices()[0].platform != "tpu":
+        # a CPU pass proves every variant compiles and steps; it has no
+        # device time or utilisation to report
+        print(json.dumps({"config": name, "platform": "cpu",
+                          "variants_ran": sorted(rows)}), flush=True)
+        return None, None
     fpt = model.flops_per_token(seq)
     tok = batch * seq
-    mfu = {k: round(tok * fpt / (v / 1e3) / PEAK, 4)
+    peak = chip_peaks().bf16_flops  # unknown device_kind raises
+    mfu = {k: round(tok * fpt / (v / 1e3) / peak, 4)
            for k, v in rows.items()}
     c = config
     attn_frac = 12 * c.num_hidden_layers * c.hidden_size * seq / fpt
@@ -152,6 +160,7 @@ if __name__ == "__main__":
                     help="tiny config, 2 differencing steps — CPU-safe "
                          "compile+step coverage of every variant")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         tiny = LlamaConfig.tiny()
         probe("smoke-tiny", tiny, 2, 32, 3, multi_precision=False)
@@ -161,6 +170,5 @@ if __name__ == "__main__":
     if only in (None, "long"):
         probe("long-S8192", LONG, 1, 8192, steps, multi_precision=True)
     if only in (None, "big"):
-        # fp32 masters don't fit at 1.59B — masterless + SR (the
-        # recorded BASELINE.md configuration)
+        # fp32 masters don't fit at 1.59B — masterless + SR
         probe("big-1.59B", BIG, 1, 2048, steps, multi_precision=False)

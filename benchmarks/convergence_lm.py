@@ -170,8 +170,12 @@ def run(hidden=256, layers=4, heads=4, batch=32, seq=128,
 if __name__ == "__main__":
     import os
 
-    # the BASELINE.md row's config (reached 1.027x floor on v5e,
-    # 2026-07-31; lr 1e-2 DIVERGES at this width — sits at unigram).
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
+    # the recorded convergence config (lr 1e-2 DIVERGES at this width
+    # — sits at unigram).
     # CONV_BF16_SR=1 reruns it in masterless-bf16 stochastic-rounding
     # mode (same lr/steps — the point is trajectory parity).
     run(hidden=256, layers=4, heads=4, batch=64, seq=128,

@@ -110,4 +110,7 @@ def run(num_classes=10, size=32, train_n=8000, eval_n=1000, batch=128,
 
 
 if __name__ == "__main__":
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

@@ -1,4 +1,4 @@
-"""int8 serving row (BASELINE.md): decode throughput + quality delta of
+"""int8 serving row: decode throughput + quality delta of
 convert(execute_dtype="int8") vs bf16 on the 542M-class model, same
 session (ref: the reference's llm.int8 deploy path,
 paddle/phi/kernels/impl/llm_int8_matmul_kernel_impl.h).
@@ -31,7 +31,9 @@ from paddle_tpu.base.tape import no_grad
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.generation import _get_compiled, generate
 from paddle_tpu.quantization import QAT, QuantConfig, quanter
+from paddle_tpu.utils.compile_cache import enable_compile_cache
 
+enable_compile_cache()
 ap = argparse.ArgumentParser()
 ap.add_argument("--kv", choices=["none", "int8"], default="int8",
                 help="append the int8 KV-cache column (paged pools)")

@@ -1,6 +1,6 @@
 """Flagship step with SGD instead of AdamW: the delta vs the AdamW
-step isolates the optimizer's HBM-roofline cost (BASELINE.md "step
-decomposition"). Run on the real chip with PYTHONPATH set."""
+step isolates the optimizer's HBM-roofline cost (step decomposition
+by substitution). Run on the real chip with PYTHONPATH set."""
 import time
 import numpy as np
 import paddle_tpu as paddle
@@ -8,7 +8,9 @@ import paddle_tpu.nn.functional as F
 import paddle_tpu.optimizer as popt
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.tensor import manipulation as M
+from paddle_tpu.utils.compile_cache import enable_compile_cache
 
+enable_compile_cache()
 config = LlamaConfig(vocab_size=32000, hidden_size=2048, intermediate_size=5632,
                      num_hidden_layers=8, num_attention_heads=16, num_key_value_heads=16,
                      max_position_embeddings=2048)

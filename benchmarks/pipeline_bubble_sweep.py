@@ -1,5 +1,5 @@
 """Measured pipeline bubble fraction: V-sweep and microbatch sweep
-(the BASELINE.md "Pipeline bubble" table). Run:
+(the "Pipeline bubble" table in pipeline_parallel.py's docstring). Run:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     PYTHONPATH="/root/repo:$PYTHONPATH" python benchmarks/pipeline_bubble_sweep.py

@@ -1,4 +1,4 @@
-"""Serving memory-capacity row (BASELINE.md): B concurrent sequences
+"""Serving memory-capacity row: B concurrent sequences
 with a 2048-token position budget but only 640 live tokens each
 (P=512 prompt + 128 generated). The dense cache must pre-allocate
 B x 2048 x kvh x d x 2 x layers; the paged pool allocates blocks for
@@ -21,7 +21,9 @@ import paddle_tpu as paddle
 from paddle_tpu import to_tensor
 from paddle_tpu.base.tape import no_grad
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.utils.compile_cache import enable_compile_cache
 
+enable_compile_cache()
 config = LlamaConfig(vocab_size=32000, hidden_size=2048, intermediate_size=5632,
                      num_hidden_layers=8, num_attention_heads=16,
                      num_key_value_heads=16, max_position_embeddings=2048)

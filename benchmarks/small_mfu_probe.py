@@ -1,4 +1,4 @@
-"""Small-shape MFU decomposition (BASELINE.md round-3 weak #2): why do
+"""Small-shape MFU decomposition: why do
 21M (h=512) and 168M (h=1024) sit at 0.485 / 0.548 MFU while 542M
 reaches 0.774? Measures, per config and batch size:
 
@@ -23,9 +23,9 @@ import paddle_tpu as paddle
 import paddle_tpu.nn.functional as F
 import paddle_tpu.optimizer as popt
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.device.peaks import chip_peaks
 from paddle_tpu.tensor import manipulation as M
-
-PEAK = 197e12  # v5e bf16
+from paddle_tpu.utils.compile_cache import enable_compile_cache
 
 
 def probe(name, config, batch, seq, steps=96,
@@ -85,7 +85,7 @@ def probe(name, config, batch, seq, steps=96,
 
     fpt = model.flops_per_token(seq)
     tok = batch * seq
-    mfu = tok * fpt / (rows["adamw"] / 1e3) / PEAK
+    mfu = tok * fpt / (rows["adamw"] / 1e3) / chip_peaks().bf16_flops
     head_frac = 6 * config.hidden_size * config.vocab_size / fpt
     extra = "".join(
         f" | {k} {v:.2f} ms" for k, v in rows.items() if k != "adamw")
@@ -108,6 +108,8 @@ tiny256 = LlamaConfig(vocab_size=256, hidden_size=512,
                       max_position_embeddings=2048)
 
 if __name__ == "__main__":
+    chip_peaks()  # no chip (or an unknown one) = error before any run
+    enable_compile_cache()
     probe("21M-v32k", tiny, 8, 512)
     probe("21M-v32k", tiny, 32, 512)
     probe("168M", small, 8, 1024)

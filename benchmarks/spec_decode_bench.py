@@ -1,4 +1,4 @@
-"""Speculative-decoding serving row (BASELINE.md): acceptance rate x
+"""Speculative-decoding serving row: acceptance rate x
 decode tokens/s at draft depth k in {2, 4, 8} vs the k=None baseline,
 same engine, same session.
 
@@ -91,6 +91,10 @@ def spec_row(model, on_tpu, spec_k, prompts, big, small, max_len):
 def main():
     argparse.ArgumentParser().parse_args()
     import jax
+
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     dev = jax.devices()[0]
     on_tpu = dev.platform == "tpu"

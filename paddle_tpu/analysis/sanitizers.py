@@ -42,14 +42,17 @@ was taken; :func:`instrument_resources` wraps the ``BlockManager``
 reference primitives so a whole process runs under it
 (``PADDLE_LEAK_SANITIZER=1`` in the 2-process serving proofs).
 
-Implementation: jax logs one "Compiling <name> with global shapes and
-types [...]" record per XLA compilation (module ``jax._src.
-interpreters.pxla``, DEBUG level unless jax_log_compiles is set). The
-guard attaches a logging handler, parses those records into
-:class:`CompileEvent`s, and checks the count on exit. No private jax
-API is touched; if the logging shape ever changes the guard counts 0
-and pinned tests fail visibly rather than silently passing a
-regression (they assert an EXACT nonzero count on the warm-up run).
+Implementation: jax logs one "Compiling jit(<name>) with global shapes
+and types (...)" record per lowering it hands to XLA (logger
+``jax._src.interpreters.pxla``, DEBUG level unless jax_log_compiles is
+set; a persistent-cache hit still logs it, so "0 compilations" means no
+retrace, not merely no backend work). The guard attaches a logging
+handler, parses those records into :class:`CompileEvent`s with the
+``jit(...)`` wrapper stripped, and checks the count on exit. No private
+jax API is touched. If the logging shape changes again the guard counts
+0 — which is why every ``max_compiles=0`` pin is paired with a warm-up
+run asserting an EXACT non-zero count: the pair fails loudly instead of
+passing vacuously.
 """
 from __future__ import annotations
 
@@ -63,6 +66,7 @@ from typing import List, Optional
 __all__ = ["CompileEvent", "RecompileError", "RecompileGuard",
            "recompile_guard", "CollectiveScheduleMismatch",
            "collective_contract", "COMPILE_LOGGERS", "COMPILING_RE",
+           "program_name",
            "LockOrderViolation", "TracedLock", "instrument_locks",
            "uninstrument_locks", "ResourceLeakError", "ResourceLedger",
            "instrument_resources", "uninstrument_resources"]
@@ -110,21 +114,21 @@ def collective_contract(store, rank, world_size, *, last_n=32,
     return _fr.contract(store, rank, world_size, last_n=last_n,
                         deadline=deadline, recorder_=recorder, tag=tag)
 
-# one logger per jax version family; 0.4.x emits from pxla, newer from
-# _src.compiler — listening on both costs nothing. Public: the obs
-# compile-event hook (paddle_tpu/obs/compile.py) listens on the SAME
-# seam, so the guard and the timeline can never disagree about what
-# counts as a compilation.
-COMPILE_LOGGERS = (
-    "jax._src.interpreters.pxla",
-    "jax._src.compiler",
-)
+# Public: the obs compile-event hook (paddle_tpu/obs/compile.py) listens
+# on the SAME seam, so the guard and the timeline can never disagree
+# about what counts as a compilation.
+COMPILE_LOGGERS = ("jax._src.interpreters.pxla",)
 COMPILING_RE = re.compile(
-    r"Compiling (\S+)"
-    r"(?: with global shapes and types (.+?)(?:\. Argument mapping.*)?)?$")
-# back-compat aliases (pre-obs private names)
-_COMPILE_LOGGERS = COMPILE_LOGGERS
-_COMPILING_RE = COMPILING_RE
+    r"Compiling (\S+) with global shapes and types (.+?)"
+    r"(?:\. Argument mapping.*)?$")
+_WRAPPED_NAME_RE = re.compile(r"^\w+\((.+)\)$")
+
+
+def program_name(module_name: str) -> str:
+    """The bare function name from jax's module name: ``jit(prefill)``
+    -> ``prefill`` (a name that is not wrapped comes back unchanged)."""
+    m = _WRAPPED_NAME_RE.match(module_name)
+    return m.group(1) if m else module_name
 
 
 class RecompileError(AssertionError):
@@ -133,8 +137,8 @@ class RecompileError(AssertionError):
 
 @dataclass(frozen=True)
 class CompileEvent:
-    name: str      # the jitted function's name as XLA sees it
-    shapes: str    # "[ShapedArray(int32[2,8]), ...]" — the arg shapes
+    name: str      # the jitted function's bare name (no ``jit(...)``)
+    shapes: str    # "(ShapedArray(int32[2,8]), ...)" — the arg shapes
     message: str   # full log record, for diagnostics
 
     def __str__(self):
@@ -151,10 +155,10 @@ class RecompileGuard:
         self._lock = threading.Lock()
 
     def _record(self, message: str):
-        m = _COMPILING_RE.search(message)
+        m = COMPILING_RE.search(message)
         if not m:
             return
-        ev = CompileEvent(m.group(1), m.group(2) or "", message)
+        ev = CompileEvent(program_name(m.group(1)), m.group(2), message)
         with self._lock:
             self._events.append(ev)
 
@@ -208,11 +212,11 @@ def recompile_guard(max_compiles: Optional[int] = None,
     """
     guard = RecompileGuard(match)
     handler = _GuardHandler(guard)
-    loggers = [logging.getLogger(n) for n in _COMPILE_LOGGERS]
+    loggers = [logging.getLogger(n) for n in COMPILE_LOGGERS]
     saved = [(lg, lg.level, lg.propagate) for lg in loggers]
     for lg in loggers:
         # the compile records are DEBUG unless jax_log_compiles is on;
-        # lower only the two compile loggers, never the root — and stop
+        # lower only the compile logger, never the root — and stop
         # propagation so the temporarily-DEBUG records don't spray
         # through the application's root handler while the guard runs
         if lg.getEffectiveLevel() > logging.DEBUG:

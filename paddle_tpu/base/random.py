@@ -110,18 +110,14 @@ _KEY_TAG = "__paddle_tpu_prng_key__"
 def _encode_key(key):
     if isinstance(key, dict) and key.get(_KEY_TAG) == 1:
         return key  # already encoded (encoding is idempotent)
-    key_data = getattr(jax.random, "key_data", None)
-    raw = key_data(key) if key_data is not None else key
-    return {_KEY_TAG: 1, "data": np.asarray(jax.device_get(raw))}
+    return {_KEY_TAG: 1,
+            "data": np.asarray(jax.device_get(jax.random.key_data(key)))}
 
 
 def _decode_key(enc):
     if not (isinstance(enc, dict) and enc.get(_KEY_TAG) == 1):
         return enc  # already a live key (in-memory snapshot path)
-    wrap = getattr(jax.random, "wrap_key_data", None)
-    data = jax.numpy.asarray(enc["data"])
-    # old jax without typed keys: the raw uint32 array IS the key
-    return wrap(data) if wrap is not None else data
+    return jax.random.wrap_key_data(jax.numpy.asarray(enc["data"]))
 
 
 def encode_rng_state(state):

@@ -50,10 +50,8 @@ def _constrain(t, mesh, spec):
     from paddle_tpu.base import tape
 
     def f(x):
-        from paddle_tpu.utils.jax_compat import get_abstract_mesh
-
-        am = get_abstract_mesh()
-        use = am if (am is not None and not am.empty) else mesh
+        am = jax.sharding.get_abstract_mesh()
+        use = mesh if am.empty else am
         return jax.lax.with_sharding_constraint(
             x, jax.sharding.NamedSharding(use, spec)
         )

@@ -46,8 +46,9 @@ with compute, so ZB is a strictly worse trade on this runtime. (The
 reference needs ZB because its MPMD ranks idle on NCCL waits that
 nothing else can fill.)
 
-MEASURED (BASELINE.md "Pipeline bubble" table, 8-dev mesh, S=4): the
-empirical bubble tracks the schedule model and is ≤5% at M·V ≥ 32
+MEASURED (benchmarks/pipeline_bubble_sweep.py, 8 virtual CPU devices,
+S=4): the empirical bubble tracks the schedule model and is ≤5% at
+M·V ≥ 32
 (e.g. V=1 M=32: 0.6%; V=2 M=16: ≤1%) — an order of magnitude below
 the ~33% recompute tax ZB-H1 would charge, at every realistic
 microbatch count.
@@ -342,6 +343,19 @@ class PipelineLayer(nn.Layer):
         stage_fn = self._stage_fn_pure
         from jax.sharding import PartitionSpec as P
 
+        manual = frozenset(
+            {"pp"}
+            | ({dp_axis} if dp_axis else set())
+            | ({sep_axis} if sep_axis else set())
+        )
+        # partial-manual (auto axes present) requires VMA tracking:
+        # jax's check_vma=False path builds an internal all-axes spec
+        # that partial mode rejects
+        check_vma = any(
+            size > 1 and name not in manual
+            for name, size in dict(mesh.shape).items()
+        )
+
         def pipeline(xs, *stacked):
             def spmd(local_xs, *local_stacked):
                 # P('pp') over the [S*V] dim leaves this device's V chunk
@@ -350,12 +364,12 @@ class PipelineLayer(nn.Layer):
                 stage = lax.axis_index("pp")
                 # VMA: microbatches and the carried state/outputs vary over
                 # pp (each stage computes different values); mark them so
-                # the scan carry typechecks under check_vma
-                # (version-bridged in utils.jax_compat; identity on
-                # pre-VMA jax)
-                from paddle_tpu.utils.jax_compat import pvary
-
-                local_xs = pvary(local_xs, ("pp",))
+                # the scan carry typechecks under check_vma. Without VMA
+                # tracking there is no type to satisfy, and the cast's
+                # transpose (a psum over pp) is rejected on untyped
+                # cotangents.
+                if check_vma:
+                    local_xs = lax.pcast(local_xs, ("pp",), to="varying")
                 state = jnp.zeros_like(local_xs[0])
                 outputs = jnp.zeros_like(local_xs)
                 SV = S * V
@@ -411,23 +425,9 @@ class PipelineLayer(nn.Layer):
             else:
                 x_spec = P(None, dp_axis) if dp_axis else P()
             in_specs = (x_spec,) + tuple(P("pp") for _ in stacked)
-            manual = frozenset(
-                {"pp"}
-                | ({dp_axis} if dp_axis else set())
-                | ({sep_axis} if sep_axis else set())
-            )
-            # partial-manual (auto axes present) requires VMA tracking:
-            # jax's check_vma=False path builds an internal all-axes spec
-            # that partial mode rejects
-            partial = any(
-                size > 1 and name not in manual
-                for name, size in dict(mesh.shape).items()
-            )
-            from paddle_tpu.utils.jax_compat import shard_map as _shard_map
-
-            return _shard_map(
+            return jax.shard_map(
                 spmd, mesh=mesh, in_specs=in_specs, out_specs=x_spec,
-                axis_names=manual, check_vma=partial,
+                axis_names=manual, check_vma=check_vma,
             )(xs, *stacked)
 
         out_stream = tape.apply(

@@ -1,5 +1,13 @@
 """Launcher implementation (ref: launch/main.py:21,
-launch/controllers/collective.py:22 CollectiveController)."""
+launch/controllers/collective.py:22 CollectiveController).
+
+One process for each chip's host: on TPU hardware a single process
+drives every local chip, so ``--nproc`` is 1 there. ``--nproc > 1`` on
+one host is the CPU TEST topology — every rank is pinned to
+``JAX_PLATFORMS=cpu`` (said in a log line), and asking for
+``JAX_PLATFORMS=tpu`` with ``--nproc > 1`` is refused: the launcher maps
+no chips to ranks, so the ranks would fight over the same chip and fail
+or hang."""
 from __future__ import annotations
 
 import argparse
@@ -92,13 +100,17 @@ def _build_env(args, local_rank: int) -> dict:
         env["TPU_VISIBLE_DEVICES"] = args.devices
     if args.nproc > 1:
         # multi-process on one host = CPU testing topology
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"
     return env
 
 
 def launch(argv: Optional[List[str]] = None) -> int:
     """Run the job; returns the first non-zero exit code (0 = success)."""
     args = _parse_args(argv if argv is not None else sys.argv[1:])
+    if args.nproc > 1:
+        from ..spawn import require_cpu_topology
+
+        require_cpu_topology(args.nproc, "launch")
 
     containers: List[Container] = []
     for lr in range(args.nproc):

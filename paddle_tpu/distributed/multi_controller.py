@@ -76,10 +76,7 @@ def initialize_from_env(force: bool = False) -> bool:
     global _initialized_here
     if _initialized_here and not force:
         return True
-    # older jax has no jax.distributed.is_initialized; treat it as "not
-    # initialized" (single-process runs proceed, multi-process runs on
-    # such versions initialize explicitly below)
-    if getattr(jax.distributed, "is_initialized", lambda: False)():
+    if jax.distributed.is_initialized():
         # the worker brought the service up itself (the previously
         # documented contract) — honor it rather than double-initialize
         _initialized_here = True
@@ -181,9 +178,7 @@ def _compiled(kind: str, shape, dtype, extra):
 
     # check_vma=False: all_gather/ppermute outputs ARE replicated but
     # the static varying-manual-axes check cannot infer it
-    from ..utils.jax_compat import shard_map as _shard_map
-
-    fn = _shard_map(body, mesh=mesh, in_specs=spec,
+    fn = jax.shard_map(body, mesh=mesh, in_specs=spec,
                     out_specs=PartitionSpec(), check_vma=False)
     return jax.jit(fn)
 

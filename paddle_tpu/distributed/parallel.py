@@ -124,9 +124,7 @@ def shard_map(fn, mesh=None, in_specs=None, out_specs=None, check_vma=False):
             is_leaf=lambda x: isinstance(x, Tensor),
         )
 
-    from ..utils.jax_compat import shard_map as _shard_map
-
-    smapped = _shard_map(
+    smapped = jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=in_specs if in_specs is not None else P(mesh.axis_names[0]),
         out_specs=out_specs if out_specs is not None else P(mesh.axis_names[0]),

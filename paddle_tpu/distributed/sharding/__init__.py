@@ -61,20 +61,45 @@ def _sharding_mesh_axis(group=None):
     return mesh, "sharding"
 
 
-def _shard_spec(shape, mesh: Mesh, axis: str) -> PartitionSpec:
-    """Shard the first dim divisible by the axis size; replicate 0-d or
-    indivisible tensors (the reference pads flat buffers instead —
-    ref group_sharded_utils.py; with per-tensor layout, skipping the
-    indivisible ones costs only those tensors' replication). Tensors
-    big enough that replication forfeits a real memory win get a
-    warning instead of silently replicating."""
+def _spec_on(arr, mesh: Mesh) -> list:
+    """Per-dim spec entries ``arr`` already carries on ``mesh`` (e.g.
+    the ``mp`` placement ``fleet.distributed_model`` made); all-None
+    for a tracer, a host array or an array on another mesh."""
+    sharding = getattr(arr, "sharding", None)
+    if isinstance(sharding, NamedSharding) and sharding.mesh == mesh:
+        spec = list(sharding.spec)
+        return spec + [None] * (arr.ndim - len(spec))
+    return [None] * arr.ndim
+
+
+def _shard_spec(shape, mesh: Mesh, axis: str, base=None) -> PartitionSpec:
+    """``base`` (the layout the tensor already has, default replicated)
+    plus ``axis`` on the first dim it divides: a dim no other mesh axis
+    holds if there is one, else stacked onto a held dim — so sharding
+    COMPOSES with tensor parallelism instead of replacing it. 0-d or
+    indivisible tensors keep ``base`` (the reference pads flat buffers
+    instead — ref group_sharded_utils.py; with per-tensor layout,
+    skipping the indivisible ones costs only those tensors'
+    replication). Tensors big enough that replication forfeits a real
+    memory win get a warning instead of silently replicating."""
     import warnings
 
-    size = dict(mesh.shape)[axis]
-    spec = [None] * len(shape)
-    for i, d in enumerate(shape):
-        if d % size == 0 and d >= size:
-            spec[i] = axis
+    sizes = dict(mesh.shape)
+    size = sizes[axis]
+    spec = list(base) if base is not None else [None] * len(shape)
+
+    def names(entry):
+        return () if entry is None else (
+            entry if isinstance(entry, tuple) else (entry,))
+
+    if any(axis in names(e) for e in spec):
+        return PartitionSpec(*spec)
+    held = [int(np.prod([sizes[n] for n in names(e)])) for e in spec]
+    order = [i for i, h in enumerate(held) if h == 1] \
+        + [i for i, h in enumerate(held) if h > 1]
+    for i in order:
+        if shape[i] % (held[i] * size) == 0 and shape[i] >= held[i] * size:
+            spec[i] = names(spec[i]) + (axis,) if held[i] > 1 else axis
             break
     else:
         numel = 1
@@ -92,10 +117,14 @@ def _shard_spec(shape, mesh: Mesh, axis: str) -> PartitionSpec:
     return PartitionSpec(*spec)
 
 
-def _place(arr, mesh: Mesh, axis: str):
+def _place(arr, mesh: Mesh, axis: str, spec=None):
+    """Put ``arr`` on ``mesh`` with ``spec`` (default: what it already
+    has there plus ``axis``)."""
     from ...utils.jax_compat import global_device_put
 
-    sharding = NamedSharding(mesh, _shard_spec(arr.shape, mesh, axis))
+    if spec is None:
+        spec = _shard_spec(arr.shape, mesh, axis, _spec_on(arr, mesh))
+    sharding = NamedSharding(mesh, spec)
     if isinstance(arr, jax.core.Tracer):
         return jax.lax.with_sharding_constraint(arr, sharding)
     return global_device_put(arr, sharding)
@@ -134,23 +163,40 @@ def group_sharded_parallel(
         )
     mesh, axis = _sharding_mesh_axis(group)
 
+    # each parameter's target layout: whatever it already has on the
+    # mesh (the tensor-parallel placement of fleet.distributed_model)
+    # PLUS the sharding axis. Its optimizer state and gradient follow
+    # the same layout — inside the traced step the parameter is a
+    # tracer with no layout to read, so the specs are fixed here.
+    specs = {
+        p.name: _shard_spec(p._data.shape, mesh, axis,
+                            _spec_on(p._data, mesh))
+        for p in model.parameters()
+    }
+
+    def like_param(arr, param):
+        if param is not None and tuple(arr.shape) == tuple(param.shape):
+            return _place(arr, mesh, axis, specs.get(param.name))
+        return _place(arr, mesh, axis)
+
     # stage 1: shard optimizer state (all levels include it)
     optimizer._accum_placement_fn = (
-        lambda arr, param=None, name=None: _place(arr, mesh, axis)
+        lambda arr, param=None, name=None: like_param(arr, param)
     )
+    by_name = {p.name: p for p in model.parameters()}
     for store in optimizer._accumulators.values():
         for key in store:
-            store[key] = _place(store[key], mesh, axis)
+            store[key] = like_param(store[key], by_name.get(key))
 
     # stage 2: constrain grads to the sharded layout inside the step
     if level in ("os_g", "p_g_os"):
-        optimizer._grad_placement_fn = lambda g: _place(g, mesh, axis)
+        optimizer._grad_placement_fn = like_param
 
     # stage 3: shard the parameters themselves (FSDP)
     if level == "p_g_os":
         for p in model.parameters():
             if not isinstance(p._data, jax.core.Tracer):
-                p._data = _place(p._data, mesh, axis)
+                p._data = _place(p._data, mesh, axis, specs[p.name])
 
     model._group_sharded_level = level
     model._group_sharded_mesh = (mesh, axis)

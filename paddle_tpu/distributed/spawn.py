@@ -3,12 +3,15 @@
 Single-node multi-process launcher: forks ``nprocs`` Python processes
 each running ``func(*args)`` with the rank env set. On TPU hardware one
 process drives all chips, so nprocs defaults to 1; nprocs>1 is the
-CPU-mesh testing topology (each child gets JAX_PLATFORMS=cpu).
+CPU TEST topology: each child gets JAX_PLATFORMS=cpu (said in a log
+line), and JAX_PLATFORMS=tpu with nprocs>1 is refused — no chips are
+mapped to ranks, so the children would fight over the same chip.
 """
 from __future__ import annotations
 
 import multiprocessing as mp
 import os
+import sys
 from typing import Optional, Sequence
 
 __all__ = ["spawn"]
@@ -22,12 +25,29 @@ def _worker(func, args, rank: int, nprocs: int, env: dict):
     func(*args)
 
 
+def require_cpu_topology(nprocs: int, who: str) -> None:
+    """Several processes on one host: refuse the TPU, and say that the
+    ranks are pinned to the CPU (shared with ``distributed.launch``)."""
+    want = os.environ.get("JAX_PLATFORMS", "")
+    if "tpu" in want.lower().split(","):
+        raise RuntimeError(
+            f"{who}: {nprocs} processes on one host with "
+            f"JAX_PLATFORMS={want!r} refused — that is the CPU test "
+            "topology; no chips are mapped to ranks, so they would all "
+            "open the same chip. Use one process on a TPU host (it "
+            "drives every local chip).")
+    print(f"[{who}] {nprocs} processes on one host is the CPU test "
+          "topology: every rank runs with JAX_PLATFORMS=cpu",
+          file=sys.stderr)
+
+
 def spawn(func, args=(), nprocs: int = 1, join: bool = True,
           daemon: bool = False, **options):
     """ref: spawn.py spawn — returns the context (list of processes)
     when join=False, else joins and raises on child failure."""
     env = {}
     if nprocs > 1:
+        require_cpu_topology(nprocs, "spawn")
         env["JAX_PLATFORMS"] = "cpu"
     ctx = mp.get_context("spawn")
     procs = []

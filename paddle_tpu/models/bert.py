@@ -1,4 +1,4 @@
-"""BERT family — bidirectional encoder with MLM head (BASELINE.md
+"""BERT family — bidirectional encoder with MLM head (BASELINE.json
 config #2: BERT-base MLM fine-tune under DataParallel).
 
 ref: transformer encoder layers (python/paddle/nn/layer/

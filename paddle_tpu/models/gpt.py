@@ -1,4 +1,4 @@
-"""GPT family — decoder-only LM with learned positions (BASELINE.md
+"""GPT family — decoder-only LM with learned positions (BASELINE.json
 config #4: GPT-3-13B hybrid TP+PP+DP).
 
 ref: the reference trains GPT via PaddleNLP's gpt modeling (downstream
@@ -170,8 +170,16 @@ class GPTModel(nn.Layer):
         if caches is None:
             pos = apply(lambda: jnp.arange(s, dtype=jnp.int32)[None, :], op_name="arange")
         else:
+            # cur_len: a scalar (generate()) or per-sequence [B] starts
+            # (the serving engine's ragged batches) -> [1|B, s]. The
+            # clamp only touches the engine's PADDED lanes (a chunk tail
+            # past max_len, an idle slot): an out-of-range take() would
+            # fill them with NaN rather than garbage.
+            last = self.config.max_position_embeddings - 1
             pos = apply(
-                lambda cl: (cl + jnp.arange(s, dtype=jnp.int32))[None, :],
+                lambda cl: jnp.minimum(
+                    jnp.reshape(cl, (-1, 1)).astype(jnp.int32)
+                    + jnp.arange(s, dtype=jnp.int32)[None, :], last),
                 cur_len, op_name="arange_offset",
             )
         x = self.wte(input_ids) + self.wpe(pos)

@@ -1,4 +1,4 @@
-"""Stable-Diffusion-style conditional UNet (BASELINE.md config #5).
+"""Stable-Diffusion-style conditional UNet (BASELINE.json config #5).
 
 ref: the reference runs SD through PPDiffusers' UNet2DConditionModel
 (downstream of this repo); the in-repo surface it exercises is conv2d,

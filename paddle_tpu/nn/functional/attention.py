@@ -57,14 +57,49 @@ def _naive_attention(q, k, v, mask, dropout_p, causal, scale, key):
 def _use_pallas(q_shape, dtype, mask, dropout_p) -> bool:
     if mask is not None or dropout_p > 0:
         return False
-    try:
-        d = jax.devices()[0]
-        if d.platform not in ("tpu",):
-            return False
-    except Exception:
+    if jax.devices()[0].platform != "tpu":
         return False
     head_dim = q_shape[-1]
     return head_dim in (64, 128, 256) and q_shape[1] % 128 == 0
+
+
+def _flash_over_mesh(mesh, q, k, v, causal):
+    """The flash kernel under a multi-device mesh. GSPMD cannot
+    partition a Mosaic kernel (jax refuses to lower one inside a
+    multi-device jit: "wrap the call in a shard_map"), so the kernel is
+    mapped by hand: heads over ``mp`` — where tensor parallelism's
+    column-parallel qkv already leaves them — and batch over the data
+    axes, each only when it divides; what does not divide is replicated
+    and every chip then runs that part whole. The sequence stays whole
+    per shard (sequence parallelism is ``sep_parallel_attention``)."""
+    from jax.sharding import PartitionSpec as P
+
+    from ...ops.flash_attention import flash_attention_fwd
+
+    sizes = dict(mesh.shape)
+    data_axes = tuple(a for a in ("dp", "sharding") if sizes.get(a, 1) > 1)
+    n_data = int(np.prod([sizes[a] for a in data_axes])) if data_axes else 1
+    batch = data_axes if data_axes and q.shape[0] % n_data == 0 else None
+    mp = sizes.get("mp", 1)
+    heads = "mp" if mp > 1 and q.shape[2] % mp == 0 \
+        and k.shape[2] % mp == 0 else None
+    spec = P(batch, None, heads, None)
+    return jax.shard_map(
+        lambda qq, kk, vv: flash_attention_fwd(qq, kk, vv, causal=causal),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
+
+
+def _fleet_mesh():
+    """The hybrid mesh ``fleet.init`` built when it spans several
+    devices, else None (one device: the kernel is called directly)."""
+    from ...distributed.fleet.base.topology import (
+        get_hybrid_communicate_group,
+    )
+
+    hcg = get_hybrid_communicate_group()
+    return hcg.mesh if hcg is not None and hcg.mesh.size > 1 else None
 
 
 def scaled_dot_product_attention(
@@ -86,15 +121,18 @@ def scaled_dot_product_attention(
     rng_key = _random.next_key() if dropout_p > 0 else None
 
     if _use_pallas(tuple(query.shape), query.dtype, attn_mask, dropout_p):
-        try:
-            from ...ops.flash_attention import flash_attention_fwd
+        # no fallback: a kernel the compiler refuses must surface, not
+        # silently become the S x S jnp path
+        from ...ops.flash_attention import flash_attention_fwd
 
-            def _pallas(qq, kk, vv):
-                return flash_attention_fwd(qq, kk, vv, causal=is_causal)
+        mesh = _fleet_mesh()
 
-            return apply(_pallas, query, key, value, op_name="flash_attention")
-        except Exception:
-            pass  # fall through to the jnp path
+        def _pallas(qq, kk, vv):
+            if mesh is not None:
+                return _flash_over_mesh(mesh, qq, kk, vv, is_causal)
+            return flash_attention_fwd(qq, kk, vv, causal=is_causal)
+
+        return apply(_pallas, query, key, value, op_name="flash_attention")
 
     def _f(qq, kk, vv, *maybe_mask):
         m = maybe_mask[0] if maybe_mask else None

@@ -2,11 +2,11 @@
 timeline and in the registry.
 
 Reuses the exact jax compile-log seam ``recompile_guard`` listens on
-(``analysis/sanitizers.py``: the ``Compiling <name> ...`` records from
-``jax._src.interpreters.pxla`` / ``jax._src.compiler``) plus the
-``Finished XLA compilation of <name> in <t> sec`` record
-``jax._src.dispatch`` emits, so compile COUNT and WALL TIME are both
-captured, tagged by program name, with no private jax API touched.
+(``analysis/sanitizers.py``: the ``Compiling jit(<name>) ...`` records
+from ``jax._src.interpreters.pxla``) plus the ``Finished XLA
+compilation of jit(<name>) in <t> sec`` record ``jax._src.dispatch``
+emits, so compile COUNT and WALL TIME are both captured, tagged by the
+bare program name, with no private jax API touched.
 If the logging shape ever changes, counts drop to zero and the pinned
 obs tests fail visibly — the same failure contract the guard makes.
 
@@ -22,7 +22,11 @@ import logging
 import re
 from typing import List, Optional, Tuple
 
-from ..analysis.sanitizers import COMPILE_LOGGERS, COMPILING_RE
+from ..analysis.sanitizers import (
+    COMPILE_LOGGERS,
+    COMPILING_RE,
+    program_name,
+)
 from .metrics import registry
 from .trace import instant
 
@@ -33,7 +37,7 @@ __all__ = [
 ]
 
 # the wall-time record comes from the dispatch logger (see
-# jax._src.dispatch.log_elapsed_time), not the two compile loggers
+# jax._src.dispatch.log_elapsed_time), not the compile logger
 FINISHED_LOGGER = "jax._src.dispatch"
 FINISHED_RE = re.compile(
     r"Finished XLA compilation of (\S+) in ([0-9.eE+-]+) sec")
@@ -58,7 +62,7 @@ class _CompileHandler(logging.Handler):
         try:
             m = COMPILING_RE.search(msg)
             if m:
-                name = m.group(1)
+                name = program_name(m.group(1))
                 registry().counter(
                     "jax_compiles_total", {"program": name},
                     help="XLA compilations by program name").inc()
@@ -66,7 +70,7 @@ class _CompileHandler(logging.Handler):
                 return
             m = FINISHED_RE.search(msg)
             if m:
-                name, secs = m.group(1), float(m.group(2))
+                name, secs = program_name(m.group(1)), float(m.group(2))
                 registry().histogram(
                     "jax_compile_seconds", {"program": name},
                     help="XLA compile wall time by program"
@@ -87,7 +91,7 @@ def compile_events_installed() -> bool:
 
 def install_compile_events() -> None:
     """Attach the compile-event handler (idempotent). Lowers only the
-    three jax compile/dispatch loggers to DEBUG and stops their
+    jax compile and dispatch loggers to DEBUG and stops their
     propagation (the guard's exact discipline) so the temporarily-
     DEBUG records don't spray through the application's root
     handler."""

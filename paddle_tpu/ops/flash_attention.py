@@ -33,15 +33,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() exact zero
                  # without inf-inf = nan hazards in the masked rows
 
-# newer jax exposes the dimension-semantics enum; older releases hang
-# PARALLEL/ARBITRARY directly off the pltpu module — same attribute
-# names either way, so the module doubles as the enum
-_SEM = getattr(pltpu, "GridDimensionSemantics", pltpu)
-
-# same jax-version bridge for the compiler-params dataclass (renamed
-# TPUCompilerParams -> CompilerParams across jax releases)
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+_SEM = pltpu.GridDimensionSemantics
 
 
 def _block(size: int) -> int:
@@ -154,7 +146,7 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, interpret: bool):
             pltpu.VMEM((bq, 128), jnp.float32),  # running denom
             pltpu.VMEM((bq, d), jnp.float32),    # output accumulator
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 _SEM.PARALLEL, _SEM.PARALLEL, _SEM.PARALLEL, _SEM.ARBITRARY,
             ),
@@ -285,7 +277,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale: float, causal: bool, interpret: boo
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda b, hh, iq, ik: (b, hh, iq, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 _SEM.PARALLEL, _SEM.PARALLEL, _SEM.PARALLEL, _SEM.ARBITRARY,
             ),
@@ -316,7 +308,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale: float, causal: bool, interpret: boo
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 _SEM.PARALLEL, _SEM.PARALLEL, _SEM.PARALLEL, _SEM.ARBITRARY,
             ),

@@ -2,8 +2,7 @@
 
 ref: paddle/phi/kernels/gpu/adamw_kernel.cu (the reference's fused
 multi-tensor CUDA path, adamw.py:493 ``_C_ops.adamw_``). TPU-native
-redesign: the AdamW tail runs at the HBM roofline (BASELINE.md flagship
-decomposition: ~13 ms, ~0.05 MFU), and XLA cannot fuse the update
+redesign: the AdamW tail is HBM-bound, and XLA cannot fuse the update
 chain across the backward scan boundary — each of m/v/p lands in its
 own fusion with its own round-trip over the optimizer state. This
 kernel streams param+grad+m+v tiles through VMEM exactly once per
@@ -11,15 +10,20 @@ step: bias-corrected update, decoupled weight decay, and the
 stochastic-rounding bf16 writeback all computed in-register, so the
 per-element HBM traffic is one read of p/g/m/v and one write of p/m/v.
 
-Numerics contract (tested bitwise on the interpret path): with
-stochastic rounding off the kernel reproduces the reference
-``AdamW._update_param`` bit-for-bit — the in-kernel expressions keep
-the reference's op order and f32 compute dtype (``_moments`` /
-``_adam_delta``), and the scalar prologue (``lr_t``, the effective
-epsilon, the decay factor) is computed OUTSIDE the kernel with the
-exact reference expressions. With SR on, the writeback uses the same
+Numerics contract (tests/test_fused_adamw.py): with stochastic
+rounding off the kernel computes the reference
+``AdamW._update_param`` expressions — the reference's op order and f32
+compute dtype (``_moments`` / ``_adam_delta``), with the scalar prologue
+(``lr_t``, the effective epsilon, the decay factor) computed OUTSIDE the
+kernel by the exact reference expressions — and every output agrees
+with the jitted reference to within one f32 rounding of an intermediate
+term (``update_error_bounds``). Not bit-equal: whether
+``b1*m + (1-b1)*g`` is contracted into a fused multiply-add is each
+compiler's choice (XLA:CPU, the interpreter, Mosaic), one rounding of
+the larger product apart — which, where the two products cancel, is
+many ulps of the small result. With SR on, the writeback uses the same
 lowbias32 hash over (flat element index, two threefry salts) as
-``_stochastic_round_bf16`` — same salts, same bits.
+``_stochastic_round_bf16`` — same salts, same draws.
 
 Layout: arrays are flattened C-order, zero-padded to a (rows, 128)
 lane grid, and tiled over ``bt`` sublanes per program (multiple of 16:
@@ -43,12 +47,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax-version bridges (same as flash_attention.py): newer jax exposes
-# the dimension-semantics enum / renames TPUCompilerParams
-_SEM = getattr(pltpu, "GridDimensionSemantics", pltpu)
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
 
 _LANES = 128
 
@@ -104,6 +102,109 @@ def unfused_adamw_hbm_bytes(size: int, p_dtype, g_dtype, m_dtype) -> int:
 
 
 # ---------------------------------------------------------------------------
+# numerics contract
+# ---------------------------------------------------------------------------
+
+
+def reference_update(p, g, m, v, *, lr, beta1, beta2, epsilon,
+                     beta1_pow, beta2_pow, weight_decay=0.0,
+                     sr_salts=None):
+    """Plain ``jax.numpy`` reference the kernel is compared against (CPU
+    tests, chip smoke): ``AdamW._update_param``'s expressions verbatim
+    (``_moments`` / ``_adam_delta`` / the decoupled decay), beta powers
+    already advanced, and with ``sr_salts`` the writeback of
+    ``optimizer._stochastic_round_bf16`` under those pinned salts.
+    Returns ``(p', m', v')`` in the storage dtypes of the inputs."""
+    g32 = g.astype(jnp.float32)
+    m_new = beta1 * m.astype(jnp.float32) + (1 - beta1) * g32
+    v_new = beta2 * v.astype(jnp.float32) + (1 - beta2) * g32 * g32
+    lr_t = lr * jnp.sqrt(1 - beta2_pow) / (1 - beta1_pow)
+    delta = lr_t * m_new / (
+        jnp.sqrt(v_new) + epsilon * jnp.sqrt(1 - beta2_pow))
+    new = p.astype(jnp.float32) * (1.0 - lr * weight_decay) - delta
+    if sr_salts is None:
+        p_new = new.astype(p.dtype)
+    else:
+        from ..optimizer.optimizer import _stochastic_round_bf16
+
+        p_new = _stochastic_round_bf16(new, sr_salts)
+    return p_new, m_new.astype(m.dtype), v_new.astype(v.dtype)
+
+
+def update_error_bounds(p, g, m, v, *, lr, beta1, beta2, epsilon,
+                        beta1_pow, beta2_pow, weight_decay=0.0,
+                        roundings: int = 1):
+    """Elementwise ``|kernel - reference|`` bounds ``(dp, dm, dv)`` in
+    f32, as float64 numpy arrays: what ``roundings`` extra f32
+    roundings per intermediate term can put into each output.
+
+    One rounding is the difference between ``a*b + c`` evaluated as a
+    fused multiply-add and as a rounded product then a sum — a relative
+    2**-23 of the LARGER term, not of the (possibly cancelled) result.
+    The parameter bound adds the moments' own error carried through
+    ``lr_t * m / (sqrt(v) + eps)``. Storage narrower than f32 rounds
+    once more on top of this; callers compare such outputs after
+    allowing one ulp of the storage dtype."""
+    import numpy as np
+
+    def f64(a):
+        return np.asarray(jnp.asarray(a).astype(jnp.float32), np.float64)
+
+    p, g, m, v = f64(p), f64(g), f64(m), f64(v)
+    b1p, b2p = float(beta1_pow), float(beta2_pow)
+    eps32 = roundings * 2.0 ** -23
+    m_new = beta1 * m + (1 - beta1) * g
+    v_new = beta2 * v + (1 - beta2) * g * g
+    dm = eps32 * (np.abs(beta1 * m) + np.abs((1 - beta1) * g))
+    dv = 2 * eps32 * (np.abs(beta2 * v) + (1 - beta2) * g * g)
+    lr_t = lr * np.sqrt(1 - b2p) / (1 - b1p)
+    denom = np.sqrt(v_new) + epsilon * np.sqrt(1 - b2p)
+    delta = lr_t * m_new / denom
+    d_denom = dv / (2 * np.sqrt(np.maximum(v_new, 1e-300))) + eps32 * denom
+    d_delta = lr_t * dm / denom + np.abs(delta) * (
+        d_denom / denom + 2 * eps32)
+    dp = eps32 * (np.abs(p * (1.0 - lr * weight_decay))
+                  + np.abs(delta)) + d_delta
+    return dp, dm, dv
+
+
+def assert_matches_reference(got, ref, inputs, *, roundings: int = 1,
+                             **hyper) -> float:
+    """The contract as a check: each of ``got = (p', m', v')`` against
+    ``ref`` (``reference_update`` on the same ``inputs = (p, g, m, v)``
+    and ``hyper``). Every output must sit within
+    ``update_error_bounds``; one stored narrower than f32 (bf16
+    moments, a bf16 or stochastically rounded parameter) gets one ulp
+    of its storage dtype on top, for where the f32 value straddles a
+    rounding boundary — and that may happen on under 1% of the elements
+    (independent SR draws would disagree on about half). Returns the
+    largest error found, as a fraction of its bound."""
+    import numpy as np
+
+    worst = 0.0
+    bounds = update_error_bounds(*inputs, roundings=roundings, **hyper)
+    for a, b, bound, name in zip(got, ref, bounds, "pmv"):
+        if a.dtype != b.dtype:
+            raise AssertionError(f"{name}: dtype {a.dtype} != {b.dtype}")
+        a64 = np.asarray(a.astype(jnp.float32), np.float64)
+        b64 = np.asarray(b.astype(jnp.float32), np.float64)
+        if a.dtype != jnp.float32:
+            if (a64 == b64).mean() <= 0.99:
+                raise AssertionError(
+                    f"{name}: only {(a64 == b64).mean():.4f} of the "
+                    f"{a.dtype} elements equal the reference")
+            exponent = np.floor(np.log2(np.maximum(np.abs(b64), 1e-300)))
+            bound = bound + 2.0 ** (exponent - jnp.finfo(a.dtype).nmant)
+        ratio = float(np.max(np.abs(a64 - b64) / bound))
+        if ratio > 1:
+            raise AssertionError(
+                f"{name}: error is {ratio:.2f}x the {roundings}-rounding "
+                "bound")
+        worst = max(worst, ratio)
+    return worst
+
+
+# ---------------------------------------------------------------------------
 # kernel
 # ---------------------------------------------------------------------------
 
@@ -125,7 +226,7 @@ def _adamw_kernel(scal_ref, salt_ref, p_ref, g_ref, m_ref, v_ref,
     m32 = m_old.astype(jnp.float32)
     v32 = v_old.astype(jnp.float32)
 
-    # _AdamBase._moments op order, bit-for-bit
+    # _AdamBase._moments op order
     m_new = beta1 * m32 + (1 - beta1) * g32
     v_new = beta2 * v32 + (1 - beta2) * g32 * g32
     # AdamW._update_param + _adam_delta: decay factor and lr_t/eps_eff
@@ -243,8 +344,8 @@ def fused_adamw_update(
             jax.ShapeDtypeStruct((rows_padded, _LANES), m.dtype),
             jax.ShapeDtypeStruct((rows_padded, _LANES), v.dtype),
         ],
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=(_SEM.PARALLEL,),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(pltpu.GridDimensionSemantics.PARALLEL,),
         ),
         cost_estimate=pl.CostEstimate(
             flops=10 * total,

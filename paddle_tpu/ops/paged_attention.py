@@ -925,9 +925,8 @@ def paged_decode_attention(q, k_pool, v_pool, tables, cache_len,
     shapes (``_ratio_aware_pages_per_block``) widen the page chunk
     inversely with the ratio, so the ratio-4 row above (*fixed-block
     number, 0.10 ms behind reshape-view) is the regime the widened
-    block targets; TPU re-measurement is the round-6 sweep (see
-    BASELINE.md). At ratios >= ~8 the kernel beats everything
-    including the dense cache.
+    block targets; the widened blocks are not measured. At ratios
+    >= ~8 the kernel beats everything including the dense cache.
 
     Policy:
     - contiguous tables: reshape to a dense view (free) unless the GQA
@@ -953,10 +952,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, cache_len,
     cache_len = _validate_cache_len(cache_len, b)
     kvh = k_pool.shape[0]
     ratio = h // max(kvh, 1)
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:  # pragma: no cover
-        platform = "cpu"
+    platform = jax.devices()[0].platform
     bs = k_pool.shape[2]
     # TPU tiling: kernel blocks are (page_size, head_dim) tiles
     if (

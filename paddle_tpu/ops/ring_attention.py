@@ -39,10 +39,16 @@ _NEG = -1e30
 def _manual_axes() -> tuple:
     """Axis names bound manually in the current trace context (empty
     outside any shard_map). Single point of contact with the abstract-
-    mesh introspection API (version-bridged in utils.jax_compat)."""
-    from ..utils.jax_compat import manual_axis_names
+    mesh introspection API."""
+    from jax.sharding import AxisType
 
-    return manual_axis_names()
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
+        return ()
+    return tuple(
+        n for n, t in zip(am.axis_names, am.axis_types)
+        if t == AxisType.Manual
+    )
 
 
 def ring_attention(q, k, v, axis_name: str, causal: bool = False,
@@ -124,11 +130,8 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
         return (k_t, v_t, m, l, acc), None
 
     def _varying(x):
-        # shard_map scans need device-varying carries (identity on
-        # pre-VMA jax — version-bridged in utils.jax_compat)
-        from ..utils.jax_compat import pvary
-
-        return pvary(x, vary_axes or (axis_name,))
+        # shard_map scans need device-varying carries
+        return jax.lax.pcast(x, vary_axes or (axis_name,), to="varying")
 
     m0 = _varying(jnp.full((b, h, s_local), _NEG, jnp.float32))
     l0 = _varying(jnp.zeros((b, h, s_local), jnp.float32))
@@ -171,8 +174,6 @@ def sep_parallel_attention(q, k, v, mesh=None, axis_name: str = "sep",
     """
     from jax.sharding import PartitionSpec as P
 
-    from ..utils.jax_compat import shard_map
-
     from ..base.tape import apply
 
     if _axis_already_manual(axis_name):
@@ -190,7 +191,7 @@ def sep_parallel_attention(q, k, v, mesh=None, axis_name: str = "sep",
     spec = P(None, axis_name, None, None)
 
     def f(qq, kk, vv):
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(ring_attention, axis_name=axis_name, causal=causal,
                     scale=scale),
             mesh=mesh,

@@ -30,7 +30,7 @@ __all__ = [
 ]
 
 
-def _stochastic_round_bf16(x):
+def _stochastic_round_bf16(x, salt=None):
     """Unbiased f32 → bf16 rounding: add 16 random bits below the bf16
     mantissa cut, truncate. Sign-magnitude format makes the trick
     unbiased for both signs (|x| rounds up with probability equal to
@@ -44,14 +44,17 @@ def _stochastic_round_bf16(x):
     per-call threefry salts) — measured ~10x cheaper inside the fused
     optimizer pass than a full per-element threefry draw (which cost
     more than the master traffic it replaced); rounding noise needs
-    per-element uniformity, not cryptographic streams."""
+    per-element uniformity, not cryptographic streams. ``salt`` pins
+    the two salts (a (2,) uint32 array — kernel-parity references);
+    by default they are drawn from the global generator."""
     import jax
 
     from ..base import random as _random
 
     xf = x.astype(jnp.float32)
     u = jax.lax.bitcast_convert_type(xf, jnp.uint32)
-    salt = jax.random.bits(_random.next_key(), (2,), jnp.uint32)
+    if salt is None:
+        salt = jax.random.bits(_random.next_key(), (2,), jnp.uint32)
     i = jax.lax.iota(jnp.uint32, x.size).reshape(x.shape)
     b = i * jnp.uint32(0x9E3779B9) + salt[0]
     b = (b ^ (b >> 16)) * jnp.uint32(0x7FEB352D)
@@ -115,7 +118,7 @@ class Optimizer:
         # and auto_parallel.shard_optimizer: the accum hook
         # fn(array, param, accum_name) places new optimizer state
         # (including master weights); the grad hook constrains gradient
-        # layout (stage-2 reduce-scatter)
+        # layout, fn(grad_array, param) (stage-2 reduce-scatter)
         self._accum_placement_fn = None
         self._grad_placement_fn = None
         # write low-precision params back with unbiased stochastic
@@ -269,7 +272,7 @@ class Optimizer:
         self._interleave_applied.add(id(p))
         garr = g._data if isinstance(g, Tensor) else g
         if self._grad_placement_fn is not None:
-            garr = self._grad_placement_fn(garr)
+            garr = self._grad_placement_fn(garr, p)
         scaler = self._interleave_scaler
         if scaler is not None and scaler.is_enable():
             # scaler-driven fused path: unscale THIS layer's grad the
@@ -301,7 +304,7 @@ class Optimizer:
             ]
             if self._grad_placement_fn is not None:
                 params_grads = [
-                    (p, Tensor(self._grad_placement_fn(g._data), _internal=True))
+                    (p, Tensor(self._grad_placement_fn(g._data, p), _internal=True))
                     for p, g in params_grads
                 ]
             # reference order (ref: optimizer.py:1519-1525): grad clip FIRST,
@@ -537,9 +540,10 @@ class AdamW(_AdamBase):
         self._apply_decay_param_fun = apply_decay_param_fun
         # fused=True routes each param update through the single-pass
         # Pallas kernel (ops.fused_adamw): one streamed read of
-        # p/g/m/v, one write of p/m/v, SR writeback in-register —
-        # bitwise-identical numerics to this class's unfused math
-        # (tested), so it is a drop-in backend, not a new optimizer
+        # p/g/m/v, one write of p/m/v, SR writeback in-register — the
+        # same f32 math as this class's unfused path, one rounding
+        # apart at most (tested), so it is a drop-in backend, not a
+        # new optimizer
         self._fused = bool(fused)
         if interleave_updates:
             self._enable_interleaving()
@@ -555,9 +559,12 @@ class AdamW(_AdamBase):
     def _fused_supported(self, p, g) -> bool:
         # the kernel computes in f32: f64 params keep the reference
         # path (reference compute promotes to f64 there); non-float
-        # grads (complex) likewise
+        # grads (complex) likewise. issubdtype, not numpy's kind: numpy
+        # files bfloat16 under kind "V", which sent every bf16 gradient
+        # — the configuration the kernel was written for — to the
+        # reference path without a word (found on the chip, PR 21).
         return (np.dtype(p._data.dtype) != np.dtype(np.float64)
-                and np.dtype(g.dtype).kind == "f"
+                and jnp.issubdtype(g.dtype, jnp.floating)
                 and not callable(self._coeff))
 
     def _update_param(self, p, g, lr_scale, group):

@@ -25,9 +25,6 @@ Instrumented sites (grep for ``chaos.inject``):
   (inference/cluster.py); a ``drop`` here deterministically MISROUTES
   the request to the next live replica — the correctness-under-
   misroute envelope the router tests pin down
-- ``bench.attempt``      — the bench child, before any JAX import
-- ``bench.probe``        — the bench preflight device-enumeration
-  child, before any JAX import (indexed by probe attempt)
 - ``comm.reorder``       — each collective flight-recorder append
   (``distributed/communication/flight_recorder.py``); a ``drop``
   here DEFERS that collective's signature until the next

@@ -210,7 +210,7 @@ class ExtensionModule:
             # Concrete inputs (eager, incl. the primal pass inside the
             # tape's jax.vjp): fetch to host and call directly — no
             # callback machinery, and it works on PJRT backends without
-            # host-callback support (e.g. tunneled devices). Tracers
+            # host-callback support. Tracers
             # (inside jit/to_static): jax.pure_callback, which XLA wires
             # as a host call on backends that support it.
             if any(isinstance(a, jax.core.Tracer) for a in arrs):

@@ -1,55 +1,7 @@
-"""Version-bridging shims for jax API drift.
-
-The build targets current jax but must come up on older releases too
-(the container baking the toolchain may lag): each symbol here prefers
-the modern location and falls back to where the same object lived
-before. Keep every shim to a getattr-probe + import fallback — no
-behavioral patches.
-"""
+"""Multi-controller-safe placement helper."""
 from __future__ import annotations
 
 import jax
-
-
-def shard_map(f=None, /, **kwargs):
-    """``jax.shard_map`` (graduated in newer jax) with the
-    ``jax.experimental.shard_map`` fallback for older releases. Same
-    calling conventions (direct or partial application); the modern
-    kwargs are translated for the old signature:
-
-    - ``check_vma``   -> ``check_rep`` (rename)
-    - ``axis_names``  -> ``auto`` (the COMPLEMENT: modern code names
-      the manual axes, the old API names the axes left automatic)
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:  # pre-graduation jax
-        from jax.experimental.shard_map import shard_map as fn
-
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        if "axis_names" in kwargs:
-            manual = set(kwargs.pop("axis_names"))
-            kwargs["auto"] = frozenset(
-                kwargs["mesh"].axis_names) - manual
-    if f is None:
-        import functools
-
-        return functools.partial(fn, **kwargs)
-    return fn(f, **kwargs)
-
-
-def pvary(x, axes):
-    """Mark a value device-varying over ``axes`` for shard_map scan
-    carries: ``lax.pcast(..., to="varying")`` on current jax,
-    ``lax.pvary`` on the release that introduced it, and IDENTITY on
-    pre-VMA jax — there is no varying-manual-axes type system to
-    satisfy, so no cast is needed."""
-    lax = jax.lax
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axes)
-    return x
 
 
 def global_device_put(value, sharding):
@@ -74,26 +26,3 @@ def global_device_put(value, sharding):
         return jax.make_array_from_process_local_data(
             sharding, host, host.shape)
     return jax.device_put(value, sharding)
-
-
-def get_abstract_mesh():
-    """``jax.sharding.get_abstract_mesh()`` or None where the
-    abstract-mesh introspection API does not exist yet."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    return fn() if fn is not None else None
-
-
-def manual_axis_names() -> tuple:
-    """Axis names bound manually in the current trace context; empty
-    outside any shard_map OR on jax without mesh introspection (there,
-    callers inside a manual region must pass axes explicitly — the same
-    contract those releases always had)."""
-    am = get_abstract_mesh()
-    if am is None or getattr(am, "empty", True):
-        return ()
-    from jax.sharding import AxisType
-
-    return tuple(
-        n for n, t in zip(am.axis_names, am.axis_types)
-        if t == AxisType.Manual
-    )

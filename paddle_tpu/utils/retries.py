@@ -1,14 +1,13 @@
 """Deadline budgets + retry policies — the shared fault-tolerance layer.
 
-Every blocking surface in the framework (bench supervisor, TCP KV
-store, comm watchdog, elastic manager, serving engine) used to carry
-its own hardcoded timeout; a single hung operation could then outlive
-the caller's window (BENCH_r05: one 1800s attempt timeout ate the whole
-driver capture). This module replaces those ad-hoc constants with one
-audited discipline:
+Every blocking surface in the framework (TCP KV store, comm watchdog,
+elastic manager, serving engine) used to carry its own hardcoded
+timeout; a single hung operation could then outlive the caller's
+window. This module replaces those ad-hoc constants with one audited
+discipline:
 
 - :class:`Deadline` — an ABSOLUTE wall-clock budget. Built-in consumers
-  (bench supervisor, store, watchdog, elastic, serving) each receive a
+  (store, watchdog, elastic, serving) each receive a
   whole Deadline and bound every blocking step against it; CALLERS
   dividing one job budget across phases carve slices with ``sub()``
   (which inherits the parent's clock and can never outlive it), e.g.
@@ -18,11 +17,9 @@ audited discipline:
   a Deadline: retrying never extends past the budget.
 - :func:`classify_text` — the shared infrastructure-error taxonomy
   (backend bring-up failures, connection loss, gRPC UNAVAILABLE) used
-  by the bench supervisor and anything else that classifies stderr.
+  by anything that classifies stderr.
 
-Intentionally stdlib-only: ``bench.py``'s supervisor loads this file by
-path before any framework/JAX import so a broken backend can never take
-the retry layer down with it.
+Intentionally stdlib-only.
 """
 from __future__ import annotations
 
@@ -165,7 +162,7 @@ class Deadline:
 
 
 # ---------------------------------------------------------------------------
-# Transient-vs-fatal classification (shared with bench.py's supervisor).
+# Transient-vs-fatal classification.
 # lowercase substrings marking a failure as transient infrastructure
 # (worth retrying) rather than a real bug in the caller or framework.
 TRANSIENT_PATTERNS: Tuple[str, ...] = (

@@ -17,8 +17,9 @@ env:
   DISAGG_MODEL_JSON   — LlamaConfig kwargs as JSON (the bench passes
                         ITS config so the disagg row measures the same
                         model as the unified baseline; default: tiny)
-  DISAGG_BF16         — non-empty: model.bfloat16() (match the bench)
-  JAX_PLATFORMS       — honored when set (TPU column); default cpu
+  JAX_PLATFORMS       — honored when set; default cpu (a worker is a
+                        CPU process: one process for each chip, and the
+                        parent that spawns it may hold the chip)
   DISAGG_CONTRACT_RANK/_WORLD — flight-recorder contract topology
                         (default: role rank in a 1+1 pair; REQUIRED
                         when running >1 worker per role)
@@ -50,8 +51,7 @@ env:
 import json
 import os
 
-# pin CPU only when the driver didn't choose a platform — the bench's
-# TPU column spawns workers with JAX_PLATFORMS=tpu and must get it
+# pin CPU only when the driver didn't choose a platform
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import paddle_tpu as paddle  # noqa: E402
@@ -95,8 +95,6 @@ def main():
         if max_len > cfg.max_position_embeddings:
             cfg = LlamaConfig.tiny(max_position_embeddings=max_len)
     model = LlamaForCausalLM(cfg)
-    if os.environ.get("DISAGG_BF16"):
-        model.bfloat16()
     blocks = int(os.environ.get("DISAGG_BLOCKS", "16"))
     chunk = os.environ.get("DISAGG_CHUNK")
 

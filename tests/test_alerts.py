@@ -350,6 +350,23 @@ def _ledger(tmp_path, name, values, metric="bench_tokens_per_sec",
     return str(path)
 
 
+def _round_files(tmp_path):
+    """Driver round files (``{n, cmd, rc, tail, parsed}``): two rounds
+    with a parsed metric line, then a failed and a timed-out one."""
+    rounds = [(0, {"metric": "synthetic_tokens_per_sec", "value": 1000.0,
+                   "unit": "tokens/s"}),
+              (0, {"metric": "synthetic_tokens_per_sec", "value": 1010.0,
+                   "unit": "tokens/s"}),
+              (1, None), (124, None)]
+    paths = []
+    for n, (rc, parsed) in enumerate(rounds, 1):
+        path = tmp_path / f"round_{n:02d}.json"
+        path.write_text(json.dumps({"n": n, "cmd": "python bench.py",
+                                    "rc": rc, "tail": "", "parsed": parsed}))
+        paths.append(str(path))
+    return paths
+
+
 class TestRegress:
     def test_true_regression_flagged(self, tmp_path):
         rnd = random.Random(7)
@@ -426,14 +443,11 @@ class TestRegress:
         assert [r["value"] for r in loaded] == [1.5, 2.5]
         assert all(r["schema"] == rg.BENCH_SCHEMA for r in loaded)
 
-    def test_loader_accepts_driver_round_files(self):
-        # the repo's real BENCH_r0*.json round files load (parsed
-        # payloads become records; null parsed rounds are skipped)
-        paths = sorted(
-            os.path.join(REPO, f) for f in os.listdir(REPO)
-            if f.startswith("BENCH_r0") and f.endswith(".json"))
-        assert paths, "seed BENCH round files missing"
-        records = rg.load_ledger(paths)
+    def test_loader_accepts_driver_round_files(self, tmp_path):
+        # driver round files load: parsed payloads become records,
+        # null-parsed (failed / timed-out) rounds are skipped
+        records = rg.load_ledger(_round_files(tmp_path))
+        assert [r["value"] for r in records] == [1000.0, 1010.0]
         assert all("metric" in r and "bench" in r for r in records)
 
 
@@ -458,11 +472,8 @@ class TestCLI:
         r = _cli(["regress", "--ledger", path])
         assert r.returncode == 0, r.stdout + r.stderr
 
-    def test_regress_quiet_on_real_bench_history(self):
-        paths = sorted(
-            os.path.join(REPO, f) for f in os.listdir(REPO)
-            if f.startswith("BENCH_r0") and f.endswith(".json"))
-        r = _cli(["regress", "--ledger", *paths])
+    def test_regress_quiet_on_round_file_history(self, tmp_path):
+        r = _cli(["regress", "--ledger", *_round_files(tmp_path)])
         assert r.returncode == 0, r.stdout + r.stderr
 
     def test_alerts_rc0_on_healthy_fleet_rc1_on_silent(self, tmp_path):

@@ -950,9 +950,9 @@ class TestRecompileGuard:
         import logging
 
         from paddle_tpu.analysis import recompile_guard
-        from paddle_tpu.analysis.sanitizers import _COMPILE_LOGGERS
+        from paddle_tpu.analysis.sanitizers import COMPILE_LOGGERS
 
-        loggers = [logging.getLogger(n) for n in _COMPILE_LOGGERS]
+        loggers = [logging.getLogger(n) for n in COMPILE_LOGGERS]
         before = [(lg.level, lg.propagate, list(lg.handlers))
                   for lg in loggers]
         with pytest.raises(RuntimeError, match="boom"):

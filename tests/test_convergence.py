@@ -1,7 +1,7 @@
 """CI-short convergence checks on held-out data (ref: SURVEY §4
 convergence-style tests; the full runs with curves live in
 benchmarks/convergence_lm.py and benchmarks/convergence_resnet.py and
-their measured results in BASELINE.md).
+their measured results).
 
 These are REAL learning checks, not overfit-one-batch: eval streams
 are disjoint from training, and the LM target is relative to the

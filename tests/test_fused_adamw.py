@@ -1,14 +1,21 @@
 """Fused AdamW Pallas kernel (ISSUE 17 lever (a)).
 
-Numerics contract: with stochastic rounding OFF the kernel reproduces
-the reference ``AdamW._update_param`` math BIT-FOR-BIT against the
-JITTED reference expressions (both production paths run under jit —
-to_static compiles the train step, and interpret-mode pallas jits
-internally — and XLA CPU contracts ``b1*m + (1-b1)*g`` into an FMA
-under jit but not in eager dispatch, so the jitted reference is the
-honest comparison; the eager deviation is <= 1 ulp). With SR on, the
-writeback matches the reference lowbias32 hash element-for-element
-given the same salts.
+Numerics contract (what is TRUE, not "bitwise"): with stochastic
+rounding OFF the kernel computes the reference ``AdamW._update_param``
+expressions, in the reference's op order, in f32 — and every output
+agrees with the jitted reference to within ONE f32 rounding of an
+intermediate term (``update_error_bounds``), plus one ulp of the
+storage dtype where storage is narrower than f32. Bit-equality does not
+hold and was never going to: whether ``b1*m + (1-b1)*g`` becomes one
+fused multiply-add or a rounded multiply then an add is each compiler's
+choice (XLA:CPU contracts it in one of the two programs and not the
+other on this jax; Mosaic on the chip makes its own choice again). The
+two differ by one rounding of the larger product — 1 ulp of the result
+where the products add, and many ulps of it where they cancel, so a
+fixed "n ulps of the result" is not a true statement either. What IS
+exact: the found-inf skip returns its inputs bit-for-bit, and with SR
+on the rounding draws are the reference lowbias32 hash
+element-for-element given the same salts.
 
 The HBM model: the kernel streams p/g/m/v through VMEM exactly once
 (read p+g+m+v, write p+m+v) vs the reference's op-boundary schedule —
@@ -28,41 +35,15 @@ import paddle_tpu.nn as nn
 import paddle_tpu.optimizer as popt
 from paddle_tpu.ops.fused_adamw import (
     fused_adamw_hbm_bytes,
+    assert_matches_reference,
     fused_adamw_update,
+    reference_update,
     unfused_adamw_hbm_bytes,
 )
 
 pytestmark = [pytest.mark.kernels, pytest.mark.quick]
 
 LR, B1, B2, EPS = 1e-2, 0.9, 0.999, 1e-8
-
-
-def _ref_update(p, g, m, v, *, lr, wd, b1p, b2p, m_store):
-    """The reference AdamW._update_param expressions, verbatim
-    (beta pows already advanced — matching the kernel's contract)."""
-    g32 = g.astype(jnp.float32)
-    m32 = m.astype(jnp.float32)
-    v32 = v.astype(jnp.float32)
-    m_new = B1 * m32 + (1 - B1) * g32
-    v_new = B2 * v32 + (1 - B2) * g32 * g32
-    lr_t = lr * jnp.sqrt(1 - b2p) / (1 - b1p)
-    delta = lr_t * m_new / (jnp.sqrt(v_new) + EPS * jnp.sqrt(1 - b2p))
-    new = p.astype(jnp.float32) * (1.0 - lr * wd) - delta
-    return new.astype(p.dtype), m_new.astype(m_store), v_new.astype(m_store)
-
-
-def _ref_sr(x32, salts):
-    """_stochastic_round_bf16's hash with pinned salts (C-order iota)."""
-    u = jax.lax.bitcast_convert_type(x32, jnp.uint32)
-    i = jax.lax.iota(jnp.uint32, x32.size).reshape(x32.shape)
-    b = i * jnp.uint32(0x9E3779B9) + salts[0]
-    b = (b ^ (b >> 16)) * jnp.uint32(0x7FEB352D)
-    b = (b ^ (b >> 15)) * jnp.uint32(0x846CA68B)
-    b = (b ^ (b >> 16)) + salts[1]
-    r = jax.lax.bitcast_convert_type(
-        (u + (b & jnp.uint32(0xFFFF))) & jnp.uint32(0xFFFF0000),
-        jnp.float32)
-    return jnp.where(jnp.isfinite(x32), r, x32).astype(jnp.bfloat16)
 
 
 def _inputs(shape, p_dtype, m_dtype, seed=0):
@@ -82,39 +63,32 @@ class TestKernelParity:
         (jnp.bfloat16, jnp.float32),
     ], ids=["f32", "f32-m_bf16", "bf16", "bf16-m_f32"])
     @pytest.mark.parametrize("wd", [0.0, 0.01], ids=["wd0", "wd.01"])
-    def test_bitwise_vs_jitted_reference(self, p_dtype, m_dtype, wd):
+    def test_one_rounding_vs_jitted_reference(self, p_dtype, m_dtype, wd):
         # (37, 19): 703 elements — exercises the lane-grid zero padding
         p, g, m, v = _inputs((37, 19), p_dtype, m_dtype)
         # beta pows are f32 accumulators in production: round FIRST
         # (python-f64 scalars here would change 1-b1p by half an ulp)
         b1p = jnp.asarray(B1 ** 3, jnp.float32)  # step 3
         b2p = jnp.asarray(B2 ** 3, jnp.float32)
-        got = fused_adamw_update(
-            p, g, m, v, lr=LR, beta1=B1, beta2=B2, epsilon=EPS,
-            beta1_pow=b1p, beta2_pow=b2p, weight_decay=wd)
-        ref = jax.jit(functools.partial(
-            _ref_update, lr=LR, wd=wd, b1p=b1p, b2p=b2p,
-            m_store=m_dtype))(p, g, m, v)
-        for a, b, name in zip(got, ref, "pmv"):
-            assert a.dtype == b.dtype, name
-            np.testing.assert_array_equal(
-                np.asarray(a).view(np.uint8), np.asarray(b).view(np.uint8),
-                err_msg=f"{name} not bitwise-identical")
+        hyper = dict(lr=LR, beta1=B1, beta2=B2, epsilon=EPS,
+                     beta1_pow=b1p, beta2_pow=b2p, weight_decay=wd)
+        got = fused_adamw_update(p, g, m, v, **hyper)
+        ref = jax.jit(functools.partial(reference_update, **hyper))(
+            p, g, m, v)
+        assert_matches_reference(got, ref, (p, g, m, v), **hyper)
 
-    def test_multi_tile_grid_bitwise(self):
+    def test_multi_tile_grid_one_rounding(self):
         # 39000 elements -> 305 rows -> bt=256, grid=(2,): the tile
         # index offset must keep the flat-index bookkeeping exact
         p, g, m, v = _inputs((300, 130), jnp.float32, jnp.float32)
         b1p = jnp.asarray(B1, jnp.float32)
         b2p = jnp.asarray(B2, jnp.float32)
-        got = fused_adamw_update(
-            p, g, m, v, lr=LR, beta1=B1, beta2=B2, epsilon=EPS,
-            beta1_pow=b1p, beta2_pow=b2p)
-        ref = jax.jit(functools.partial(
-            _ref_update, lr=LR, wd=0.0, b1p=b1p, b2p=b2p,
-            m_store=jnp.float32))(p, g, m, v)
-        for a, b in zip(got, ref):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        hyper = dict(lr=LR, beta1=B1, beta2=B2, epsilon=EPS,
+                     beta1_pow=b1p, beta2_pow=b2p)
+        got = fused_adamw_update(p, g, m, v, **hyper)
+        ref = jax.jit(functools.partial(reference_update, **hyper))(
+            p, g, m, v)
+        assert_matches_reference(got, ref, (p, g, m, v), **hyper)
 
     def test_sr_writeback_matches_reference_hash(self):
         # multi-tile shape: the global flat index the in-kernel hash
@@ -124,27 +98,20 @@ class TestKernelParity:
         b1p = jnp.asarray(B1, jnp.float32)
         b2p = jnp.asarray(B2, jnp.float32)
         p, g, m, v = _inputs((300, 130), jnp.bfloat16, jnp.bfloat16)
-        got_p, _, _ = fused_adamw_update(
-            p, g, m, v, lr=LR, beta1=B1, beta2=B2, epsilon=EPS,
-            beta1_pow=b1p, beta2_pow=b2p, weight_decay=0.01,
-            sr_salts=salts)
-
-        def ref(p, g, m, v):
-            new, _, _ = _ref_update(p, g, m, v, lr=LR, wd=0.01,
-                                    b1p=B1, b2p=B2, m_store=jnp.float32)
-            # reference rounds the pre-cast f32 value
-            g32 = g.astype(jnp.float32)
-            m_new = B1 * m.astype(jnp.float32) + (1 - B1) * g32
-            v_new = B2 * v.astype(jnp.float32) + (1 - B2) * g32 * g32
-            lr_t = LR * jnp.sqrt(1 - b2p) / (1 - b1p)
-            d = lr_t * m_new / (jnp.sqrt(v_new) + EPS * jnp.sqrt(1 - b2p))
-            x32 = p.astype(jnp.float32) * (1.0 - LR * 0.01) - d
-            return _ref_sr(x32, salts)
-
-        ref_p = jax.jit(ref)(p, g, m, v)
-        np.testing.assert_array_equal(
-            np.asarray(got_p).view(np.uint8),
-            np.asarray(ref_p).view(np.uint8))
+        hyper = dict(lr=LR, beta1=B1, beta2=B2, epsilon=EPS,
+                     beta1_pow=b1p, beta2_pow=b2p, weight_decay=0.01,
+                     sr_salts=salts)
+        got = fused_adamw_update(p, g, m, v, **hyper)
+        ref = jax.jit(functools.partial(reference_update, **hyper))(
+            p, g, m, v)
+        # the DRAWS are equal (same hash, same flat index, same salts):
+        # independent draws would disagree on about half the elements.
+        # The f32 value being rounded may sit one rounding from the
+        # reference's (see module docstring), which can move a rare
+        # element across the truncation boundary — hence "1 bf16 ulp,
+        # nearly all exactly equal" instead of bit-equality.
+        del hyper["sr_salts"]
+        assert_matches_reference(got, ref, (p, g, m, v), **hyper)
 
     def test_sr_deterministic_and_salt_sensitive(self):
         p, g, m, v = _inputs((64, 64), jnp.bfloat16, jnp.bfloat16)
@@ -285,7 +252,18 @@ class TestFusedOptimizerBackend:
         for a, b in zip(pr, pf):
             np.testing.assert_allclose(a, b, atol=5e-6)
 
-    def test_sr_deterministic_under_fixed_seed(self):
+    def test_sr_deterministic_under_fixed_seed(self, monkeypatch):
+        # bf16 gradients must REACH the kernel: fused=True once fell
+        # back to the reference for them without a word, and this
+        # determinism check passed on the fallback
+        from paddle_tpu.ops import fused_adamw as kernel_module
+
+        calls = []
+        real = kernel_module.fused_adamw_update
+        monkeypatch.setattr(
+            kernel_module, "fused_adamw_update",
+            lambda *a, **k: calls.append(a[1].dtype) or real(*a, **k))
+
         def run():
             paddle.seed(11)
             m = nn.Linear(8, 8)
@@ -303,6 +281,7 @@ class TestFusedOptimizerBackend:
             return [np.asarray(p._data) for p in m.parameters()]
 
         a, b = run(), run()
+        assert len(calls) == 2 * 5 * 2 and set(calls) == {jnp.dtype("bfloat16")}
         for x, y in zip(a, b):
             np.testing.assert_array_equal(x.view(np.uint8),
                                           y.view(np.uint8))

@@ -57,6 +57,27 @@ class TestLaunch:
         content = (tmp_path / "logs" / logs[0]).read_text()
         assert "rank 0 of 2" in content
 
+    @pytest.mark.quick
+    def test_several_ranks_on_one_host_is_the_cpu_topology(
+            self, tmp_path, monkeypatch, capsys):
+        """One process for each chip: ranks on one host are pinned to
+        the CPU (and the launcher says so); asking for the TPU with
+        --nproc > 1 is refused, for launch and for spawn."""
+        from paddle_tpu.distributed import spawn
+
+        script = self._script(
+            tmp_path, "import os; print(os.environ['JAX_PLATFORMS'])\n")
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        with pytest.raises(RuntimeError, match="CPU test topology"):
+            launch(["--nproc", "2", "--log_dir", str(tmp_path / "l"), script])
+        with pytest.raises(RuntimeError, match="CPU test topology"):
+            spawn(print, nprocs=2)
+        monkeypatch.delenv("JAX_PLATFORMS")
+        log_dir = tmp_path / "logs"
+        assert launch(["--nproc", "2", "--log_dir", str(log_dir), script]) == 0
+        assert "CPU test topology" in capsys.readouterr().err
+        assert all(p.read_text().strip() == "cpu" for p in log_dir.iterdir())
+
     def test_failure_restarts_then_fails(self, tmp_path):
         script = self._script(tmp_path, "import sys; sys.exit(7)\n")
         rc = launch(

@@ -188,8 +188,7 @@ class TestInt8Serving:
     path (ref: llm_int8_matmul_kernel_impl.h): int8 generate must run,
     stay close to the bf16/f32 logits, and keep argmax in the float
     top-5 (greedy match on a RANDOM-init model is a worst-case metric —
-    near-tie logits flip under tiny perturbations; BASELINE.md records
-    the measured 542M row)."""
+    near-tie logits flip under tiny perturbations)."""
 
     def test_int8_generate_matches_float_logits(self):
         from paddle_tpu.models import LlamaConfig, LlamaForCausalLM

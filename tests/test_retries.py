@@ -175,25 +175,3 @@ class TestClassifyText:
             "known backends") == "fatal"
         assert classify_text("ValueError: shape mismatch") == "fatal"
         assert classify_text("") == "fatal"
-
-    def test_bench_reexports_the_shared_taxonomy(self):
-        """bench.py must consume the shared module, not carry a fork."""
-        import importlib.util
-        import os
-
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        spec = importlib.util.spec_from_file_location(
-            "_bench_mod", os.path.join(repo, "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        from paddle_tpu.utils import retries
-
-        # bench path-loads retries.py (separate module object by design
-        # — the supervisor must not import the framework), so compare by
-        # value: the taxonomy must be THE shared one, not a fork
-        assert bench.TRANSIENT_PATTERNS == retries.TRANSIENT_PATTERNS
-        assert bench.FATAL_OVERRIDES == retries.FATAL_OVERRIDES
-        assert bench._retries.classify_text is not None
-        assert bench._classify("connection reset", 1) == "transient"
-        assert bench._classify("anything", -9) == "transient"  # killed
-        assert bench._classify("boom", 1) == "fatal"

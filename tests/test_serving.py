@@ -52,6 +52,29 @@ class TestContinuousBatching:
             want = _reference_tokens(model, p, budgets[rid])
             assert done[rid].out == want, (rid, done[rid].out, want)
 
+    def test_gpt_through_the_engine_matches_generate(self):
+        """The engine only needs init_cache/forward_with_cache: the
+        learned-position GPT serves through the same chunked-prefill +
+        ragged-table path as Llama (chip_smoke.py runs this pairing at
+        GPT-3-13B widths)."""
+        from paddle_tpu.models import GPTConfig, GPTForCausalLM
+
+        paddle.seed(0)
+        model = GPTForCausalLM(GPTConfig.tiny())
+        rng = np.random.RandomState(0)
+        prompts = {"a": rng.randint(0, 500, (5,)),
+                   "b": rng.randint(0, 500, (19,)),
+                   "c": rng.randint(0, 500, (8,))}
+        eng = ContinuousBatchingEngine(
+            model, max_batch=2, max_len=64, block_size=8, num_blocks=16,
+            prefill_chunk=8)
+        for rid, p in prompts.items():
+            eng.add_request(rid, p, max_new_tokens=5)
+        done = eng.run()
+        for rid, p in prompts.items():
+            want = _reference_tokens(model, p, 5)
+            assert done[rid].out == want, (rid, done[rid].out, want)
+
     def test_eviction_recycles_blocks_without_corruption(self):
         """max_batch=2, pool sized so the 3rd request MUST reuse the 1st
         request's freed blocks while the 2nd is still decoding — the
@@ -523,6 +546,11 @@ class TestRecompilePin:
         # width 8), one decode (batch shape [2]) — NOT one per prompt
         # length and NOT one per engine step
         assert sorted(g.names()) == ["decode", "prefill"], g.names()
+        # the exact NON-ZERO warm-up count is what keeps the
+        # max_compiles=0 pin below honest: when jax's log line changed
+        # shape (bare name -> "jit(name)") an anchored match counted 0
+        # and every zero-budget pin passed vacuously
+        assert g.count() == 2
         for ev in g.events():
             assert ev.shapes  # the (width/shape) identity is recorded
 

@@ -10,8 +10,7 @@ Two contracts pinned here:
    refactoring.
 2. INT8 KV QUALITY + SCALE CARRIAGE — quantized KV stays within an
    explicit last-logit rel-err tolerance of the bf16/f32 cache (the
-   int8-weights-style gate, BASELINE.md r4: weight-only rel err
-   0.031), and COW fork / prefix-cache adoption carry the per-block
+   int8-weights-style gate), and COW fork / prefix-cache adoption carry the per-block
    scales with the physical block (a forked block with stale scales
    decodes garbage — the regression tests would catch it).
 
