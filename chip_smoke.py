@@ -158,6 +158,14 @@ def memory_line(tag: str) -> dict:
     return stats
 
 
+# the Mosaic kernel_name of each of the package's own kernels: the ``name``
+# its pl.pallas_call carries (ops/flash_attention.py, ops/fused_adamw.py;
+# tests/test_pallas_kernel_names.py lowers them for the TPU and holds the
+# two together)
+FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+ADAMW_KERNELS = ("fused_adamw",)
+
+
 def kernels_in(text: str) -> list:
     """Names of the Mosaic kernels a lowered module carries."""
     import re
@@ -295,7 +303,7 @@ def kernel_flash(sq, sk, hq, hkv, d, causal):
     tag = f"flash sq={sq} sk={sk} hq={hq} hkv={hkv} d={d} causal={causal}"
     got = compiled_with_kernels(
         kernel, (q, k, v, do),
-        ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel"), tag)(q, k, v, do)
+        FLASH_KERNELS, tag)(q, k, v, do)
     ref = jax.jit(reference)(q, k, v, do)
     for g, r, name in zip(got, ref, ("out", "dq", "dk", "dv")):
         _close(g, r, f"{tag} {name}")
@@ -390,7 +398,7 @@ def kernel_adamw(p_dtype, m_dtype, sr, shape=(2048, 1280)):
                                   **hyper)
 
     run = compiled_with_kernels(kernel, (p, g, m, v, jnp.asarray(False)),
-                                ("_adamw_kernel",), tag)
+                                ADAMW_KERNELS, tag)
     got = run(p, g, m, v, jnp.asarray(False))
     ref = jax.jit(functools.partial(
         reference_update, sr_salts=salts, **hyper))(p, g, m, v)
@@ -439,7 +447,7 @@ def kernel_adamw_train_step():
     say(f"fused_adamw train step losses={[round(v, 4) for v in losses]}")
     check(all(math.isfinite(v) for v in losses), "fused AdamW: loss not finite")
     check(losses[-1] < losses[0], "fused AdamW: loss did not fall")
-    require_kernels(lambda: program_that_ran("pure"), ("_adamw_kernel",),
+    require_kernels(lambda: program_that_ran("pure"), ADAMW_KERNELS,
                     "fused_adamw train step")
 
 
@@ -583,8 +591,7 @@ def phase_trainer(tiny: bool, size: dict) -> None:
           f"trainer: compiled in the steady window: {guard.names()}")
     check_losses(warm + losses, cfg.vocab_size, "trainer")
     require_kernels(
-        lambda: program_that_ran("pure"),
-        ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel"), "trainer step")
+        lambda: program_that_ran("pure"), FLASH_KERNELS, "trainer step")
     memory_line("after trainer")
 
 

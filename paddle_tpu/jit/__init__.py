@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import tree_util
 
+from .. import obs as _obs
 from ..base import random as _random
 from ..base.tensor import Tensor
 
@@ -53,6 +54,12 @@ class _PiecewiseUnsafe(RuntimeError):
 def enable_to_static(flag: bool = True):
     """ref: paddle.jit.enable_to_static — globally fall back to eager."""
     _jit_enabled[0] = bool(flag)
+
+
+def _leg(name, call, **args):
+    """A span of one leg of a compiled call, child of its ``to_static.call``
+    span (``obs/trace.py``: the ring, and a profiler trace's host plane)."""
+    return _obs.span("to_static." + name, parent=call, tid="to_static", **args)
 
 
 def _is_tensor(x):
@@ -119,6 +126,12 @@ class StaticFunction:
         self._jit_cache: Dict[Any, Any] = {}  # arg_treedef -> jitted pure fn
         self._last_lowered = None
         self._pure_runs = 0  # pure() executions == jax trace count
+        # what the compiled path tells paddle_tpu.obs: the spans of one
+        # call share the id "<qualname>:<call index>"
+        self._qualname = getattr(fn, "__qualname__", None) or repr(fn)
+        self._n_calls = 0
+        self._n_leaves = 0  # arrays threaded as state, as last traced
+        self._call_span = None  # the open to_static.call span, for pure()
         # optimizers whose step() actually ran in the traced step (set
         # during tracing); only these get host-side step corrections
         self._stepped_optimizers: List[Any] = []
@@ -364,47 +377,52 @@ class StaticFunction:
             # second call, once lazily-created accumulators change the
             # state pytree), which double-counted _global_step.
             self._pure_runs += 1
-            steps_before = [o._global_step for o in self._optimizers]
-            self._write_state(state)
-            for o, lr in zip(self._optimizers, lrs):
-                o._lr_override = lr
-            try:
-                wrapped = [
-                    Tensor(a, stop_gradient=True, _internal=True)
-                    if isinstance(a, (jax.Array, np.ndarray)) or hasattr(a, "dtype")
-                    else a
-                    for a in flat_args
-                ]
-                if self._carry_args:
-                    for w in wrapped:
-                        if isinstance(w, Tensor):
-                            w._piecewise_carry = True
-                args, kwargs = tree_util.tree_unflatten(arg_treedef, wrapped)
+            self._n_leaves = len(tree_util.tree_leaves(state))
+            # a trace-time span on purpose: how long the program's own
+            # Python runs under jax's tracer (dy2static, the tape, the
+            # optimizer's per-leaf loop), as against lowering/compiling.
+            # A with-block here and not a wrapper: see __call__ on frames
+            with _leg("trace", self._call_span, fn=self._qualname):
+                steps_before = [o._global_step for o in self._optimizers]
+                self._write_state(state)
+                for o, lr in zip(self._optimizers, lrs):
+                    o._lr_override = lr
                 try:
-                    out = self._fn(*args, **kwargs)
-                except (
-                    jax.errors.ConcretizationTypeError,  # incl. bool conv
-                    jax.errors.TracerArrayConversionError,
-                    jax.errors.TracerIntegerConversionError,
-                ) as e:
-                    from . import dy2static as _d2s
+                    wrapped = [
+                        Tensor(a, stop_gradient=True, _internal=True)
+                        if isinstance(a, (jax.Array, np.ndarray)) or hasattr(a, "dtype")
+                        else a
+                        for a in flat_args
+                    ]
+                    if self._carry_args:
+                        for w in wrapped:
+                            if isinstance(w, Tensor):
+                                w._piecewise_carry = True
+                    args, kwargs = tree_util.tree_unflatten(arg_treedef, wrapped)
+                    try:
+                        out = self._fn(*args, **kwargs)
+                    except (
+                        jax.errors.ConcretizationTypeError,  # incl. bool conv
+                        jax.errors.TracerArrayConversionError,
+                        jax.errors.TracerIntegerConversionError,
+                    ) as e:
+                        from . import dy2static as _d2s
 
-                    raise _d2s.graph_break_error(e) from e
-            finally:
-                for o in self._optimizers:
-                    o._lr_override = None
-            # which optimizers actually stepped during the traced run:
-            # only those get host-side step-count corrections (a merely
-            # READ optimizer, e.g. get_lr() logging, must not advance)
-            self._stepped_optimizers = [
-                o for o, s0 in zip(self._optimizers, steps_before)
-                if o._global_step > s0
-            ]
-            new_state = self._read_state()
-            out_arrays = tree_util.tree_map(
-                lambda t: t._data if isinstance(t, Tensor) else t, out, is_leaf=_is_tensor
-            )
-            return out_arrays, new_state
+                        raise _d2s.graph_break_error(e) from e
+                finally:
+                    for o in self._optimizers:
+                        o._lr_override = None
+                # which optimizers actually stepped during the traced run:
+                # only those get host-side step-count corrections (a merely
+                # READ optimizer, e.g. get_lr() logging, must not advance)
+                self._stepped_optimizers = [
+                    o for o, s0 in zip(self._optimizers, steps_before)
+                    if o._global_step > s0
+                ]
+                new_state = self._read_state()
+                return tree_util.tree_map(
+                    lambda t: t._data if isinstance(t, Tensor) else t, out, is_leaf=_is_tensor
+                ), new_state
 
         return pure
 
@@ -432,105 +450,130 @@ class StaticFunction:
                 self._piecewise = None
                 self._fallback_eager = True
                 return self._orig_fn(*args, **kwargs)
-        if self._needs_discovery:
-            self._auto_discover(self._orig_fn)
-            self._needs_discovery = False
-        else:
-            self._revalidate_captures()
-        if not self._cells:
-            self._collect_cells()
-
-        flat, arg_treedef = tree_util.tree_flatten((args, kwargs), is_leaf=_is_tensor)
-        flat_arrays = [a._data if isinstance(a, Tensor) else a for a in flat]
-
-        state = self._read_state()
-        lrs = [jnp.asarray(o.get_lr(), jnp.float32) for o in self._optimizers]
-
-        jitted = self._jit_cache.get(arg_treedef)
-        if jitted is None:
-            pure = self._make_pure(arg_treedef)
-            jit_kwargs = {}
-            if self._donate_state:
-                jit_kwargs["donate_argnums"] = (0,)
-            jitted = jax.jit(pure, **jit_kwargs)
-            self._jit_cache[arg_treedef] = jitted
+        # the compiled path: one to_static.call span from here to the
+        # return, its legs as children (obs/trace.py; with a profiler
+        # session running they are pt: events beside the device's ops).
+        # All of it stays in THIS frame and what it needs is kept on the
+        # object: while jax traces, this frame lies under every traced
+        # op, and one more frame there (or a larger one) shifts where
+        # the interpreter's frame stack crosses a chunk boundary, which
+        # made set-up's tracing a quarter slower (PERF.md, PR 24)
+        self._n_calls += 1
         runs_before = self._pure_runs
-        steps_before = [o._global_step for o in self._optimizers]
-        try:
-            out_arrays, new_state = jitted(state, lrs, flat_arrays)
-        except dy2static.GraphBreakError as e:
-            if self._full_graph:
-                raise
-            # SOT semantics (ref jit/sot opcode_executor.py:305,1594):
-            # split the function at the breaking statement — prefix and
-            # suffix stay COMPILED (their own StaticFunctions), the
-            # breaking statement runs eagerly each call. Only when no
-            # safe split exists does the whole function demote to
-            # per-op eager. The failed trace wrote tracers into the
-            # threaded state; roll it back first.
-            self._write_state(state)
-            self._sanitize_grads()
-            for o, s0 in zip(self._optimizers, steps_before):
-                o._global_step = s0
-            import warnings
-
-            if self._split_depth < 3:
-                piecewise = self._build_piecewise(e)
-                if piecewise is not None:
-                    snap = self._snapshot_host_state()
-                    try:
-                        out = piecewise(*args, **kwargs)
-                    except Exception as why:
-                        # ANY failure in the split path (unsafe carry,
-                        # tape truncation, a Tensor where the break
-                        # expected a python int, ...) demotes: restore
-                        # the snapshot so a prefix that already stepped
-                        # the optimizer isn't applied twice, then rerun
-                        # eagerly — genuine user errors re-raise from
-                        # the eager path with clean state
-                        self._restore_host_state(snap)
-                        warnings.warn(
-                            "to_static(full_graph=False): piecewise "
-                            f"capture unsafe ({why}); falling back to "
-                            "whole-function eager execution.",
-                            stacklevel=2)
+        with _obs.span("to_static.call", tid="to_static",
+                       trace_id=f"{self._qualname}:{self._n_calls}",
+                       fn=self._qualname) as call:
+            self._call_span = call  # pure() parents its trace span on it
+            try:
+                with _leg("revalidate", call):
+                    if self._needs_discovery:
+                        self._auto_discover(self._orig_fn)
+                        self._needs_discovery = False
                     else:
-                        info = piecewise._info
-                        warnings.warn(
-                            "to_static(full_graph=False): graph break at "
-                            f"line {info['line']} ({info['stmt']!r}) — "
-                            "piecewise capture: prefix and suffix run "
-                            "compiled; only the breaking statement runs "
-                            "eagerly each call (host side effects "
-                            "re-execute; carried locals: "
-                            f"{info['carry1']}).",
-                            stacklevel=2)
-                        self._piecewise = piecewise
-                        return out
-            warnings.warn(
-                "to_static(full_graph=False): graph break — falling back "
-                f"to piecewise eager execution for "
-                f"{getattr(self._orig_fn, '__qualname__', self._orig_fn)}. "
-                f"Reason: {e}",
-                stacklevel=2,
-            )
-            self._fallback_eager = True
-            return self._orig_fn(*args, **kwargs)
-        trace_runs = self._pure_runs - runs_before
-        self._last_lowered = jitted
-        self._write_state(new_state)
-        self._sanitize_grads()
-        # host-side step counters: this call represents exactly ONE step
-        # for each optimizer that actually steps in the traced program;
-        # tracing already advanced _global_step once per pure() execution
-        # (0 on cached calls, 1 per [re]trace)
-        correction = 1 - trace_runs
-        if correction:
-            for o in self._stepped_optimizers:
-                o._global_step += correction
-        return tree_util.tree_map(
-            lambda a: Tensor(a, _internal=True) if isinstance(a, jax.Array) else a, out_arrays
-        )
+                        self._revalidate_captures()
+                    if not self._cells:
+                        self._collect_cells()
+
+                flat, arg_treedef = tree_util.tree_flatten((args, kwargs), is_leaf=_is_tensor)
+                flat_arrays = [a._data if isinstance(a, Tensor) else a for a in flat]
+
+                with _leg("read_state", call):
+                    state = self._read_state()
+                    lrs = [jnp.asarray(o.get_lr(), jnp.float32) for o in self._optimizers]
+
+                jitted = self._jit_cache.get(arg_treedef)
+                if jitted is None:
+                    jit_kwargs = {}
+                    if self._donate_state:
+                        jit_kwargs["donate_argnums"] = (0,)
+                    # the pure function gets no local of its own: this
+                    # frame keeps its size (see above; PERF.md §6)
+                    jitted = jax.jit(self._make_pure(arg_treedef), **jit_kwargs)
+                    self._jit_cache[arg_treedef] = jitted
+                steps_before = [o._global_step for o in self._optimizers]
+                try:
+                    with _leg("dispatch", call):
+                        out_arrays, new_state = jitted(state, lrs, flat_arrays)
+                except dy2static.GraphBreakError as e:
+                    if self._full_graph:
+                        raise
+                    # from here on this call is not a compiled one: the
+                    # span says so, and its readers leave it out
+                    call.args["fallback"] = True
+                    # SOT semantics (ref jit/sot opcode_executor.py:305,1594):
+                    # split the function at the breaking statement — prefix and
+                    # suffix stay COMPILED (their own StaticFunctions), the
+                    # breaking statement runs eagerly each call. Only when no
+                    # safe split exists does the whole function demote to
+                    # per-op eager. The failed trace wrote tracers into the
+                    # threaded state; roll it back first.
+                    self._write_state(state)
+                    self._sanitize_grads()
+                    for o, s0 in zip(self._optimizers, steps_before):
+                        o._global_step = s0
+                    import warnings
+
+                    if self._split_depth < 3:
+                        piecewise = self._build_piecewise(e)
+                        if piecewise is not None:
+                            snap = self._snapshot_host_state()
+                            try:
+                                out = piecewise(*args, **kwargs)
+                            except Exception as why:
+                                # ANY failure in the split path (unsafe carry,
+                                # tape truncation, a Tensor where the break
+                                # expected a python int, ...) demotes: restore
+                                # the snapshot so a prefix that already stepped
+                                # the optimizer isn't applied twice, then rerun
+                                # eagerly — genuine user errors re-raise from
+                                # the eager path with clean state
+                                self._restore_host_state(snap)
+                                warnings.warn(
+                                    "to_static(full_graph=False): piecewise "
+                                    f"capture unsafe ({why}); falling back to "
+                                    "whole-function eager execution.",
+                                    stacklevel=2)
+                            else:
+                                info = piecewise._info
+                                warnings.warn(
+                                    "to_static(full_graph=False): graph break at "
+                                    f"line {info['line']} ({info['stmt']!r}) — "
+                                    "piecewise capture: prefix and suffix run "
+                                    "compiled; only the breaking statement runs "
+                                    "eagerly each call (host side effects "
+                                    "re-execute; carried locals: "
+                                    f"{info['carry1']}).",
+                                    stacklevel=2)
+                                self._piecewise = piecewise
+                                return out
+                    warnings.warn(
+                        "to_static(full_graph=False): graph break — falling back "
+                        f"to piecewise eager execution for "
+                        f"{getattr(self._orig_fn, '__qualname__', self._orig_fn)}. "
+                        f"Reason: {e}",
+                        stacklevel=2,
+                    )
+                    self._fallback_eager = True
+                    return self._orig_fn(*args, **kwargs)
+                trace_runs = self._pure_runs - runs_before
+                self._last_lowered = jitted
+                with _leg("write_state", call):
+                    self._write_state(new_state)
+                    self._sanitize_grads()
+                    # host-side step counters: this call represents exactly ONE
+                    # step for each optimizer that actually steps in the traced
+                    # program; tracing already advanced _global_step once per
+                    # pure() execution (0 on cached calls, 1 per [re]trace)
+                    if trace_runs != 1:
+                        for o in self._stepped_optimizers:
+                            o._global_step += 1 - trace_runs
+                return tree_util.tree_map(
+                    lambda a: Tensor(a, _internal=True) if isinstance(a, jax.Array) else a, out_arrays
+                )
+            finally:
+                self._call_span = None
+                call.args.update(traces=self._pure_runs - runs_before,
+                                 leaves=self._n_leaves)
 
     # -- host-state snapshot (piecewise trial safety) --------------------
     def _snapshot_host_state(self):

@@ -27,6 +27,20 @@ no-op while keeping id propagation intact, which is what the
 merge on one axis; :func:`stitch_traces` unions dumps and
 :func:`export_chrome_trace` renders either a single ring or a stitched
 set.
+
+**Two sinks, one primitive.** The ring's clock is this process's own; a
+device trace's events are on the profiler's clock. So the context-manager
+form :func:`span` also enters a ``jax.profiler.TraceAnnotation`` named
+``pt:<span name>`` (``pt:to_static.call``, ``pt:route``) for the span's
+lifetime, carrying ``trace_id`` / ``span_id`` / ``parent_id`` as stats.
+While a profiler session runs (``paddle_tpu.profiler.Profiler``, or
+``jax.profiler.start_trace``) the span is then an event of the xplane's
+host plane, beside the device's ``XLA Ops`` line and on its clock, so a
+device gap can be laid under the program span that was open; open the
+trace directory in TensorBoard's profile plugin or Perfetto and look for
+the ``pt:`` events. With no session the annotation costs a flag check.
+This is the only place in ``paddle_tpu`` that opens a ``TraceAnnotation``.
+The ring itself is dumped with ``python -m paddle_tpu.obs trace``.
 """
 from __future__ import annotations
 
@@ -37,6 +51,8 @@ import random
 import threading
 import time
 from typing import Dict, Iterable, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Span",
@@ -224,7 +240,12 @@ def start_span(name: str, *, trace_id: Optional[str] = None,
     or any object with trace_id/span_id attributes; when it carries a
     trace the span joins it, otherwise ``trace_id`` (or a fresh id) is
     used. Always returns a usable Span — recording is decided at
-    finish time."""
+    finish time. The ``start_span`` / ``finish_span`` pair is for
+    lifetimes that do not nest (a dispatch issued in one engine step
+    and harvested in a later one), which a profiler annotation cannot
+    express: these spans reach the ring only, never the device trace.
+    Use :func:`span` for anything that should be seen beside the
+    device's ops."""
     ptrace, pspan = _resolve_parent(trace_id, parent)
     return Span(name, ptrace or new_trace_id(), _new_span_id(), pspan,
                 tid, args)
@@ -244,16 +265,34 @@ def finish_span(sp: Optional[Span], **args) -> Optional[Span]:
     return sp
 
 
+# prefix of every annotation this module writes into a profiler trace:
+# a reader that filters host events by its own bare names (``harvest``,
+# ``train.step``) never picks up a program span of the same name
+ANNOTATION_PREFIX = "pt:"
+
+
 class _SpanCtx:
-    __slots__ = ("_span",)
+    __slots__ = ("_span", "_annotation")
 
     def __init__(self, sp: Span):
         self._span = sp
+        self._annotation = None
 
     def __enter__(self) -> Span:
+        # the second sink: an event on the profiler's clock, only while
+        # a profiler session runs (is_enabled is a flag read)
+        if _ENABLED and TraceAnnotation.is_enabled():
+            sp = self._span
+            self._annotation = TraceAnnotation(
+                ANNOTATION_PREFIX + sp.name, trace_id=sp.trace_id,
+                span_id=sp.span_id, parent_id=sp.parent_id or "")
+            self._annotation.__enter__()
         return self._span
 
     def __exit__(self, exc_type, exc, tb):
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if exc_type is not None:
             self._span.args.setdefault("error", exc_type.__name__)
         finish_span(self._span)
@@ -266,7 +305,10 @@ def span(name: str, *, trace_id: Optional[str] = None, parent=None,
 
         with obs.span("route", parent=req) as sp:
             ...
-    """
+
+    Records to the ring at exit and, while a profiler session runs,
+    is a ``pt:<name>`` event of the device trace's host plane (see the
+    module docstring)."""
     return _SpanCtx(start_span(name, trace_id=trace_id, parent=parent,
                                tid=tid, **args))
 

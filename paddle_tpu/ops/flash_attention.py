@@ -152,6 +152,7 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, interpret: bool):
             ),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse[:, :, 0, :]
 
@@ -283,6 +284,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale: float, causal: bool, interpret: boo
             ),
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse3, delta3)
 
     dk, dv = pl.pallas_call(
@@ -314,6 +316,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale: float, causal: bool, interpret: boo
             ),
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse3, delta3)
     return dq, dk, dv
 

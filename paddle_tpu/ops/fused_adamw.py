@@ -354,6 +354,7 @@ def fused_adamw_update(
             transcendentals=total,
         ),
         interpret=interpret,
+        name="fused_adamw",
     )(scalars, salts, p2, g2, m2, v2)
 
     unflat = lambda a: a.reshape(-1)[:total].reshape(p.shape)  # noqa: E731

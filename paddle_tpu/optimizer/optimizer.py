@@ -16,6 +16,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -296,39 +297,48 @@ class Optimizer:
     # ------------------------------------------------------------------
     @no_grad()
     def step(self):
-        self._global_step += 1
-        self._interleave_applied.clear()
-        for group in self._param_groups:
-            params_grads = [
-                (p, p.grad) for p in group["params"] if not p.stop_gradient and p.grad is not None
-            ]
-            if self._grad_placement_fn is not None:
+        # under a trace the scope goes into the op metadata of every update
+        # op, so a device trace says what they are (a with-block and not a
+        # decorator: one more Python frame under every traced update shifts
+        # the interpreter's frame stack, see PERF.md on set-up). The
+        # with-block takes a stack slot, so the last loop passes the
+        # gradient's array without a local of its own: this frame lies
+        # under every traced update and stays at 24 words (PERF.md §6)
+        with jax.named_scope("optimizer.step"):
+            self._global_step += 1
+            self._interleave_applied.clear()
+            for group in self._param_groups:
                 params_grads = [
-                    (p, Tensor(self._grad_placement_fn(g._data, p), _internal=True))
-                    for p, g in params_grads
+                    (p, p.grad) for p in group["params"] if not p.stop_gradient and p.grad is not None
                 ]
-            # reference order (ref: optimizer.py:1519-1525): grad clip FIRST,
-            # then regularization — the decay term is not clipped
-            grad_clip = group.get("grad_clip", self._grad_clip)
-            if grad_clip is not None:
-                params_grads = grad_clip(params_grads)
-            group_reg = group.get("weight_decay", None)
-            if isinstance(group_reg, (int, float)):
-                group_reg = L2Decay(float(group_reg))
-            new_pg = []
-            for p, g in params_grads:
-                # parameter's own regularizer wins, then the group's, then
-                # the optimizer-level one (reference precedence)
-                reg = getattr(p, "regularizer", None) or group_reg or self.regularization
-                if reg is not None:
-                    g = Tensor(reg(p._data, g._data), _internal=True)
-                new_pg.append((p, g))
-            params_grads = new_pg
-            group_lr_scale = float(group.get("learning_rate", 1.0))
-            for p, g in params_grads:
-                garr = g._data if isinstance(g, Tensor) else g
-                lr_scale = p.optimize_attr.get("learning_rate", 1.0) if getattr(p, "optimize_attr", None) else 1.0
-                self._update_param(p, garr, lr_scale * group_lr_scale, group)
+                if self._grad_placement_fn is not None:
+                    params_grads = [
+                        (p, Tensor(self._grad_placement_fn(g._data, p), _internal=True))
+                        for p, g in params_grads
+                    ]
+                # reference order (ref: optimizer.py:1519-1525): grad clip FIRST,
+                # then regularization — the decay term is not clipped
+                grad_clip = group.get("grad_clip", self._grad_clip)
+                if grad_clip is not None:
+                    params_grads = grad_clip(params_grads)
+                group_reg = group.get("weight_decay", None)
+                if isinstance(group_reg, (int, float)):
+                    group_reg = L2Decay(float(group_reg))
+                new_pg = []
+                for p, g in params_grads:
+                    # parameter's own regularizer wins, then the group's, then
+                    # the optimizer-level one (reference precedence)
+                    reg = getattr(p, "regularizer", None) or group_reg or self.regularization
+                    if reg is not None:
+                        g = Tensor(reg(p._data, g._data), _internal=True)
+                    new_pg.append((p, g))
+                params_grads = new_pg
+                group_lr_scale = float(group.get("learning_rate", 1.0))
+                for p, g in params_grads:
+                    lr_scale = p.optimize_attr.get("learning_rate", 1.0) if getattr(p, "optimize_attr", None) else 1.0
+                    self._update_param(
+                        p, g._data if isinstance(g, Tensor) else g,
+                        lr_scale * group_lr_scale, group)
 
     def _update_param(self, p, g, lr_scale, group):
         raise NotImplementedError

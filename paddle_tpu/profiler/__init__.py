@@ -6,8 +6,8 @@ timer.py:394 (benchmark ips tracking).
 
 TPU-native redesign: the device-side tracer is jax.profiler (XLA/TPU
 trace via TensorBoard's profile plugin — the role kineto/CUPTI plays in
-the reference); RecordEvent lowers to jax.profiler.TraceAnnotation so
-user spans show up inside the device trace. The chrome-trace exporter
+the reference); RecordEvent opens an ``obs.span``, whose ``pt:``
+annotation puts user spans inside the device trace. The chrome-trace exporter
 writes the TensorBoard profile directory; ``make_scheduler`` reproduces
 the reference's CLOSED/READY/RECORD state machine.
 """

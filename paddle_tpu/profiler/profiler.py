@@ -1,11 +1,14 @@
 """Profiler core (ref: python/paddle/profiler/profiler.py:346).
 
 Since ISSUE 12 this is a thin adapter over :mod:`paddle_tpu.obs`: every
-:class:`RecordEvent` doubles as an obs span (so user annotations land
-on the same Perfetto timeline as the serving/request spans) and step /
-event durations feed registry histograms readable via
-``python -m paddle_tpu.obs dump``. The jax.profiler device trace
-integration is unchanged.
+:class:`RecordEvent` is an ``obs.span`` (so user annotations land on the
+same Perfetto timeline as the serving/request spans and, through the
+span's own ``pt:`` annotation, beside the device's ops in a profiler
+trace) and step / event durations feed registry histograms readable via
+``python -m paddle_tpu.obs dump``. :class:`Profiler` drives
+``jax.profiler.start_trace`` / ``stop_trace``; while it records, every
+``obs.span`` of the program (``pt:to_static.call`` and its legs among
+them) is an event of the trace's host plane.
 """
 from __future__ import annotations
 
@@ -86,35 +89,29 @@ def export_chrome_tracing(dir_name: str, worker_name: Optional[str] = None):
 
 
 class RecordEvent:
-    """User span annotation (ref: profiler/utils.py RecordEvent) —
-    shows up in the XLA device trace via TraceAnnotation AND as an obs
-    span named ``profiler:<name>`` on the host trace timeline, with the
-    duration folded into the ``profiler_event_seconds`` histogram."""
+    """User span annotation (ref: profiler/utils.py RecordEvent): an
+    ``obs.span`` named ``profiler:<name>`` — so it lands in the obs ring
+    and, while a profiler session runs, in the device trace's host
+    plane as ``pt:profiler:<name>`` — with the duration folded into the
+    ``profiler_event_seconds`` histogram and the active Profiler's
+    UserDefined summary."""
 
     def __init__(self, name: str, event_type=None):
         self.name = name
         self._ctx = None
-        self._sp = None
         self.begin_ns = None
         self.end_ns = None
 
     def begin(self):
-        import jax.profiler
-
         self.begin_ns = time.perf_counter_ns()
-        self._ctx = jax.profiler.TraceAnnotation(self.name)
+        self._ctx = _obs.span(f"profiler:{self.name}", tid="profiler")
         self._ctx.__enter__()
-        if _obs.enabled():
-            self._sp = _obs.start_span(f"profiler:{self.name}",
-                                       tid="profiler")
 
     def end(self):
         if self._ctx is not None:
             self._ctx.__exit__(None, None, None)
             self._ctx = None
             self.end_ns = time.perf_counter_ns()
-            _obs.finish_span(self._sp)
-            self._sp = None
             _obs_registry().histogram(
                 "profiler_event_seconds", {"name": self.name},
                 help="RecordEvent span durations").observe(

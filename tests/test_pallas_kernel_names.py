@@ -1,0 +1,65 @@
+"""The Mosaic ``kernel_name`` of each kernel the package owns, as the
+TPU lowering writes it, against the names ``chip_smoke.py`` requires in
+the programs that ran and the patterns the benchmark's flash readers
+match in a device trace. Lowered for the TPU from the CPU, so a rename of
+a ``pl.pallas_call`` cannot pass tier-1 and break only on the chip."""
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import chip_smoke
+from chipbench.layer_metrics import flash_bwd_roofline, flash_fwd_roofline
+from paddle_tpu.ops import flash_attention as flash
+from paddle_tpu.ops import fused_adamw
+
+
+def _lowered_for_tpu(fn, *args):
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+def _flash_program():
+    def loss(q, k, v):
+        return flash.flash_attention(
+            q, k, v, True, None, False).astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, 256, 4, 128), jnp.bfloat16)
+    return _lowered_for_tpu(jax.grad(loss, (0, 1, 2)), q, q, q)
+
+
+def _adamw_program():
+    p = jax.ShapeDtypeStruct((256, 256), jnp.float32)
+    return _lowered_for_tpu(
+        functools.partial(
+            fused_adamw.fused_adamw_update, lr=1e-3, beta1=0.9, beta2=0.999,
+            epsilon=1e-8, beta1_pow=0.9, beta2_pow=0.999, interpret=False),
+        p, p, p, p)
+
+
+@pytest.mark.parametrize("program, wanted", [
+    (_flash_program, chip_smoke.FLASH_KERNELS),
+    (_adamw_program, chip_smoke.ADAMW_KERNELS),
+], ids=["flash", "fused_adamw"])
+def test_kernel_names_are_the_ones_chip_smoke_requires(program, wanted):
+    have = chip_smoke.kernels_in(program())
+    assert have == sorted(wanted)
+
+
+def test_flash_readers_patterns_match_the_kernel_names():
+    # the trace shows a kernel as its HLO instruction, named after the
+    # pallas_call inside jax's transforms (PERF.md §3, chip runs of PR 24)
+    shown = {
+        "flash_fwd": "%jvp_flash_fwd_.12 = ",
+        "flash_bwd_dq": "%transpose_jvp_flash_bwd_dq__.3 = ",
+        "flash_bwd_dkv": "%transpose_jvp_flash_bwd_dkv__.4 = ",
+    }
+    assert sorted(shown) == sorted(chip_smoke.FLASH_KERNELS)
+    patterns = {"flash_fwd": flash_fwd_roofline.KERNEL,
+                "flash_bwd_dq": flash_bwd_roofline.DQ,
+                "flash_bwd_dkv": flash_bwd_roofline.DKV}
+    for kernel, pattern in patterns.items():
+        hits = [k for k, text in shown.items() if re.search(pattern, text)]
+        assert hits == [kernel]
