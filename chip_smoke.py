@@ -463,11 +463,15 @@ def phase_kernels(tiny: bool) -> None:
         cases = [
             # the train step's shape, then the other head dims
             # _use_pallas admits, GQA, and cross-attention Sk=77 (no
-            # block divides it: _block() hands back the whole axis)
+            # block divides it: the tiling takes the whole axis)
             ("flash-d128", kernel_flash, (2048, 2048, 4, 4, 128, True)),
             ("flash-d64-gqa", kernel_flash, (2048, 2048, 4, 2, 64, True)),
             ("flash-d256", kernel_flash, (1024, 1024, 2, 2, 256, True)),
             ("flash-cross77", kernel_flash, (1024, 77, 2, 2, 64, False)),
+            # the causal schedule's off > 0 branch (sq < sk), and the
+            # benchmark cell's own shape and head count
+            ("flash-sq-lt-sk", kernel_flash, (512, 2048, 4, 4, 128, True)),
+            ("flash-cell-h16", kernel_flash, (2048, 2048, 16, 16, 128, True)),
             ("paged-r1-p32", kernel_paged, (8, 8, 32, 32, False)),
             ("paged-r1-p64", kernel_paged, (8, 8, 64, 64, False)),
             ("paged-r8-p8", kernel_paged, (16, 2, 32, 8, False)),

@@ -11,7 +11,9 @@ import pytest
 
 import paddle_tpu as paddle
 import paddle_tpu.nn.functional as F
-from paddle_tpu.ops.flash_attention import flash_attention
+from paddle_tpu.ops import flash_attention as kernels
+from paddle_tpu.ops.flash_attention import (
+    Sweep, TileCounts, flash_attention, tile_plan)
 
 
 def _naive(q, k, v, causal):
@@ -100,6 +102,154 @@ class TestFlashKernel:
         out = f(q)
         ref = _naive(q, q, q, True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.quick
+class TestTileSchedule:
+    """The causal tile schedule (PR 25): which tiles run bare, which
+    masked, which never — counted per head, and proven against the
+    naive path on every branch the schedule has."""
+
+    def test_plan_of_the_benchmark_cell(self):
+        # (1, 2048, 16, 128) bf16 causal: the sequence is held whole and
+        # its 16 tiles of 512 x 512 are classified when the kernel is
+        # traced: 6 below the diagonal, 4 on it, 6 never visited
+        whole = Sweep(held=2048, rows=512, fetch=2048, sub=512)
+        assert tile_plan(2048, 2048, 128, True) == {
+            name: (whole, TileCounts(full=6, masked=4, dead=6))
+            for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+        assert tile_plan(2048, 2048, 128, False)["flash_fwd"][1] == \
+            TileCounts(full=16, masked=0, dead=0)
+
+    def test_plan_with_an_offset(self):
+        # sq < sk: query i sees keys <= i + 1536; only the last k tile
+        # straddles the diagonal
+        plan = tile_plan(512, 2048, 128, True)
+        assert plan["flash_fwd"] == (
+            Sweep(held=512, rows=512, fetch=2048, sub=512),
+            TileCounts(full=3, masked=1, dead=0))
+        assert plan["flash_bwd_dkv"] == (
+            Sweep(held=2048, rows=512, fetch=512, sub=512),
+            TileCounts(full=3, masked=1, dead=0))
+        # rectangular tiles, 128 x 512, whose diagonal tiles are partly
+        # dead: rows 0..127 see keys <= 767, rows 256..383 keys <= 1023
+        assert tile_plan(384, 1024, 64, True)["flash_fwd"] == (
+            Sweep(held=384, rows=128, fetch=1024, sub=512),
+            TileCounts(full=3, masked=3, dead=0))
+        # float32 operands halve the rows a fetched block holds: the
+        # sequence is no longer held whole, the counts stay
+        assert tile_plan(2048, 2048, 128, True, itemsize=4)["flash_fwd"] == (
+            Sweep(held=512, rows=512, fetch=1024, sub=512),
+            TileCounts(full=6, masked=4, dead=6))
+
+    @pytest.mark.parametrize("off", [0, 256, -256, 100])
+    @pytest.mark.parametrize("over_k", [True, False])
+    def test_spans_against_the_rule_itself(self, off, over_k):
+        """``_spans`` against a brute-force count of visible pairs, on
+        ints and on traced scalars alike."""
+        rows, sub, n = 256, 128, 8
+        traced = jax.jit(
+            lambda first: kernels._spans(first, rows, off, sub, n, over_k))
+        for first in range(0, 1024, rows):
+            f0, f1, d0, d1 = kernels._spans(first, rows, off, sub, n, over_k)
+            assert tuple(int(x) for x in traced(first)) == (f0, f1, d0, d1)
+            for j in range(n):
+                held = np.arange(first, first + rows)[:, None]
+                swept = np.arange(j * sub, (j + 1) * sub)[None, :]
+                q, k = (held, swept) if over_k else (swept, held)
+                seen = int((k <= q + off).sum())
+                kind = ("full" if f0 <= j < f1 else
+                        "masked" if d0 <= j < d1 else "dead")
+                assert kind == ("full" if seen == rows * sub else
+                                "masked" if seen else "dead"), (first, j)
+
+    @pytest.fixture
+    def looped(self, monkeypatch):
+        """Fetched blocks of 128 rows and nothing unrolled: the loops
+        with traced bounds and the clamped index maps, which at these
+        small shapes the whole-sequence schedule would bypass."""
+        monkeypatch.setattr(kernels, "_FETCH_BYTES", 128 * 64 * 4)
+        monkeypatch.setattr(kernels, "_UNROLL", 0)
+        self._forget_traces()
+        yield
+        self._forget_traces()
+
+    @staticmethod
+    def _forget_traces():
+        # the launchers are jitted and the constants patched above are
+        # no part of a trace's key
+        kernels._flash_fwd.clear_cache()
+        kernels._flash_bwd.clear_cache()
+
+    @staticmethod
+    def _parity(sq, sk, hq, hkv, d, causal):
+        q = _rand((1, sq, hq, d), seed=0)
+        k = _rand((1, sk, hkv, d), seed=1)
+        v = _rand((1, sk, hkv, d), seed=2)
+        out = flash_attention(q, k, v, causal, None, True)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(_naive(q, k, v, causal)), atol=2e-5)
+        g1 = jax.grad(
+            lambda *a: (flash_attention(*a, causal, None, True) ** 2).sum(),
+            (0, 1, 2))(q, k, v)
+        g2 = jax.grad(
+            lambda *a: (_naive(*a, causal) ** 2).sum(), (0, 1, 2))(q, k, v)
+        for a, b in zip(g1, g2):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
+
+    @pytest.mark.parametrize("sq, sk, hq, hkv, d, causal", [
+        (256, 512, 2, 2, 64, True),     # sq < sk: off > 0
+        (384, 1024, 1, 1, 64, True),    # 128 x 512 tiles, diagonal partly dead
+        (1024, 1024, 1, 1, 64, True),   # a dead tile, skipped when traced
+        (256, 256, 4, 2, 64, True),     # GQA under the causal mask
+        (256, 384, 2, 2, 64, False),    # non-causal, three fetched blocks
+        (128, 77, 2, 1, 64, False),     # cross-attention, sk whole
+        (256, 256, 1, 1, 256, True),    # D = 256
+        (256, 256, 1, 1, 128, True),    # D = 128
+    ], ids=["sq<sk", "rect-partly-dead", "dead-tile", "gqa", "noncausal",
+            "cross77", "d256", "d128"])
+    def test_unrolled_schedule_matches_naive(self, sq, sk, hq, hkv, d, causal):
+        self._parity(sq, sk, hq, hkv, d, causal)
+
+    @pytest.mark.parametrize("sq, sk, hq, hkv, d, causal", [
+        (512, 512, 1, 1, 64, True),     # 512 x 128 tiles: bq != bk
+        (256, 512, 2, 1, 64, True),     # off > 0, GQA
+        (384, 384, 1, 1, 64, True),     # 128 x 128, dead blocks clamped
+        (256, 384, 1, 1, 64, False),    # non-causal through the loop
+    ], ids=["rect", "sq<sk-gqa", "dead-blocks", "noncausal"])
+    def test_looped_schedule_matches_naive(self, looped, sq, sk, hq, hkv, d,
+                                           causal):
+        plan = tile_plan(sq, sk, d, causal, itemsize=4)
+        assert all(t.held == t.rows and t.fetch == 128
+                   for t, _ in plan.values())
+        self._parity(sq, sk, hq, hkv, d, causal)
+
+    @pytest.mark.parametrize("unrolled", [True, False])
+    def test_more_queries_than_keys(self, unrolled, monkeypatch):
+        """sq > sk under the causal mask (off < 0): the first sq - sk
+        queries see no key and emit zeros; the rest are the square case."""
+        if not unrolled:
+            monkeypatch.setattr(kernels, "_UNROLL", 0)
+        self._forget_traces()
+        q = _rand((1, 256, 2, 64), seed=0)
+        k = _rand((1, 128, 2, 64), seed=1)
+        v = _rand((1, 128, 2, 64), seed=2)
+
+        def loss(fn):
+            return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+        flash = lambda q, k, v: flash_attention(q, k, v, True, None, True)
+        seen = lambda q, k, v: _naive(q[:, 128:], k, v, True)
+        out = flash(q, k, v)
+        assert not np.asarray(out[:, :128]).any()
+        np.testing.assert_allclose(
+            np.asarray(out[:, 128:]), np.asarray(seen(q, k, v)), atol=2e-5)
+        g1 = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+        g2 = jax.grad(loss(seen), (0, 1, 2))(q, k, v)
+        for a, b in zip(g1, g2):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
+        self._forget_traces()
 
 
 class TestFunctionalDispatch:

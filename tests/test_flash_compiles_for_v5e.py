@@ -1,0 +1,88 @@
+"""The flash-attention kernels compile for the chip the benchmark runs on.
+
+Interpret mode proves the arithmetic; it cannot see what Mosaic and the
+v5e refuse: a slice off the (8, 128) tiling, a block VMEM cannot hold, a
+loop form the lowering does not know. The TPU compiler is installed here
+and compiles for a chip that is described, not attached
+(/opt/skills/guides/on-chip-measurement §2), so every later change of
+tile sizes is held to the chip's rules at no chip time. Nothing runs:
+these say nothing about results or times.
+
+The topology is described inside a module-scoped fixture (only the
+worker that is given this file loads the TPU's library) and everything
+that needs it is in this one file.
+"""
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from chipbench.layer_metrics import flash_bwd_roofline, flash_fwd_roofline
+from paddle_tpu.ops.flash_attention import flash_attention
+
+# the patterns the benchmark's flash readers find the kernels by in a
+# device trace, whose events are named by the compiled HLO instruction
+READERS = (flash_fwd_roofline.KERNEL, flash_bwd_roofline.DQ,
+           flash_bwd_roofline.DKV)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # whatever keeps the compiler from a topology
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it out of there
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# (sq, sk, q heads, kv heads, d_head, causal, dtype)
+SHAPES = {
+    # the benchmark's cell, train-1p3b-2k: held whole, 10 tiles unrolled
+    "cell-1p3b": (2048, 2048, 16, 16, 128, True, jnp.bfloat16),
+    # a 13B shard of train-13b-fleet4 (40 heads over mp 2)
+    "fleet-13b-shard": (2048, 2048, 20, 20, 128, True, jnp.bfloat16),
+    # chip_smoke.py's kernel cases
+    "flash-d128": (2048, 2048, 4, 4, 128, True, jnp.bfloat16),
+    "flash-d64-gqa": (2048, 2048, 4, 2, 64, True, jnp.bfloat16),
+    "flash-d256": (1024, 1024, 2, 2, 256, True, jnp.bfloat16),
+    "flash-cross77": (1024, 77, 2, 2, 64, False, jnp.bfloat16),
+    "flash-sq-lt-sk": (512, 2048, 4, 4, 128, True, jnp.bfloat16),
+    # the looped schedule at real sizes: a sequence too long to hold, and
+    # float32 operands, whose blocks hold half the rows
+    "long-8k": (8192, 8192, 2, 2, 128, True, jnp.bfloat16),
+    "noncausal-4k": (4096, 4096, 2, 2, 64, False, jnp.bfloat16),
+    "float32": (2048, 2048, 2, 2, 128, True, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
+def test_grad_of_the_kernel_compiles_for_a_v5e(one_chip, shape):
+    sq, sk, hq, hkv, d, causal, dtype = shape
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal, None, False)
+        return out.astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, sq, hq, d), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, sk, hkv, d), dtype, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3  # forward, dq, dk/dv: one kernel each
+    for pattern in READERS:
+        assert sum(bool(re.search(pattern, c)) for c in calls) == 1, pattern
