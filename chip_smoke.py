@@ -13,7 +13,9 @@ seed, in ONE process on ONE chip:
            (ratio 1 with 32 and 64 pages per compute block, ratio 8
            with 8, bf16 and int8 pools), fused AdamW (f32/bf16 moments,
            SR on/off, the found-inf skip) plus one ``AdamW(fused=True)``
-           train step;
+           train step; the grouped matmul (forward, input and weight
+           gradient, uneven groups with an empty one) and one ZAYA1
+           block at its published widths against its float32 reference;
   trainer  ``jit.to_static(step, layers=[model], optimizers=[opt])`` with
            the bench's optimizer settings (bf16 params, masterless
            stochastic rounding, bf16 moments), 2 layers, B x S = 1 x 2048:
@@ -164,6 +166,7 @@ def memory_line(tag: str) -> dict:
 # two together)
 FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 ADAMW_KERNELS = ("fused_adamw",)
+MOE_KERNELS = ("moe_gmm", "moe_tgmm")   # ops/grouped_matmul.py
 
 
 def kernels_in(text: str) -> list:
@@ -368,6 +371,103 @@ def kernel_paged(h, kvh, pages_per_seq, want_pages, int8, bs=64, d=128):
     _close(got, jax.jit(reference)(*args), tag)
 
 
+def kernel_grouped_matmul(rows, k, n, groups):
+    """``ops.grouped_matmul`` forward, input gradient and weight gradient
+    against a float32 loop over the groups; the sizes are uneven, one
+    group is empty and the edges fall inside tiles."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.grouped_matmul import grouped_matmul
+
+    rng = np.random.RandomState(rows + k + n)
+    share = rng.uniform(0.5, 1.5, groups)
+    share[groups // 2] = 0.0
+    sizes = rng.multinomial(rows, share / share.sum()).astype(np.int32)
+    ks = jax.random.split(jax.random.key(rows + k + n), 3)
+    x = jax.random.normal(ks[0], (rows, k), jnp.bfloat16)
+    w = jax.random.normal(ks[1], (groups, k, n), jnp.bfloat16)
+    dy = jax.random.normal(ks[2], (rows, n), jnp.bfloat16)
+    gs = jnp.asarray(sizes)
+
+    def kernel(x, w, dy):
+        out, vjp = jax.vjp(lambda x, w: grouped_matmul(x, w, gs), x, w)
+        return (out,) + vjp(dy)
+
+    def plain(x, w):
+        ends = jnp.cumsum(gs)
+        row = jnp.arange(rows)[:, None]
+        out = jnp.zeros((rows, n), jnp.float32)
+        for e in range(groups):
+            own = (row >= ends[e] - gs[e]) & (row < ends[e])
+            out = out + jnp.where(own, x @ w[e], 0.0)
+        return out
+
+    def reference(x, w, dy):
+        f32 = [a.astype(jnp.float32) for a in (x, w, dy)]
+        with jax.default_matmul_precision("highest"):
+            out, vjp = jax.vjp(plain, *f32[:2])
+            return (out,) + vjp(f32[2])
+
+    tag = f"grouped_matmul rows={rows} k={k} n={n} groups={groups}"
+    say(f"{tag} sizes={sizes.tolist()}")
+    got = compiled_with_kernels(kernel, (x, w, dy), MOE_KERNELS,
+                                tag)(x, w, dy)
+    ref = jax.jit(reference)(x, w, dy)
+    for g, r, name in zip(got, ref, ("out", "dx", "dw")):
+        _close(g, r, f"{tag} {name}")
+
+
+def kernel_zaya_block(tiny: bool):
+    """One ZAYA1 decoder block (``models/zaya.py``: compressed
+    convolutional attention through the flash kernel, the MLP router, the
+    dropless experts through the grouped matmul) in bfloat16 against its
+    plain float32 reference on the same seeded weights. Top-1 routing is
+    discrete, so the reference is told which expert the program gave each
+    token and computes the rest itself (PERF.md section 2): every row is
+    compared, and the tokens the reference would have routed otherwise
+    are counted beside."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from chipbench.families import zaya
+    from chipbench.harness import ROOT, load_json
+    from paddle_tpu.base.tape import no_grad
+
+    name = ("tests/chipbench/configs/toy-zaya.json" if tiny
+            else "chipbench/configs/zaya1-8b-d6.json")
+    cfg = load_json(os.path.join(ROOT, name))
+    cfg = dict(cfg, held=dict(cfg["held"], layers=1))
+    seq, seed = (64 if tiny else 4096), 5
+    model, _ = zaya._build_model(cfg, seed)
+    layer = model.model.layers[0]
+    x = jax.random.normal(jax.random.key(seed), (1, seq, cfg["hidden_size"]),
+                          jnp.float32).astype(jnp.bfloat16)
+
+    def block(x):
+        chosen = []
+        with no_grad():
+            out = layer(paddle.to_tensor(x), chosen)
+        return out._data, chosen[0]._data[0, :, 0]
+
+    tag = f"zaya block seq={seq}"
+    wanted = FLASH_KERNELS[:1] + MOE_KERNELS[:1]
+    out, ids = compiled_with_kernels(block, (x,), wanted, tag)(x)
+    ref = zaya.reference(cfg, seed)
+    with jax.default_matmul_precision("highest"):
+        want, (own, _) = ref._block(ref.block_params(ref.get, 0),
+                                    ref.fixed(0, 0), x[0].astype(jnp.float32),
+                                    ids)
+    flips = float((np.asarray(ids) != np.asarray(own)).mean())
+    say(f"{tag} routing_flip_share={flips:.4f} "
+        f"({int(round(flips * seq))} of {seq} tokens)")
+    check(flips <= 0.05, f"{tag}: {flips:.3f} of the tokens went to another "
+                         "expert than the float32 reference's")
+    _close(out[0].astype(jnp.float32), want,
+           f"{tag} out (the reference following the program's routing)")
+
+
 def kernel_adamw(p_dtype, m_dtype, sr, shape=(2048, 1280)):
     import functools
 
@@ -457,6 +557,8 @@ def phase_kernels(tiny: bool) -> None:
     bf16, f32 = jnp.bfloat16, jnp.float32
     if tiny:  # interpreted kernels at the smallest legal tiles
         cases = [("flash", kernel_flash, (128, 128, 2, 1, 64, True)),
+                 ("moe-gmm", kernel_grouped_matmul, (256, 128, 128, 4)),
+                 ("zaya-block", kernel_zaya_block, (True,)),
                  ("adamw", kernel_adamw, (bf16, bf16, True, (40, 130))),
                  ("adamw-step", kernel_adamw_train_step, ())]
     else:
@@ -472,6 +574,12 @@ def phase_kernels(tiny: bool) -> None:
             # benchmark cell's own shape and head count
             ("flash-sq-lt-sk", kernel_flash, (512, 2048, 4, 4, 128, True)),
             ("flash-cell-h16", kernel_flash, (2048, 2048, 16, 16, 128, True)),
+            # the routed cell (train-zaya1-6l-4k): GQA 8/2 at 4096 on the
+            # looped schedule, both grouped matmuls of a block, one block
+            ("flash-zaya-4k", kernel_flash, (4096, 4096, 8, 2, 128, True)),
+            ("moe-gmm-gate-up", kernel_grouped_matmul, (4096, 2048, 4096, 16)),
+            ("moe-gmm-down", kernel_grouped_matmul, (4096, 2048, 2048, 16)),
+            ("zaya-block", kernel_zaya_block, (False,)),
             ("paged-r1-p32", kernel_paged, (8, 8, 32, 32, False)),
             ("paged-r1-p64", kernel_paged, (8, 8, 64, 64, False)),
             ("paged-r8-p8", kernel_paged, (16, 2, 32, 8, False)),

@@ -2,7 +2,8 @@
 
 llama: decoder LM family (config #3); gpt: decoder LM with learned
 positions (config #4); bert: bidirectional encoder + MLM head
-(config #2); vision models live in paddle_tpu.vision (config #1).
+(config #2); zaya: compressed convolutional attention + top-1 routed
+experts, training path only; vision models live in paddle_tpu.vision (config #1).
 """
 from .llama import (  # noqa: F401
     LlamaConfig,
@@ -16,6 +17,12 @@ from .bert import (  # noqa: F401
     BertForMaskedLM,
     BertForSequenceClassification,
     BertModel,
+)
+from .zaya import (  # noqa: F401
+    ZayaConfig,
+    ZayaDecoderLayer,
+    ZayaForCausalLM,
+    ZayaModel,
 )
 from .unet import UNet2DConditionModel, UNetConfig  # noqa: F401
 from .generation import generate  # noqa: F401

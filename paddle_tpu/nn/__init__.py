@@ -16,6 +16,7 @@ from .layer.container import *  # noqa: F401,F403
 from .layer.conv import *  # noqa: F401,F403
 from .layer.layers import Layer, Parameter  # noqa: F401
 from .layer.loss import *  # noqa: F401,F403
+from .layer.moe import *  # noqa: F401,F403
 from .layer.norm import *  # noqa: F401,F403
 from .layer.pooling import *  # noqa: F401,F403
 from .layer.rnn import *  # noqa: F401,F403

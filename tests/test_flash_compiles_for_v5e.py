@@ -1,4 +1,5 @@
-"""The flash-attention kernels compile for the chip the benchmark runs on.
+"""The flash-attention and grouped-matmul kernels compile for the chip the
+benchmark runs on.
 
 Interpret mode proves the arithmetic; it cannot see what Mosaic and the
 v5e refuse: a slice off the (8, 128) tiling, a block VMEM cannot hold, a
@@ -19,8 +20,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from chipbench.layer_metrics import flash_bwd_roofline, flash_fwd_roofline
+from chipbench.layer_metrics import (flash_bwd_roofline, flash_fwd_roofline,
+                                     moe_gmm_roofline)
 from paddle_tpu.ops.flash_attention import flash_attention
+from paddle_tpu.ops.grouped_matmul import grouped_matmul
 
 # the patterns the benchmark's flash readers find the kernels by in a
 # device trace, whose events are named by the compiled HLO instruction
@@ -53,6 +56,8 @@ def one_chip():
 SHAPES = {
     # the benchmark's cell, train-1p3b-2k: held whole, 10 tiles unrolled
     "cell-1p3b": (2048, 2048, 16, 16, 128, True, jnp.bfloat16),
+    # train-zaya1-6l-4k: GQA 8/2 at 4096, 36 live tiles a head: the loop
+    "cell-zaya-4k": (4096, 4096, 8, 2, 128, True, jnp.bfloat16),
     # a 13B shard of train-13b-fleet4 (40 heads over mp 2)
     "fleet-13b-shard": (2048, 2048, 20, 20, 128, True, jnp.bfloat16),
     # chip_smoke.py's kernel cases
@@ -86,3 +91,34 @@ def test_grad_of_the_kernel_compiles_for_a_v5e(one_chip, shape):
     assert len(calls) == 3  # forward, dq, dk/dv: one kernel each
     for pattern in READERS:
         assert sum(bool(re.search(pattern, c)) for c in calls) == 1, pattern
+
+
+# (rows, k, n, groups, dtype): both grouped matmuls of a block of
+# train-zaya1-6l-4k, chip_smoke.py's cases, and float32 operands
+GROUPED = {
+    "cell-zaya-gate-up": (4096, 2048, 4096, 16, jnp.bfloat16),
+    "cell-zaya-down": (4096, 2048, 2048, 16, jnp.bfloat16),
+    "float32": (1024, 512, 1024, 8, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("shape", GROUPED.values(), ids=GROUPED.keys())
+def test_grad_of_the_grouped_matmul_compiles_for_a_v5e(one_chip, shape):
+    rows, k, n, groups, dtype = shape
+
+    def loss(x, w, sizes):
+        out = grouped_matmul(x, w, sizes, False)
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))
+
+    x = jax.ShapeDtypeStruct((rows, k), dtype, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((groups, k, n), dtype, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(x, w, sizes).compile()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # forward and input gradient are the same kernel, the weight
+    # gradient the other
+    assert sum(bool(re.search(moe_gmm_roofline.GMM, c)) for c in calls) == 2
+    assert sum(bool(re.search(moe_gmm_roofline.TGMM, c)) for c in calls) == 1
+    assert len(calls) == 3

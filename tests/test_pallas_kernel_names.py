@@ -11,9 +11,10 @@ import jax.numpy as jnp
 import pytest
 
 import chip_smoke
-from chipbench.layer_metrics import flash_bwd_roofline, flash_fwd_roofline
+from chipbench.layer_metrics import (flash_bwd_roofline, flash_fwd_roofline,
+                                     moe_gmm_roofline)
 from paddle_tpu.ops import flash_attention as flash
-from paddle_tpu.ops import fused_adamw
+from paddle_tpu.ops import fused_adamw, grouped_matmul
 
 
 def _lowered_for_tpu(fn, *args):
@@ -39,10 +40,22 @@ def _adamw_program():
         p, p, p, p)
 
 
+def _grouped_matmul_program():
+    def loss(x, w, sizes):
+        out = grouped_matmul.grouped_matmul(x, w, sizes, False)
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))
+
+    x = jax.ShapeDtypeStruct((256, 128), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((4, 128, 128), jnp.bfloat16)
+    sizes = jax.ShapeDtypeStruct((4,), jnp.int32)
+    return _lowered_for_tpu(jax.grad(loss, (0, 1)), x, w, sizes)
+
+
 @pytest.mark.parametrize("program, wanted", [
     (_flash_program, chip_smoke.FLASH_KERNELS),
     (_adamw_program, chip_smoke.ADAMW_KERNELS),
-], ids=["flash", "fused_adamw"])
+    (_grouped_matmul_program, chip_smoke.MOE_KERNELS),
+], ids=["flash", "fused_adamw", "grouped_matmul"])
 def test_kernel_names_are_the_ones_chip_smoke_requires(program, wanted):
     have = chip_smoke.kernels_in(program())
     assert have == sorted(wanted)
@@ -63,3 +76,20 @@ def test_flash_readers_patterns_match_the_kernel_names():
     for kernel, pattern in patterns.items():
         hits = [k for k, text in shown.items() if re.search(pattern, text)]
         assert hits == [kernel]
+
+
+def test_grouped_matmul_readers_patterns_match_the_kernel_names():
+    # the launchers are jitted, so the trace shows the pallas_call's own
+    # name: %moe_gmm.<n> for the forward AND the input gradient (the same
+    # kernel), %moe_tgmm.<n> for the weight gradient
+    shown = {"moe_gmm": "%moe_gmm.7 = bf16[4096,4096]{1,0} custom-call(",
+             "moe_tgmm": "%moe_tgmm.2 = bf16[16,2048,4096]{2,1,0} custom-call("}
+    assert sorted(shown) == sorted(chip_smoke.MOE_KERNELS)
+    patterns = {"moe_gmm": moe_gmm_roofline.GMM,
+                "moe_tgmm": moe_gmm_roofline.TGMM}
+    for kernel, pattern in patterns.items():
+        hits = [k for k, text in shown.items() if re.search(pattern, text)]
+        assert hits == [kernel]
+    # a fusion that only USES a kernel's result is not the kernel
+    user = "%fusion.9 = bf16[4096,2048]{1,0} fusion(bf16[4096,4096] %moe_gmm.7)"
+    assert not any(re.search(p, user) for p in patterns.values())
