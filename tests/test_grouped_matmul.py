@@ -1,8 +1,10 @@
 """``ops.grouped_matmul``: the forward and both backward forms against
 the jnp reference with the kernels interpreted, over the group layouts
 that break a tiled grouped matmul (empty groups, one group holding
-everything, edges inside a tile), the tables the kernels find their
-work by, and the dropless layer built on it.
+everything, edges inside a tile) or its walk over groups (blocks of the
+weights fetched a group ahead past empty groups, an accumulator written
+by a group's first visit and cast by its last), the tables the kernels
+find their work by, and the dropless layer built on it.
 
 That the kernels compile for the v5e is in
 ``tests/test_flash_compiles_for_v5e.py`` (one file describes the chip);
@@ -13,12 +15,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.ops import grouped_matmul as gm
 from paddle_tpu.ops.grouped_matmul import (
-    Tiling, _gmm, _tgmm, _visits, gmm_tiling, grouped_matmul,
-    grouped_matmul_reference, tgmm_tiling)
+    Tiling, _gmm, _next_with_rows, _tgmm, _visits, gmm_tiling,
+    grouped_matmul, grouped_matmul_reference, tgmm_tiling)
 
-# (rows, k, n, group sizes): rows tile by 128 unless no power of two
-# down to 128 divides them, then the whole axis is one tile
+# (rows, k, n, group sizes): rows tile by 128 (by 256 in the weight
+# gradient where that divides them) unless no power of two down to 128
+# divides them, then the whole axis is one tile
 LAYOUTS = {
     "edges-inside-tiles": (256, 128, 128, [100, 56, 100]),
     "empty-groups": (256, 128, 128, [100, 0, 56, 0, 100]),
@@ -28,6 +32,16 @@ LAYOUTS = {
     "one-row-groups": (384, 256, 128, [1, 127, 130, 0, 125, 1]),
     "whole-axis-one-tile": (48, 32, 16, [10, 0, 38]),
     "many-groups-one-tile": (128, 128, 128, [8] * 16),
+    # a weight-gradient visit that is its group's first and last at once,
+    # beside two groups that share a tile
+    "one-visit-groups": (512, 128, 128, [256, 128, 128]),
+    # the ledger's load peak of 2.67 (PR 26): one group of two thirds of
+    # the rows (a first, middle and last visit of 256), groups of 40 or
+    # fewer beside it, two of them inside one tile
+    "ledger-skew": (1024, 128, 128, [683, 40, 24, 36, 100, 141]),
+    # the fetch ahead runs past empty groups: first, last, two in a row
+    "empty-first-last-and-two-in-a-row": (512, 128, 128,
+                                          [0, 100, 0, 0, 156, 256, 0]),
 }
 
 
@@ -59,7 +73,7 @@ def test_forward_and_both_backward_forms_float32(layout):
 
 
 @pytest.mark.parametrize("layout", ["edges-inside-tiles", "empty-groups",
-                                    "one-group-holds-all"])
+                                    "one-group-holds-all", "ledger-skew"])
 def test_bfloat16_operands_accumulate_in_float32(layout):
     got, ref = _both(*_case(*LAYOUTS[layout], jnp.bfloat16))
     for g, r, name in zip(got, ref, ("out", "dx", "dw")):
@@ -71,25 +85,49 @@ def test_bfloat16_operands_accumulate_in_float32(layout):
         assert err <= 1e-2 * scale, (name, err, scale)
 
 
-def test_an_empty_group_gets_a_zero_weight_gradient_not_garbage():
-    x, w, dy, sizes = _case(*LAYOUTS["empty-first-and-last"], jnp.float32)
+@pytest.mark.parametrize("layout", ["empty-first-and-last",
+                                    "empty-first-last-and-two-in-a-row"])
+def test_an_empty_group_gets_a_zero_weight_gradient_not_garbage(layout):
+    x, w, dy, sizes = _case(*LAYOUTS[layout], jnp.float32)
     dw = _tgmm(x, dy, sizes, None, True)
-    assert float(jnp.abs(dw[0]).max()) == 0.0
-    assert float(jnp.abs(dw[3]).max()) == 0.0
-    assert float(jnp.abs(dw[1]).max()) > 0.0
+    for g, rows in enumerate(LAYOUTS[layout][3]):
+        assert (float(jnp.abs(dw[g]).max()) > 0.0) == (rows > 0), g
 
 
-@pytest.mark.parametrize("tiling", [Tiling(128, 64, 128), Tiling(128, 128, 64),
-                                    Tiling(256, 128, 128)])
-def test_other_tilings_give_the_same_result(tiling):
-    """The contraction in steps (an accumulator across grid steps), the
-    output in column blocks, a taller row tile: same numbers."""
-    x, w, dy, sizes = _case(256, 128, 128, [100, 0, 56, 100], jnp.float32)
-    want = _gmm(x, w, sizes, False, None, True)
-    assert jnp.allclose(_gmm(x, w, sizes, False, tiling, True), want,
-                        rtol=1e-5, atol=1e-4)
+@pytest.mark.parametrize("tiling", [None, Tiling(128, 128, 128)],
+                         ids=["visits-of-256", "visits-of-128"])
+@pytest.mark.parametrize("layout", ["ledger-skew", "one-visit-groups"])
+def test_the_bfloat16_weight_gradient_is_the_float32_sum_rounded_once(
+        layout, tiling):
+    """A group's first visit writes the accumulator, its last casts
+    accumulator plus product into the output: no visit may round to
+    bfloat16 on the way."""
+    x, w, dy, sizes = _case(*LAYOUTS[layout], jnp.bfloat16)
+    got = _tgmm(x, dy, sizes, tiling, True)
+    exact = _tgmm(x.astype(jnp.float32), dy.astype(jnp.float32), sizes,
+                  tiling, True)
+    assert got.dtype == jnp.bfloat16 and exact.dtype == jnp.float32
+    assert jnp.array_equal(got, exact.astype(jnp.bfloat16))
+
+
+@pytest.mark.parametrize("tilings", [
+    (Tiling(128, 128, 32), Tiling(128, 64, 128)),
+    (Tiling(128, 128, 64), Tiling(128, 128, 64)),
+    (Tiling(256, 128, 128), Tiling(256, 128, 128)),
+], ids=["contraction-in-steps", "column-blocks", "taller-tile"])
+def test_other_tilings_give_the_same_result(tilings):
+    """The output in column blocks (``moe_gmm`` carries its ring of
+    weight blocks from one column block into the next, past the empty
+    groups), the weight gradient's contraction in steps, a taller row
+    tile: same numbers."""
+    x, w, dy, sizes = _case(*LAYOUTS["empty-first-last-and-two-in-a-row"],
+                            jnp.float32)
+    for transpose_w, rows in ((False, x), (True, dy)):
+        want = _gmm(rows, w, sizes, transpose_w, None, True)
+        got = _gmm(rows, w, sizes, transpose_w, tilings[0], True)
+        assert jnp.allclose(got, want, rtol=1e-5, atol=1e-4), transpose_w
     want = _tgmm(x, dy, sizes, None, True)
-    assert jnp.allclose(_tgmm(x, dy, sizes, tiling, True), want,
+    assert jnp.allclose(_tgmm(x, dy, sizes, tilings[1], True), want,
                         rtol=1e-5, atol=1e-4)
 
 
@@ -110,19 +148,43 @@ def test_visit_tables():
     assert seen == sorted(seen)
     # never more visits than the tables hold
     assert n <= 256 // 128 + 4 - 1
+    # visits of two tiles are counted from the group's first tile: the 683
+    # rows' six tiles make three visits, and the fourth group's 36 rows,
+    # which straddle tiles 5 and 6, one (tiles of 256 aligned to the
+    # array would give it two)
+    skew = jnp.asarray(LAYOUTS["ledger-skew"][3], jnp.int32)
+    (_, gid, tid), count = _visits(skew, 1024, 128, visit_empty=True, parts=2)
+    n = int(count)
+    assert gid[:n].tolist() == [0, 0, 0, 1, 2, 3, 4, 5]
+    assert tid[:n].tolist() == [0, 2, 4, 5, 5, 5, 6, 6]
+    # the order the weights' blocks are fetched in: from each group to the
+    # next that has rows (4: none), the last entry the first such group
+    assert _next_with_rows(sizes).tolist() == [2, 2, 3, 4, 0]
+    empties = jnp.asarray([0, 100, 0, 0, 156, 256, 0], jnp.int32)
+    assert _next_with_rows(empties).tolist() == [1, 4, 4, 4, 5, 7, 7, 1]
+    assert _next_with_rows(jnp.zeros((3,), jnp.int32)).tolist() == [3] * 4
 
 
-def test_the_cells_tilings_hold_a_groups_matrix_block_between_visits():
-    # at the benchmark cell's shapes the contraction is one step: the
-    # block of an expert's matrix is the same for consecutive visits of
-    # that expert and is fetched once
+def test_the_cells_tilings_hold_a_groups_matrix_block_for_all_its_visits():
+    # at the benchmark cell's shapes the contraction is whole and so is
+    # the block: an expert's matrix is fetched once, a group ahead, into
+    # one of the ring's slots, and every visit of the group multiplies by
+    # it; ring, accumulator and the double-buffered tiles fit the VMEM
+    # the kernels ask for
     for k, n in ((2048, 4096), (2048, 2048)):
-        t = gmm_tiling(4096, k, n, 2)
-        assert t.tm == 128 and t.tk == k and n % t.tn == 0
-        t = gmm_tiling(4096, n, k, 2)          # the input gradient's
-        assert t.tk == n
+        for kk, nn in ((k, n), (n, k)):        # forward, input gradient
+            t = gmm_tiling(4096, kk, nn, 2)
+            assert t.tm == 128 and t.tk == kk and t.tn == nn
+            ring = gm._W_SLOTS * 2 * t.tk * t.tn
+            tiles = 2 * 2 * t.tm * (t.tk + t.tn)
+            assert ring + tiles + 4 * t.tm * t.tn <= gm._VMEM_LIMIT
         t = tgmm_tiling(4096, k, n, 2)
-        assert 4 * t.tk * t.tn <= 8 * 1024 * 1024   # the accumulator
+        acc, block = 4 * t.tk * t.tn, 2 * t.tk * t.tn
+        assert t.tm == 256 and block <= gm._DW_BLOCK_BYTES
+        rows = 2 * 2 * t.tm * (t.tk + t.tn)
+        assert acc + 2 * block + rows <= gm._VMEM_LIMIT
+    # float32 operands: the same bytes a block, half the columns
+    assert tgmm_tiling(1024, 2048, 4096, 4).tn == 1024
 
 
 def test_no_gradient_reaches_the_group_sizes_and_jit_composes():
