@@ -11,13 +11,12 @@ seed, in ONE process on ONE chip:
            ``jax.numpy`` reference: flash fwd/dq/dkv (D = 64/128/256,
            GQA, Sk=77 cross-attention), jax's paged decode kernel
            (ratio 1 with 32 and 64 pages per compute block, ratio 8
-           with 8, bf16 and int8 pools), fused AdamW (f32/bf16 moments,
-           SR on/off, the found-inf skip) plus one ``AdamW(fused=True)``
-           train step; the grouped matmul (forward, input and weight
-           gradient, uneven groups with an empty one) and one ZAYA1
-           block at its published widths against its float32 reference;
+           with 8, bf16 and int8 pools); the grouped matmul (forward,
+           input and weight gradient, uneven groups with an empty one)
+           and one ZAYA1 block at its published widths against its
+           float32 reference;
   trainer  ``jit.to_static(step, layers=[model], optimizers=[opt])`` with
-           the bench's optimizer settings (bf16 params, masterless
+           the cells' optimizer settings (bf16 params, masterless
            stochastic rounding, bf16 moments), 2 layers, B x S = 1 x 2048:
            loss finite, first loss ~ ln(vocab), loss falls, flash
            fwd/dq/dkv in the program that ran, 0 compilations in the
@@ -161,11 +160,10 @@ def memory_line(tag: str) -> dict:
 
 
 # the Mosaic kernel_name of each of the package's own kernels: the ``name``
-# its pl.pallas_call carries (ops/flash_attention.py, ops/fused_adamw.py;
-# tests/test_pallas_kernel_names.py lowers them for the TPU and holds the
-# two together)
+# its pl.pallas_call carries (tests/test_pallas_kernel_names.py lowers them
+# for the TPU and holds the two together)
+# ops/flash_attention.py
 FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
-ADAMW_KERNELS = ("fused_adamw",)
 MOE_KERNELS = ("moe_gmm", "moe_tgmm")   # ops/grouped_matmul.py
 
 
@@ -227,7 +225,6 @@ def phase_env(cache_dir: str) -> None:
 
     import paddle_tpu as paddle
     from paddle_tpu.ops import flash_attention as fa
-    from paddle_tpu.ops import fused_adamw as fw
 
     dev = jax.devices()[0]
     try:
@@ -238,15 +235,15 @@ def phase_env(cache_dir: str) -> None:
         f"jax={jax.__version__} jaxlib={jaxlib.__version__} libtpu={libtpu}")
     say(f"compile_cache_dir={cache_dir}")
     say(f"paddle.get_device()={paddle.get_device()}")
-    interpret = (fa._interpret_default(), fw._interpret_default())
-    say(f"interpret flash={interpret[0]} fused_adamw={interpret[1]}")
+    interpret = fa._interpret_default()
+    say(f"interpret flash={interpret}")
     if PLATFORM == "tpu":
         from paddle_tpu.device.peaks import chip_peaks
 
         chip_peaks(dev)  # unknown device_kind = error
         check(paddle.get_device() == "tpu:0",
               f"paddle.get_device() is {paddle.get_device()!r}, not 'tpu:0'")
-        check(interpret == (False, False),
+        check(not interpret,
               "Pallas kernels default to interpret mode on the chip")
 
 
@@ -468,99 +465,11 @@ def kernel_zaya_block(tiny: bool):
            f"{tag} out (the reference following the program's routing)")
 
 
-def kernel_adamw(p_dtype, m_dtype, sr, shape=(2048, 1280)):
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.ops.fused_adamw import (
-        assert_matches_reference,
-        fused_adamw_update,
-        reference_update,
-    )
-
-    rng = np.random.RandomState(3)
-    p = jnp.asarray(rng.randn(*shape), p_dtype)
-    g = jnp.asarray(0.1 * rng.randn(*shape), p_dtype)
-    m = jnp.asarray(0.01 * rng.randn(*shape), m_dtype)
-    v = jnp.asarray(0.01 * rng.rand(*shape), m_dtype)
-    salts = jnp.asarray([0xDEADBEEF, 0x12345678], jnp.uint32) if sr else None
-    hyper = dict(lr=1e-2, beta1=0.9, beta2=0.999, epsilon=1e-8,
-                 beta1_pow=jnp.asarray(0.9 ** 3, jnp.float32),
-                 beta2_pow=jnp.asarray(0.999 ** 3, jnp.float32),
-                 weight_decay=0.01)
-    tag = (f"fused_adamw p={jnp.dtype(p_dtype).name} "
-           f"m={jnp.dtype(m_dtype).name} sr={sr}")
-
-    def kernel(p, g, m, v, skip):
-        return fused_adamw_update(p, g, m, v, sr_salts=salts, skip=skip,
-                                  **hyper)
-
-    run = compiled_with_kernels(kernel, (p, g, m, v, jnp.asarray(False)),
-                                ADAMW_KERNELS, tag)
-    got = run(p, g, m, v, jnp.asarray(False))
-    ref = jax.jit(functools.partial(
-        reference_update, sr_salts=salts, **hyper))(p, g, m, v)
-    # one f32 rounding of an intermediate term: what FMA contraction
-    # alone can do (CPU: XLA vs the interpreter reach 1.00 of it; on the
-    # v5e Mosaic vs XLA:TPU measured under 0.04, PR 21). A wrong beta,
-    # epsilon or op order is thousands of times the bound.
-    worst = assert_matches_reference(got, ref, (p, g, m, v), **hyper)
-    # found-inf veto: every output is its input, bit for bit
-    vetoed = run(p, g, m, v, jnp.asarray(True))
-    for out, src, name in zip(vetoed, (p, m, v), "pmv"):
-        check(bool((np.asarray(out).view(np.uint8)
-                    == np.asarray(src).view(np.uint8)).all()),
-              f"{tag}: skip changed {name}")
-    say(f"{tag} worst_err={worst:.2f} of the one-rounding bound; "
-        "skip veto exact")
-
-
-def kernel_adamw_train_step():
-    """``AdamW(fused=True)`` through the optimizer and ``to_static``: it
-    may not be a path that only interprets, nor quietly the reference."""
-    import paddle_tpu as paddle
-    import paddle_tpu.nn as nn
-    import paddle_tpu.nn.functional as F
-    import paddle_tpu.optimizer as popt
-
-    paddle.seed(0)
-    model = nn.Sequential(nn.Linear(256, 512), nn.GELU(), nn.Linear(512, 64))
-    model.bfloat16()
-    opt = popt.AdamW(learning_rate=1e-2, parameters=model.parameters(),
-                     use_stochastic_rounding=True, moment_dtype="bfloat16",
-                     fused=True)
-
-    def fused_step(x, y):
-        loss = F.cross_entropy(model(x), y)
-        loss.backward()
-        opt.step()
-        opt.clear_grad()
-        return loss
-
-    step = paddle.jit.to_static(fused_step, layers=[model], optimizers=[opt])
-    rng = np.random.RandomState(0)
-    x = paddle.to_tensor(rng.randn(32, 256).astype("float32")).astype("bfloat16")
-    y = paddle.to_tensor(rng.randint(0, 64, (32,)).astype("int64"))
-    losses = [float(step(x, y)) for _ in range(4)]
-    say(f"fused_adamw train step losses={[round(v, 4) for v in losses]}")
-    check(all(math.isfinite(v) for v in losses), "fused AdamW: loss not finite")
-    check(losses[-1] < losses[0], "fused AdamW: loss did not fall")
-    require_kernels(lambda: program_that_ran("pure"), ADAMW_KERNELS,
-                    "fused_adamw train step")
-
-
 def phase_kernels(tiny: bool) -> None:
-    import jax.numpy as jnp
-
-    bf16, f32 = jnp.bfloat16, jnp.float32
     if tiny:  # interpreted kernels at the smallest legal tiles
         cases = [("flash", kernel_flash, (128, 128, 2, 1, 64, True)),
                  ("moe-gmm", kernel_grouped_matmul, (256, 128, 128, 4)),
-                 ("zaya-block", kernel_zaya_block, (True,)),
-                 ("adamw", kernel_adamw, (bf16, bf16, True, (40, 130))),
-                 ("adamw-step", kernel_adamw_train_step, ())]
+                 ("zaya-block", kernel_zaya_block, (True,))]
     else:
         cases = [
             # the train step's shape, then the other head dims
@@ -586,12 +495,6 @@ def phase_kernels(tiny: bool) -> None:
             ("paged-r1-p32-int8", kernel_paged, (8, 8, 32, 32, True)),
             ("paged-r1-p64-int8", kernel_paged, (8, 8, 64, 64, True)),
             ("paged-r8-p8-int8", kernel_paged, (16, 2, 32, 8, True)),
-            ("adamw-f32", kernel_adamw, (f32, f32, False)),
-            ("adamw-bf16-mf32", kernel_adamw, (bf16, f32, False)),
-            ("adamw-bf16-mbf16", kernel_adamw, (bf16, bf16, False)),
-            ("adamw-bf16-mf32-sr", kernel_adamw, (bf16, f32, True)),
-            ("adamw-bf16-mbf16-sr", kernel_adamw, (bf16, bf16, True)),
-            ("adamw-step", kernel_adamw_train_step, ()),
         ]
     for name, fn, args in cases:
         run_phase(f"kernels/{name}", fn, *args)
@@ -603,8 +506,9 @@ def phase_kernels(tiny: bool) -> None:
 
 
 def build_train_step(model):
-    """The bench's train step and optimizer settings (bench.py): bf16
-    params, masterless stochastic rounding, bf16 moments."""
+    """The cells' train step and optimizer settings
+    (chipbench/configs/*.json ``optimizer``): bf16 params, masterless
+    stochastic rounding, bf16 moments."""
     import paddle_tpu as paddle
     import paddle_tpu.nn.functional as F
     import paddle_tpu.optimizer as popt

@@ -72,13 +72,6 @@ class AmpScaler:
         self._last_skip_step = -1
         self._n_updates = 0
         self._on_skip = on_skip
-        # fused-interleaved support: scale()-time snapshots of each
-        # attached optimizer's params+accums, keyed by id(optimizer)
-        # — the rollback target for layers whose fused update landed
-        # BEFORE a later layer's grad revealed the inf (layers after
-        # detection are vetoed in-kernel and never written at all)
-        self._interleave_snaps: Dict[int, tuple] = {}
-        self._interleaved_opts: Dict[int, object] = {}
 
     # ------------------------------------------------------------------
     def is_enable(self) -> bool:
@@ -89,75 +82,12 @@ class AmpScaler:
     def is_use_dynamic_loss_scaling(self) -> bool:
         return self._use_dynamic_loss_scaling
 
-    def _attach_or_refuse_interleaved(self, when: str):
-        """Interleaved optimizers apply updates DURING backward — on
-        grads that are still scaled. By the time step() could object,
-        params and Adam moments are already corrupted, so this must
-        fire BEFORE backward ever runs: here, on the pre-backward
-        surfaces (scale / unscale_). The check is deliberately
-        PROCESS-GLOBAL (scale() cannot see which params the loss
-        reaches).
-
-        FUSED interleaved optimizers (AdamW(fused=True)) are the
-        exception: the single-pass kernel takes a found-inf veto that
-        is read in SMEM before any tile is written, so the scaler can
-        drive them safely — each finalized grad is unscaled per-layer
-        (_interleave_unscale) and the running found flag vetoes every
-        fused update from the first bad layer on; layers updated
-        before detection roll back at step() against the snapshot
-        taken here. Everything else still refuses: a non-fused
-        interleaved update has no pre-write veto point."""
-        from ..base import tape as _tape
-
-        if not _tape._interleave_registry:
-            return
-        opts = {}
-        for pref, oref in list(_tape._interleave_registry.values()):
-            o = oref()
-            if o is not None:
-                opts[id(o)] = o
-        for opt in opts.values():
-            if not getattr(opt, "_fused", False):
-                raise ValueError(
-                    "GradScaler cannot drive an interleave_updates "
-                    f"optimizer ({when}): interleaved updates would fire "
-                    "during backward on SCALED grads, before unscale_/"
-                    "inf-skip can run — construct the optimizer without "
-                    "interleave_updates when using a GradScaler, or "
-                    "with fused=True (the fused kernel takes a "
-                    "found-inf veto, which makes scaling safe)")
-        for opt in opts.values():
-            # attachment lasts one scale()→update() cycle: update()
-            # detaches, so a later scaler-less backward runs the plain
-            # interleaved path instead of unscaling unscaled grads
-            opt._interleave_scaler = self
-            self._interleaved_opts[id(opt)] = opt
-            if id(opt) not in self._interleave_snaps:
-                self._interleave_snaps[id(opt)] = self._snapshot(opt)
-                opt._accum_creation_log = {}
-
     # ------------------------------------------------------------------
     def scale(self, var):
         """Multiply the loss by the current scale (ref: grad_scaler.py scale)."""
         if not self._enable:
             return var
-        self._attach_or_refuse_interleaved(
-            "refused at scale(), before backward")
         return var * Tensor(self._scale.astype(var._data.dtype), _internal=True)
-
-    @no_grad()
-    def _interleave_unscale(self, g):
-        """Per-layer unscale for the fused interleaved path: called by
-        Optimizer._interleave_apply the moment a grad finalizes during
-        backward. ORs this grad's finiteness into the running
-        found_inf and returns (unscaled grad, veto flag) — the flag
-        covers every layer finalized SO FAR, so the fused kernel skips
-        all writes from the first bad layer onward."""
-        if np.dtype(g.dtype).kind in "fc":
-            self._found_inf = self._found_inf | ~jnp.all(jnp.isfinite(g))
-            inv_scale = 1.0 / self._scale
-            g = (g.astype(jnp.float32) * inv_scale).astype(g.dtype)
-        return g, self._found_inf
 
     # ------------------------------------------------------------------
     def _params_with_grads(self, optimizer):
@@ -172,9 +102,6 @@ class AmpScaler:
         (check_finite_and_unscale semantics, traceable)."""
         if not self._enable:
             return
-        if (getattr(optimizer, "_interleave", False)
-                and getattr(optimizer, "_interleave_scaler", None) is not self):
-            self._attach_or_refuse_interleaved("refused at unscale_()")
         state = self._opt_states.get(id(optimizer), OptimizerState.INIT)
         if state is OptimizerState.UNSCALED:
             raise RuntimeError("unscale_() has already been called on this optimizer since the last update()")
@@ -189,10 +116,8 @@ class AmpScaler:
             if np.dtype(g.dtype).kind in "fc":
                 found = found | ~jnp.all(jnp.isfinite(g))
                 p._grad._data = (g.astype(jnp.float32) * inv_scale).astype(g.dtype)
-        # OR, not overwrite: the fused interleaved path may already
-        # have accumulated found-inf from per-layer unscales during
-        # backward (and a second optimizer's unscale_ must not erase
-        # the first's verdict)
+        # OR, not overwrite: a second optimizer's unscale_ must not
+        # erase the first's verdict
         self._found_inf = self._found_inf | found
         self._opt_states[id(optimizer)] = OptimizerState.UNSCALED
 
@@ -220,15 +145,6 @@ class AmpScaler:
     def step(self, optimizer):
         """Unscale (if needed) then step, skipping the update when inf/nan
         grads were found (ref: grad_scaler.py step)."""
-        if getattr(optimizer, "_interleave", False):
-            if getattr(optimizer, "_interleave_scaler", None) is self:
-                return self._step_interleaved(optimizer)
-            raise ValueError(
-                "GradScaler cannot drive an interleave_updates "
-                "optimizer: updates fire during backward with SCALED "
-                "grads, before unscale_/inf-skip can run — construct "
-                "it with fused=True to enable the kernel-level "
-                "found-inf veto")
         if not self._enable:
             optimizer.step()
             return
@@ -243,35 +159,6 @@ class AmpScaler:
         try:
             optimizer.step()
             self._rollback_where_inf(optimizer, *snap, optimizer._accum_creation_log)
-        finally:
-            optimizer._accum_creation_log = None
-        self._opt_states[id(optimizer)] = OptimizerState.STEPPED
-
-    def _step_interleaved(self, optimizer):
-        """step() for a fused interleaved optimizer the scaler attached
-        at scale() time. Most params were already updated during
-        backward (per-layer unscale + in-kernel veto from the first
-        bad layer on); here: unscale any leftover grads (params whose
-        grad never finalized interleaved), run the residual step, then
-        roll back everything the GLOBAL found_inf invalidates against
-        the scale()-time snapshot — layers updated before the inf was
-        detected come back bitwise."""
-        if not self._enable:
-            optimizer.step()
-            return
-        state = self._opt_states.get(id(optimizer), OptimizerState.INIT)
-        if state is OptimizerState.STEPPED:
-            raise RuntimeError("step() has already been called since the last update()")
-        if state is OptimizerState.INIT:
-            self.unscale_(optimizer)
-        snap = self._interleave_snaps.pop(id(optimizer), None)
-        if snap is None:  # scale() never saw this optimizer attached
-            snap = self._snapshot(optimizer)
-            optimizer._accum_creation_log = optimizer._accum_creation_log or {}
-        creation_log = optimizer._accum_creation_log
-        try:
-            optimizer.step()
-            self._rollback_where_inf(optimizer, *snap, creation_log or {})
         finally:
             optimizer._accum_creation_log = None
         self._opt_states[id(optimizer)] = OptimizerState.STEPPED
@@ -311,11 +198,6 @@ class AmpScaler:
             self._bad_steps = bad
         self._found_inf = jnp.asarray(False)
         self._opt_states.clear()
-        self._interleave_snaps.clear()
-        for opt in self._interleaved_opts.values():
-            if getattr(opt, "_interleave_scaler", None) is self:
-                opt._interleave_scaler = None
-        self._interleaved_opts.clear()
 
     def minimize(self, optimizer, *args, **kwargs):
         """step + update in one call (ref: AmpScaler.minimize)."""

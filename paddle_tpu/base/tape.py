@@ -276,38 +276,6 @@ def _collect_reachable(roots) -> Dict[int, TapeNode]:
     return nodes
 
 
-# -- interleaved optimizer updates -------------------------------------
-# Params registered here get their optimizer update applied the moment
-# their gradient FINALIZES during run_backward (all contributions
-# accumulated), instead of in a serial opt.step() tail after backward.
-# Inside a traced train step this interleaves the HBM-bound update ops
-# with the remaining backward layers in the jaxpr — the basis of the
-# fused-optimizer-into-backward schedule (see optimizer.AdamW
-# interleave_updates; ref: the reference fuses the same tail into a
-# single kernel, paddle/phi/kernels/gpu/adamw_kernel.cu).
-import weakref as _weakref
-
-_interleave_registry: Dict[int, Any] = {}  # id(param) -> (wref, opt wref)
-
-
-def register_interleaved_param(param, opt) -> None:
-    key = id(param)
-    _interleave_registry[key] = (
-        _weakref.ref(param, lambda _: _interleave_registry.pop(key, None)),
-        _weakref.ref(opt),
-    )
-
-
-def unregister_interleaved_params(params) -> None:
-    """Drop interleave ownership of ``params``. Called by Optimizer
-    __init__ for every new optimizer: constructing a replacement
-    optimizer over the same parameters must strip a previous
-    interleaving optimizer's hooks, or the abandoned optimizer would
-    keep applying its updates on every backward."""
-    for p in params:
-        _interleave_registry.pop(id(p), None)
-
-
 def run_backward(
     tensors: Sequence,
     grad_tensors: Optional[Sequence] = None,
@@ -344,37 +312,11 @@ def run_backward(
     # grads for explicitly requested inputs (paddle.grad)
     want: Dict[int, Any] = {}
     want_ids = {id(t) for t in inputs} if inputs is not None else set()
-    # interleaved updates: outstanding grad contributions per registered
-    # leaf; when a leaf's count hits 0 its update fires immediately.
-    # Only for loss.backward() semantics (not paddle.grad/double grad).
-    _pending: Dict[int, int] = {}
-    _interleave_on = bool(
-        _interleave_registry) and inputs is None and not create_graph
-
-    def _interleave_dec(t):
-        if not _interleave_on:
-            return
-        k = id(t)
-        if k not in _pending:
-            return
-        _pending[k] -= 1
-        if _pending[k] > 0:
-            return
-        del _pending[k]
-        ref = _interleave_registry.get(k)
-        if ref is None:
-            return
-        param, opt = ref[0](), ref[1]()
-        if param is not None and opt is not None:
-            opt._interleave_apply(param)
 
     def _accumulate(t: Tensor, g: Tensor):
         if g is None or (
             isinstance(g, np.ndarray) and g.dtype == jax.dtypes.float0
         ):
-            if (isinstance(t, Tensor) and t._grad_node is None
-                    and not t.stop_gradient):
-                _interleave_dec(t)
             return
         if not isinstance(g, Tensor):
             g = Tensor(g, stop_gradient=not create_graph, _internal=True)
@@ -404,7 +346,6 @@ def run_backward(
         elif inputs is None and not t.stop_gradient:
             # leaf accumulation (GradNodeAccumulation parity)
             t._grad = g if t._grad is None else t._grad + g
-            _interleave_dec(t)
 
     with set_grad_enabled(create_graph):
         for t, g in zip(tensors, grad_tensors):
@@ -422,13 +363,6 @@ def run_backward(
             _accumulate(t, g if isinstance(g, Tensor) else Tensor(g, _internal=True))
 
         nodes = _collect_reachable(tensors)
-        if _interleave_on:
-            for node in nodes.values():
-                for inp in node.inputs:
-                    if (isinstance(inp, Tensor) and inp._grad_node is None
-                            and not inp.stop_gradient
-                            and id(inp) in _interleave_registry):
-                        _pending[id(inp)] = _pending.get(id(inp), 0) + 1
         for node in sorted(nodes.values(), key=lambda n: n.id, reverse=True):
             out_cots = []
             any_seeded = False
@@ -442,12 +376,6 @@ def run_backward(
             if not any_seeded:
                 # dead branch not on the path from roots: its inputs
                 # will never receive a contribution from this node
-                if _interleave_on:
-                    for inp in node.inputs:
-                        if (isinstance(inp, Tensor)
-                                and inp._grad_node is None
-                                and not inp.stop_gradient):
-                            _interleave_dec(inp)
                 continue
             if node.vjp_fn is None:
                 raise RuntimeError(

@@ -9,8 +9,3 @@ from __future__ import annotations
 
 from .flash_attention import flash_attention as flash_attention_fused  # noqa: F401
 from .flash_attention import flash_attention_fwd  # noqa: F401
-from .fused_adamw import (  # noqa: F401
-    fused_adamw_hbm_bytes,
-    fused_adamw_update,
-    unfused_adamw_hbm_bytes,
-)

@@ -31,7 +31,7 @@ __all__ = [
 ]
 
 
-def _stochastic_round_bf16(x, salt=None):
+def _stochastic_round_bf16(x):
     """Unbiased f32 → bf16 rounding: add 16 random bits below the bf16
     mantissa cut, truncate. Sign-magnitude format makes the trick
     unbiased for both signs (|x| rounds up with probability equal to
@@ -42,20 +42,18 @@ def _stochastic_round_bf16(x, salt=None):
     inf/NaN pass through unperturbed.
 
     Bit source: a lowbias32-style integer hash over (lane index, two
-    per-call threefry salts) — measured ~10x cheaper inside the fused
-    optimizer pass than a full per-element threefry draw (which cost
-    more than the master traffic it replaced); rounding noise needs
-    per-element uniformity, not cryptographic streams. ``salt`` pins
-    the two salts (a (2,) uint32 array — kernel-parity references);
-    by default they are drawn from the global generator."""
+    per-call threefry salts drawn from the global generator) — measured
+    ~10x cheaper inside the fused optimizer pass than a full per-element
+    threefry draw (which cost more than the master traffic it
+    replaced); rounding noise needs per-element uniformity, not
+    cryptographic streams."""
     import jax
 
     from ..base import random as _random
 
     xf = x.astype(jnp.float32)
     u = jax.lax.bitcast_convert_type(xf, jnp.uint32)
-    if salt is None:
-        salt = jax.random.bits(_random.next_key(), (2,), jnp.uint32)
+    salt = jax.random.bits(_random.next_key(), (2,), jnp.uint32)
     i = jax.lax.iota(jnp.uint32, x.size).reshape(x.shape)
     b = i * jnp.uint32(0x9E3779B9) + salt[0]
     b = (b ^ (b >> 16)) * jnp.uint32(0x7FEB352D)
@@ -126,23 +124,6 @@ class Optimizer:
         # rounding (subclasses expose use_stochastic_rounding=True)
         self._stochastic_rounding = False
         self._global_step = 0
-        # interleaved updates (subclasses expose interleave_updates=True):
-        # the tape applies each param's update the moment its gradient
-        # finalizes during backward — see _enable_interleaving
-        self._interleave = False
-        self._interleave_applied = set()  # params updated this cycle
-        # amp.GradScaler attach point for the FUSED interleaved path:
-        # when set, _interleave_apply routes each finalized grad
-        # through the scaler (per-layer unscale + found-inf veto)
-        # before the fused kernel writes any tile
-        self._interleave_scaler = None
-        self._fused_skip = None  # traced found-inf veto for this layer
-        # a NEW optimizer over these params takes ownership: strip any
-        # previous interleaving optimizer's hooks or the abandoned one
-        # would keep training the model on every backward
-        from ..base import tape as _tape
-
-        _tape.unregister_interleaved_params(self._parameter_list)
 
     # ------------------------------------------------------------------
     def _normalize_params(self, parameters):
@@ -225,74 +206,6 @@ class Optimizer:
         return store[param.name]
 
     # ------------------------------------------------------------------
-    # interleaved updates
-    # ------------------------------------------------------------------
-    def _enable_interleaving(self):
-        """Register every parameter for update-at-grad-finalization
-        (tape.register_interleaved_param). The update math is identical
-        to step(); only its POSITION in the traced program moves — each
-        param's HBM-bound update lands right after its backward layer,
-        where the scheduler can hide it under the remaining MXU-bound
-        grads instead of a serial tail (round-4 verdict Next #4; the
-        reference's answer is a fused kernel,
-        ref: paddle/phi/kernels/gpu/adamw_kernel.cu).
-
-        Scope: single param group, no grad clip and no optimizer-level
-        regularization (both need ALL grads before any update) — step()
-        still runs afterwards for the global-step counter and any param
-        whose grad never finalized."""
-        if len(self._param_groups) != 1:
-            raise ValueError(
-                "interleave_updates supports a single param group")
-        group = self._param_groups[0]
-        if (self._grad_clip is not None or self.regularization is not None
-                or group.get("grad_clip") is not None
-                or group.get("weight_decay") is not None):
-            raise ValueError(
-                "interleave_updates is incompatible with grad_clip/"
-                "weight_decay regularizers (they need all grads before "
-                "any update); use the optimizer's decoupled decay")
-        from ..base import tape as _tape
-
-        self._interleave = True
-        for p in self._param_groups[0]["params"]:
-            _tape.register_interleaved_param(p, self)
-
-    @no_grad()
-    def _interleave_apply(self, p):
-        g = p.grad
-        if g is None or p.stop_gradient:
-            return
-        if id(p) in self._interleave_applied:
-            raise RuntimeError(
-                "interleave_updates: a second backward() reached "
-                f"parameter {p.name!r} before step() — gradient "
-                "accumulation (multiple backwards per step) is "
-                "incompatible with interleaved updates; disable "
-                "interleave_updates for accumulation loops")
-        self._interleave_applied.add(id(p))
-        garr = g._data if isinstance(g, Tensor) else g
-        if self._grad_placement_fn is not None:
-            garr = self._grad_placement_fn(garr, p)
-        scaler = self._interleave_scaler
-        if scaler is not None and scaler.is_enable():
-            # scaler-driven fused path: unscale THIS layer's grad the
-            # moment it finalizes and carry the running found-inf flag
-            # into the kernel as the per-tile write veto
-            garr, self._fused_skip = scaler._interleave_unscale(garr)
-        group = self._param_groups[0]
-        lr_scale = (p.optimize_attr.get("learning_rate", 1.0)
-                    if getattr(p, "optimize_attr", None) else 1.0)
-        try:
-            self._update_param(
-                p, garr, lr_scale * float(group.get("learning_rate", 1.0)),
-                group)
-        finally:
-            self._fused_skip = None
-        # grad consumed: step() skips this param (grad is None there)
-        p.clear_grad()
-
-    # ------------------------------------------------------------------
     # step
     # ------------------------------------------------------------------
     @no_grad()
@@ -306,7 +219,6 @@ class Optimizer:
         # under every traced update and stays at 24 words (PERF.md §6)
         with jax.named_scope("optimizer.step"):
             self._global_step += 1
-            self._interleave_applied.clear()
             for group in self._param_groups:
                 params_grads = [
                     (p, p.grad) for p in group["params"] if not p.stop_gradient and p.grad is not None
@@ -540,23 +452,13 @@ class AdamW(_AdamBase):
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999, epsilon=1e-8, parameters=None,
                  weight_decay=0.01, lr_ratio=None, apply_decay_param_fun=None, grad_clip=None,
                  lazy_mode=False, multi_precision=False, name=None, moment_dtype=None,
-                 use_stochastic_rounding=False, interleave_updates=False,
-                 fused=False):
+                 use_stochastic_rounding=False):
         super().__init__(learning_rate, beta1, beta2, epsilon, parameters, None, grad_clip, lazy_mode, multi_precision, name,
                          moment_dtype=moment_dtype,
                          use_stochastic_rounding=use_stochastic_rounding)
         self._coeff = float(weight_decay) if not callable(weight_decay) else weight_decay
         self._lr_ratio = lr_ratio
         self._apply_decay_param_fun = apply_decay_param_fun
-        # fused=True routes each param update through the single-pass
-        # Pallas kernel (ops.fused_adamw): one streamed read of
-        # p/g/m/v, one write of p/m/v, SR writeback in-register — the
-        # same f32 math as this class's unfused path, one rounding
-        # apart at most (tested), so it is a drop-in backend, not a
-        # new optimizer
-        self._fused = bool(fused)
-        if interleave_updates:
-            self._enable_interleaving()
 
     def _decay_for(self, p):
         decay = self._coeff
@@ -566,23 +468,10 @@ class AdamW(_AdamBase):
             decay = 0.0
         return decay
 
-    def _fused_supported(self, p, g) -> bool:
-        # the kernel computes in f32: f64 params keep the reference
-        # path (reference compute promotes to f64 there); non-float
-        # grads (complex) likewise. issubdtype, not numpy's kind: numpy
-        # files bfloat16 under kind "V", which sent every bf16 gradient
-        # — the configuration the kernel was written for — to the
-        # reference path without a word (found on the chip, PR 21).
-        return (np.dtype(p._data.dtype) != np.dtype(np.float64)
-                and jnp.issubdtype(g.dtype, jnp.floating)
-                and not callable(self._coeff))
-
     def _update_param(self, p, g, lr_scale, group):
         lr = self._lr() * lr_scale
         if self._lr_ratio is not None:
             lr = lr * self._lr_ratio(p)
-        if self._fused and self._fused_supported(p, g):
-            return self._fused_update(p, g, lr)
         pv, g, m, v, b1p, b2p = self._moments(p, g)
         decay = self._decay_for(p)
         # decay in the f32 compute dtype: a bf16 pv * (1 - lr*decay)
@@ -593,52 +482,6 @@ class AdamW(_AdamBase):
         compute = jnp.float64 if pv.dtype == jnp.float64 else jnp.float32
         pv = pv.astype(compute) * (1.0 - lr * decay)
         self._apply(p, pv - self._adam_delta(lr, m, v, b1p, b2p))
-
-    def _fused_update(self, p, garr, lr):
-        """Single-pass kernel backend: same accumulator layout and
-        writeback modes as _moments/_apply (master weights, masterless
-        bf16 + SR, plain cast), so state_dict/jit threading see no
-        difference. ``self._fused_skip`` (set by the GradScaler's
-        interleaved hook) vetoes the whole update in-kernel before any
-        tile is written."""
-        import jax
-
-        from ..ops.fused_adamw import fused_adamw_update
-
-        pv = self._param_value(p)
-        compute = jnp.float32
-        m = self._get_accum("moment1", p, dtype=self._moment_dtype)
-        v = self._get_accum("moment2", p, dtype=self._moment_dtype)
-        b1p_old = self._get_accum("beta1_pow", p, init=jnp.ones((), compute))
-        b2p_old = self._get_accum("beta2_pow", p, init=jnp.ones((), compute))
-        b1p = b1p_old.astype(compute) * self._beta1
-        b2p = b2p_old.astype(compute) * self._beta2
-        use_master = self._use_master(p)
-        sr = (not use_master and self._stochastic_rounding
-              and p._data.dtype == jnp.bfloat16)
-        salts = None
-        if sr:
-            from ..base import random as _random
-
-            salts = jax.random.bits(_random.next_key(), (2,), jnp.uint32)
-        skip = self._fused_skip
-        new_p, m_new, v_new = fused_adamw_update(
-            pv, garr, m, v, lr=lr, beta1=self._beta1, beta2=self._beta2,
-            epsilon=self._epsilon, beta1_pow=b1p, beta2_pow=b2p,
-            weight_decay=self._decay_for(p), sr_salts=salts, skip=skip)
-        if skip is not None:
-            # vetoed layer: the beta powers must not advance either
-            b1p = jnp.where(skip, b1p_old.astype(compute), b1p)
-            b2p = jnp.where(skip, b2p_old.astype(compute), b2p)
-        self._set_accum("moment1", p, m_new)
-        self._set_accum("moment2", p, v_new)
-        self._set_accum("beta1_pow", p, b1p)
-        self._set_accum("beta2_pow", p, b2p)
-        if use_master:
-            self._accumulators["master_weight"][p.name] = new_p
-            p._data = new_p.astype(p._data.dtype)
-        else:
-            p._data = new_p
 
 
 class Adagrad(Optimizer):

@@ -1,6 +1,6 @@
 """Where the persistent XLA compile cache lives.
 
-Entry points (``chip_smoke.py``, ``bench.py``, ``benchmarks/*``) call
+Entry points (``chip_smoke.py``, ``chipbench``, ``benchmarks/*``) call
 :func:`enable_compile_cache` once before their first compilation. The
 directory is part of the cache key, so it must not move between runs:
 no temp name, pid or time in the path.

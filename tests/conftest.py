@@ -21,10 +21,12 @@ jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
 
-# Heavyweight files (multi-second jit compiles, model zoo, distributed
-# meshes, full parity scans). Everything else is marked `quick`:
-#   pytest -m quick   -> the <5-minute subset
-#   pytest -m slow    -> the rest (CI shard 2)
+# What the driver's lane leaves out: it runs `-m 'not slow'` over six
+# workers (`--dist loadfile`, a 1470 s limit), and every test of a file
+# named here is marked `slow` unless the test carries a marker of its
+# own. A file leaves this list when its tests guard a path a benchmark
+# cell runs and fit the lane (ROADMAP D14 has each file's seconds); a
+# single heavy test of a quick file is marked `slow` where it stands.
 _SLOW_FILES = {
     "test_advice_fixes.py",       # torch-parity ctc/grid_sample sweeps
     "test_auto_checkpoint.py",    # kill-and-relaunch subprocess
@@ -34,15 +36,12 @@ _SLOW_FILES = {
     "test_distributed.py",
     "test_distribution.py",       # 25 scipy-validated distributions
     "test_fft_sparse.py",
-    "test_flash_attention.py",
     "test_generation.py",
     "test_grad_sweep.py",
     "test_graft_entry.py",        # 8-device GSPMD + pipeline dryrun
-    "test_optimizer_training.py",
     "test_hapi_metric.py",
     "test_hybrid_parallel.py",
     "test_io.py",
-    "test_models_gpt_bert.py",
     "test_moe.py",
     "test_namespace_parity.py",
     "test_namespace_parity2.py",
@@ -55,7 +54,6 @@ _SLOW_FILES = {
     "test_store_rpc.py",          # spawns subprocesses
     "test_unet.py",
     "test_vision.py",
-    # round-5 rebalance (quick must stay < 5 min on a slow box):
     "test_sparse_nn.py",          # point-cloud training runs
     "test_multi_controller.py",   # spawns 2 jax.distributed processes
     "test_serving.py",            # continuous-batching vs generate()
@@ -83,9 +81,8 @@ def pytest_configure(config):
         "(standalone via `pytest -m analysis`, < 60 s)")
     config.addinivalue_line(
         "markers",
-        "kernels: Pallas kernel numerics lane — fused-AdamW parity/"
-        "HBM-model + fp8 GEMM quality gates, interpret-mode on CPU "
-        "(standalone via `pytest -m kernels`)")
+        "kernels: Pallas kernel numerics lane — fp8 GEMM quality gates, "
+        "interpret-mode on CPU (standalone via `pytest -m kernels`)")
     config.addinivalue_line(
         "markers",
         "robustness: overload-control / chaos / self-healing serving "

@@ -483,7 +483,10 @@ class TestCLI:
         store = FileKVStore(root)
         reg = MetricsRegistry()
         agg.publish(store, "rep-0", registry=reg)
-        r = _cli(["alerts", root])
+        # the rule's default age (5 s) is shorter than a subprocess can
+        # take to import jax beside five busy workers: 30 s keeps the
+        # healthy source healthy, and the silent one is 60 s old
+        r = _cli(["alerts", root, "--absence-age", "30"])
         assert r.returncode == 0, r.stdout + r.stderr
         # now a source whose last publication is a minute old
         state = reg.dump_state()
@@ -491,7 +494,7 @@ class TestCLI:
         state["published_unix"] = time.time() - 60.0
         store.put_bytes("obs/rep-1/metrics",
                         json.dumps(state, sort_keys=True).encode())
-        r = _cli(["alerts", root])
+        r = _cli(["alerts", root, "--absence-age", "30"])
         assert r.returncode == 1, r.stdout + r.stderr
         doc = json.loads(r.stdout)
         firing = [d for d in doc if d["state"] == "firing"]

@@ -356,143 +356,3 @@ class TestStochasticRounding:
         assert np.isfinite(l1) and l1 < l0
         # the threaded RNG state advanced (keys differ per call)
         assert m.weight._data.dtype == jnp.bfloat16
-
-
-class TestInterleavedUpdates:
-    """AdamW(interleave_updates=True): identical math to the serial
-    step() tail, moved to each param's grad-finalization point in
-    backward (round-4 verdict Next #4 — the fused-optimizer-into-
-    backward schedule)."""
-
-    @staticmethod
-    def _train(interleave, steps=25, shared=False):
-        import jax.numpy as jnp
-
-        paddle.seed(0)
-        if shared:
-            # one param consumed twice: the update must wait for BOTH
-            # grad contributions
-            lin = nn.Linear(8, 8)
-            head = nn.Linear(8, 3)
-            params = [*lin.parameters(), *head.parameters()]
-
-            def fwd(x):
-                return head(F.relu(lin(F.relu(lin(x)))))
-        else:
-            m = nn.Sequential(nn.Linear(8, 16), nn.ReLU(), nn.Linear(16, 3))
-            params = m.parameters()
-            fwd = m
-        o = opt.AdamW(learning_rate=1e-2, parameters=params,
-                      interleave_updates=interleave)
-        rng = np.random.RandomState(0)
-        x = paddle.to_tensor(rng.randn(16, 8).astype(np.float32))
-        y = paddle.to_tensor(rng.randint(0, 3, (16,)).astype(np.int64))
-        losses = []
-        for _ in range(steps):
-            loss = F.cross_entropy(fwd(x), y)
-            loss.backward()
-            o.step()
-            o.clear_grad()
-            losses.append(float(loss))
-        return losses, [np.asarray(p._data).copy() for p in params], o
-
-    def test_matches_serial_step_exactly(self):
-        l_serial, p_serial, o1 = self._train(False)
-        l_inter, p_inter, o2 = self._train(True)
-        np.testing.assert_allclose(l_inter, l_serial, rtol=1e-6)
-        for a, b in zip(p_serial, p_inter):
-            np.testing.assert_allclose(b, a, rtol=1e-6, atol=1e-7)
-        assert o1._global_step == o2._global_step
-
-    def test_shared_param_waits_for_all_contributions(self):
-        l_serial, p_serial, _ = self._train(False, shared=True)
-        l_inter, p_inter, _ = self._train(True, shared=True)
-        np.testing.assert_allclose(l_inter, l_serial, rtol=1e-6)
-        for a, b in zip(p_serial, p_inter):
-            np.testing.assert_allclose(b, a, rtol=1e-6, atol=1e-7)
-
-    def test_under_to_static_multi_step(self):
-        import jax.numpy as jnp
-
-        def build(interleave):
-            paddle.seed(1)
-            m = nn.Sequential(nn.Linear(8, 16), nn.ReLU(), nn.Linear(16, 3))
-            o = opt.AdamW(learning_rate=1e-2, parameters=m.parameters(),
-                          interleave_updates=interleave)
-
-            def step(x, y):
-                loss = F.cross_entropy(m(x), y)
-                loss.backward()
-                o.step()
-                o.clear_grad()
-                return loss
-
-            return paddle.jit.to_static(step, layers=[m], optimizers=[o]), m
-
-        rng = np.random.RandomState(2)
-        x = paddle.to_tensor(rng.randn(16, 8).astype(np.float32))
-        y = paddle.to_tensor(rng.randint(0, 3, (16,)).astype(np.int64))
-        sf_a, m_a = build(False)
-        sf_b, m_b = build(True)
-        la = [float(sf_a(x, y)) for _ in range(6)]
-        lb = [float(sf_b(x, y)) for _ in range(6)]
-        np.testing.assert_allclose(lb, la, rtol=1e-5)
-        la2 = float(np.asarray(sf_a.multi_step(x, y, steps=4)._data)[-1])
-        lb2 = float(np.asarray(sf_b.multi_step(x, y, steps=4)._data)[-1])
-        np.testing.assert_allclose(lb2, la2, rtol=1e-5)
-        for pa, pb in zip(m_a.parameters(), m_b.parameters()):
-            np.testing.assert_allclose(np.asarray(pb._data),
-                                       np.asarray(pa._data), rtol=1e-5,
-                                       atol=1e-6)
-
-    def test_incompatible_options_raise(self):
-        p = nn.Linear(2, 2).parameters()
-        with pytest.raises(ValueError, match="grad_clip"):
-            opt.AdamW(parameters=p, interleave_updates=True,
-                      grad_clip=nn.ClipGradByGlobalNorm(1.0))
-
-    def test_guards(self):
-        import paddle_tpu.amp as amp
-
-        # gradient accumulation: second backward before step() is loud
-        paddle.seed(4)
-        m = nn.Linear(4, 2)
-        o = opt.AdamW(learning_rate=1e-3, parameters=m.parameters(),
-                      interleave_updates=True)
-        x = paddle.to_tensor(np.random.RandomState(0)
-                             .randn(4, 4).astype(np.float32))
-        (m(x) ** 2).mean().backward()
-        with pytest.raises(RuntimeError, match="second backward"):
-            (m(x) ** 2).mean().backward()
-        o.step()
-        o.clear_grad()
-
-        # GradScaler refuses interleaved optimizers
-        scaler = amp.GradScaler(init_loss_scaling=2.0)
-        with pytest.raises(ValueError, match="interleave_updates"):
-            scaler.step(o)
-
-        # group-dict weight_decay rejected
-        with pytest.raises(ValueError, match="grad_clip/weight_decay"):
-            opt.AdamW(parameters=[{"params": nn.Linear(2, 2).parameters(),
-                                   "weight_decay": 0.01}],
-                      interleave_updates=True)
-
-    def test_new_optimizer_takes_ownership(self):
-        """Replacing an interleaving optimizer must strip its hooks —
-        the abandoned optimizer must not keep training the model."""
-        paddle.seed(5)
-        m = nn.Linear(4, 2)
-        o1 = opt.AdamW(learning_rate=1e-2, parameters=m.parameters(),
-                       interleave_updates=True)
-        o2 = opt.SGD(learning_rate=0.1, parameters=m.parameters())
-        x = paddle.to_tensor(np.random.RandomState(1)
-                             .randn(4, 4).astype(np.float32))
-        before = np.asarray(m.weight._data).copy()
-        (m(x) ** 2).mean().backward()
-        # o1's hook is gone: grads survive backward for o2 to consume
-        assert m.weight.grad is not None
-        np.testing.assert_array_equal(np.asarray(m.weight._data), before)
-        o2.step()
-        o2.clear_grad()
-        assert not np.allclose(np.asarray(m.weight._data), before)

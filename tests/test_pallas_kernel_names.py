@@ -3,7 +3,6 @@ TPU lowering writes it, against the names ``chip_smoke.py`` requires in
 the programs that ran and the patterns the benchmark's flash readers
 match in a device trace. Lowered for the TPU from the CPU, so a rename of
 a ``pl.pallas_call`` cannot pass tier-1 and break only on the chip."""
-import functools
 import re
 
 import jax
@@ -14,7 +13,7 @@ import chip_smoke
 from chipbench.layer_metrics import (flash_bwd_roofline, flash_fwd_roofline,
                                      moe_gmm_roofline)
 from paddle_tpu.ops import flash_attention as flash
-from paddle_tpu.ops import fused_adamw, grouped_matmul
+from paddle_tpu.ops import grouped_matmul
 
 
 def _lowered_for_tpu(fn, *args):
@@ -31,15 +30,6 @@ def _flash_program():
     return _lowered_for_tpu(jax.grad(loss, (0, 1, 2)), q, q, q)
 
 
-def _adamw_program():
-    p = jax.ShapeDtypeStruct((256, 256), jnp.float32)
-    return _lowered_for_tpu(
-        functools.partial(
-            fused_adamw.fused_adamw_update, lr=1e-3, beta1=0.9, beta2=0.999,
-            epsilon=1e-8, beta1_pow=0.9, beta2_pow=0.999, interpret=False),
-        p, p, p, p)
-
-
 def _grouped_matmul_program():
     def loss(x, w, sizes):
         out = grouped_matmul.grouped_matmul(x, w, sizes, False)
@@ -53,9 +43,8 @@ def _grouped_matmul_program():
 
 @pytest.mark.parametrize("program, wanted", [
     (_flash_program, chip_smoke.FLASH_KERNELS),
-    (_adamw_program, chip_smoke.ADAMW_KERNELS),
     (_grouped_matmul_program, chip_smoke.MOE_KERNELS),
-], ids=["flash", "fused_adamw", "grouped_matmul"])
+], ids=["flash", "grouped_matmul"])
 def test_kernel_names_are_the_ones_chip_smoke_requires(program, wanted):
     have = chip_smoke.kernels_in(program())
     assert have == sorted(wanted)
