@@ -74,8 +74,59 @@ def selu(
     )
 
 
+_SQRT_HALF = 0.7071067811865476  # 1 / sqrt(2)
+_INV_SQRT_2PI = 0.3989422804014327  # 1 / sqrt(2 pi)
+
+
+def _gelu_cdf(a):
+    """``x`` in the type the exact GELU is computed in (float32 for bfloat16
+    and float16, the input's own for float32 / float64) and ``Phi(x) = 0.5 (1
+    + erf(x / sqrt 2))``: ONE ``erf``, which the TPU evaluates as one clamped
+    rational polynomial. ``jax.nn.gelu`` takes ``erfc`` in the input's type,
+    a two-branch expansion of some 70 vector ops an element that outweighs
+    the matmul whose epilogue it rides in (PERF.md section 6, PR 30)."""
+    x = a.astype(jnp.promote_types(a.dtype, jnp.float32))
+    return x, 0.5 * (1.0 + jax.lax.erf(x * _SQRT_HALF))
+
+
+@jax.custom_vjp
+def _gelu_erf(a):
+    x, cdf = _gelu_cdf(a)
+    return (x * cdf).astype(a.dtype)
+
+
+def _gelu_erf_fwd(a):
+    # The derivative Phi(x) + x pdf(x) is the only residual: the input has
+    # no reader after the forward and the backward is one multiply. The
+    # barrier makes the producer (a matmul's epilogue, in an MLP) evaluate
+    # erf once and write both results; without it XLA evaluates the
+    # activation again inside every consumer, which costs more than the
+    # expansion did (PERF.md section 6, PR 30).
+    x, cdf = _gelu_cdf(a)
+    slope = cdf + x * (_INV_SQRT_2PI * jnp.exp(-0.5 * x * x))
+    return jax.lax.optimization_barrier(
+        ((x * cdf).astype(a.dtype), slope.astype(a.dtype)))
+
+
+def _gelu_erf_bwd(slope, ct):
+    wide = jnp.promote_types(slope.dtype, jnp.float32)
+    return ((ct.astype(wide) * slope.astype(wide)).astype(slope.dtype),)
+
+
+_gelu_erf.defvjp(_gelu_erf_fwd, _gelu_erf_bwd)
+
+
+def _gelu_exact(a):
+    # integers and booleans become float32, as under jax.nn.gelu
+    if not jnp.issubdtype(a.dtype, jnp.floating):
+        a = a.astype(jnp.float32)
+    return _gelu_erf(a)
+
+
 def gelu(x, approximate=False, name=None):
-    return apply(lambda a: jax.nn.gelu(a, approximate=approximate), x, op_name="gelu")
+    if approximate:
+        return apply(lambda a: jax.nn.gelu(a, approximate=True), x, op_name="gelu")
+    return apply(_gelu_exact, x, op_name="gelu")
 
 
 def leaky_relu(x, negative_slope=0.01, name=None):
