@@ -3,6 +3,8 @@
 llama: decoder LM family (config #3); gpt: decoder LM with learned
 positions (config #4); bert: bidirectional encoder + MLM head
 (config #2); zaya: compressed convolutional attention + top-1 routed
+experts, training path only; afmoe: window and full gated attention
+mixed, a sigmoid top-k router beside a shared expert, a held share of the
 experts, training path only; vision models live in paddle_tpu.vision (config #1).
 """
 from .llama import (  # noqa: F401
@@ -23,6 +25,12 @@ from .zaya import (  # noqa: F401
     ZayaDecoderLayer,
     ZayaForCausalLM,
     ZayaModel,
+)
+from .afmoe import (  # noqa: F401
+    AfmoeConfig,
+    AfmoeDecoderLayer,
+    AfmoeForCausalLM,
+    AfmoeModel,
 )
 from .unet import UNet2DConditionModel, UNetConfig  # noqa: F401
 from .generation import generate  # noqa: F401

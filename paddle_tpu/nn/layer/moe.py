@@ -7,7 +7,13 @@ the tokens are sorted by expert (stable), the sizes of the groups are a
 rows (``ops.grouped_matmul``: no capacity, no ``[E, C, H]`` padding
 buffer, no dispatch mode), and the inverse permutation puts the rows
 back. The layer takes the router's choice — expert ids and gate values
-``[T, k]`` — and returns the combined output ``[T, H]``.
+``[T, k]`` — and returns the combined output ``[T, H]``. Told that it
+holds a SHARE of the experts the router chooses among (``held`` of
+``num_experts`` from ``first`` on: one chip's part of an expert-parallel
+layer), it computes its own experts' part for the pairs routed to them,
+dropless, whatever number that is: the pairs of the absent experts sort
+last, belong to no group, are never computed and add zero. No code
+stands in for the absent chips or their traffic.
 
 ``MLPRouter``: a down-projection and a three-layer GELU MLP to E logits,
 computed in float32 whatever the model's storage type, with a selection
@@ -15,11 +21,18 @@ bias ``beta`` (a float32 buffer: no gradient reaches it) that moves
 WHICH expert is chosen and never the gate value. Top-1. Nothing here
 moves ``beta``: a balancing rule is its owner's to run.
 
+``SigmoidTopKRouter``: one float32 matrix to E scores ``sigmoid(m W)``,
+the k best of ``score + bias`` chosen (``bias`` a float32 buffer that
+enters the choice alone), the chosen scores renormalised to sum to one
+and scaled: the gates.
+
 (``distributed.fleet.meta_parallel.moe.MoELayer`` — capacity-dropping
 ``TopKGate``, two-matrix GELU experts, expert parallelism over a mesh —
 is the older of the two and is left as it is; ROADMAP D5.)
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +42,7 @@ from ...base.tensor import Tensor
 from .. import initializer as I
 from .layers import Layer
 
-__all__ = ["RoutedExperts", "MLPRouter"]
+__all__ = ["RoutedExperts", "MLPRouter", "SigmoidTopKRouter"]
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -54,26 +67,72 @@ def _permute_bwd(res, g):
 _permute_rows.defvjp(_permute_fwd, _permute_bwd)
 
 
-def routed_experts(x, w_gu, w_dn, ids, gates):
+def _zero_tail(a, rows):
+    from ...ops.grouped_matmul import zero_tail
+    return zero_tail(a, rows)
+
+
+@jax.custom_vjp
+def _result_tail(a, rows):
+    """A grouped matmul's result with the rows its kernels left unwritten
+    (from ``rows`` on) read as zero. The cotangent passes as it comes: it
+    is finite, and the kernels that take it visit no row of the tail
+    (``moe_tgmm`` selects its ``dy`` side row by row)."""
+    return _zero_tail(a, rows)
+
+
+_result_tail.defvjp(lambda a, rows: (_zero_tail(a, rows), None),
+                    lambda _, g: (g, None))
+
+
+@jax.custom_vjp
+def _gradient_tail(a, rows):
+    """``a`` as it is (its tail is finite: the tokens' own rows); its
+    GRADIENT's rows from ``rows`` on, which the input-gradient kernel
+    left unwritten, read as zero."""
+    return a
+
+
+_gradient_tail.defvjp(lambda a, rows: (a, rows),
+                      lambda rows, g: (_zero_tail(g, rows), None))
+
+
+def routed_experts(x, w_gu, w_dn, ids, gates, num_experts: int, first: int):
     """x [T, H], w_gu [E, H, 2F] (gate | up), w_dn [E, F, H], ids / gates
-    [T, k] -> (out [T, H], tokens per expert [E] int32)."""
+    [T, k] -> (out [T, H], tokens per expert [E] int32). ``ids`` run over
+    ``num_experts``; the E held ones are ``first .. first + E``."""
     from ...ops.grouped_matmul import grouped_matmul
 
     t, k = ids.shape
-    experts, f = w_gu.shape[0], w_dn.shape[1]
+    held, f = w_gu.shape[0], w_dn.shape[1]
+    share = held < num_experts
     with jax.named_scope("moe.permute"):
         flat = ids.reshape(t * k)
+        if share:   # the absent experts' pairs sort last, in no group
+            flat = jnp.where((flat >= first) & (flat < first + held),
+                             flat - first, held)
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
-        sizes = jnp.bincount(flat, length=experts).astype(jnp.int32)
+        sizes = jnp.bincount(flat, length=held + 1 if share else held)
+        sizes = (sizes[:held] if share else sizes).astype(jnp.int32)
         rows = x if k == 1 else jnp.repeat(x, k, axis=0)
         rows = _permute_rows(rows, order, inverse)
+    if share:
+        # a select costs a pass over all T*k rows (~1 ms at the cell's
+        # size), so only where a kernel's unwritten rows would be read:
+        # each result in the forward (``act`` must be finite where
+        # ``moe_tgmm`` takes it as ``x``), the rows' gradient in the backward
+        total = jnp.sum(sizes)
+        rows = _gradient_tail(rows, total)
+        result = lambda a: _result_tail(a, total)
+    else:
+        result = lambda a: a
     with jax.named_scope("moe.experts"):
-        gu = grouped_matmul(rows, w_gu, sizes)
+        gu = result(grouped_matmul(rows, w_gu, sizes))
         act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
                * gu[:, f:].astype(jnp.float32)).astype(x.dtype)
-        y = grouped_matmul(act, w_dn, sizes)
+        y = result(grouped_matmul(act, w_dn, sizes))
     with jax.named_scope("moe.combine"):
         y = _permute_rows(y, inverse, order).reshape(t, k, -1)
         out = jnp.sum(y.astype(jnp.float32)
@@ -82,38 +141,61 @@ def routed_experts(x, w_gu, w_dn, ids, gates):
 
 
 class RoutedExperts(Layer):
-    """E stacked gated experts, dropless. ``forward(x [.., H], ids [.., k],
-    gates [.., k])`` -> [.., H]. ``tokens_per_expert`` (int32 [E], a buffer
-    on the device) adds up how many rows each expert was given, call by
-    call; nothing reads it back but whoever asks (``numpy()``)."""
+    """Stacked gated experts, dropless. ``forward(x [.., H], ids [.., k],
+    gates [.., k])`` -> [.., H]. ``held`` of the ``num_experts`` the ids
+    run over live here, from ``first`` on (default: all of them).
+    ``tokens_per_expert`` (int32 [held], a buffer on the device) adds up
+    how many rows each held expert was given, call by call; a layer that
+    holds a share also adds up ``pairs_routed``, every (token, choice)
+    pair it saw (its rows no longer add up to them). Nothing reads them
+    back but whoever asks (``numpy()``)."""
 
     def __init__(self, hidden_size: int, intermediate_size: int,
-                 num_experts: int):
+                 num_experts: int, held: int = None, first: int = 0):
         super().__init__()
+        held = num_experts if held is None else held
+        if not 0 <= first <= first + held <= num_experts:
+            raise ValueError(f"experts {first}..{first + held} of "
+                             f"{num_experts}")
+        self.num_experts, self.first = num_experts, first
         init = I.Normal(0.0, 0.02)
         self.w_gu = self.create_parameter(
-            [num_experts, hidden_size, 2 * intermediate_size],
+            [held, hidden_size, 2 * intermediate_size],
             default_initializer=init)
         self.w_dn = self.create_parameter(
-            [num_experts, intermediate_size, hidden_size],
+            [held, intermediate_size, hidden_size],
             default_initializer=init)
         self.register_buffer("tokens_per_expert", Tensor(
-            jnp.zeros([num_experts], jnp.int32), _internal=True))
+            jnp.zeros([held], jnp.int32), _internal=True))
+        if held < num_experts:
+            self.register_buffer("pairs_routed", Tensor(
+                jnp.zeros([], jnp.int32), _internal=True))
 
-    def forward(self, x, ids, gates):
+    def compute(self, x, ids, gates):
+        """The layer without its counters: (out, rows each held expert
+        got [held]). For a caller that runs it where a buffer cannot be
+        written (under ``recompute``) and calls ``count`` after."""
         lead, h = tuple(x.shape[:-1]), x.shape[-1]
         k = ids.shape[-1]
 
         def run(x, w_gu, w_dn, ids, gates):
             out, sizes = routed_experts(
                 x.reshape(-1, h), w_gu, w_dn, ids.reshape(-1, k),
-                gates.reshape(-1, k))
+                gates.reshape(-1, k), self.num_experts, self.first)
             return out.reshape(*lead, h), sizes
 
-        out, sizes = apply(run, x, self.w_gu, self.w_dn, ids, gates,
-                           op_name="routed_experts")
+        return apply(run, x, self.w_gu, self.w_dn, ids, gates,
+                     op_name="routed_experts")
+
+    def count(self, sizes, pairs: int) -> None:
         self.tokens_per_expert.set_value(
             self.tokens_per_expert._data + sizes._data)
+        if "pairs_routed" in self._buffers:
+            self.pairs_routed.set_value(self.pairs_routed._data + pairs)
+
+    def forward(self, x, ids, gates):
+        out, sizes = self.compute(x, ids, gates)
+        self.count(sizes, math.prod(ids.shape))
         return out
 
 
@@ -162,3 +244,43 @@ class MLPRouter(Layer):
         return apply(mlp_router, w, self.beta, self.wd, self.bd, self.w1,
                      self.b1, self.w2, self.b2, self.w3, self.b3,
                      op_name="mlp_router")
+
+
+def sigmoid_topk_router(m, w, bias, *, top_k: int, scale: float, norm: bool):
+    """m [.., H] -> (ids [.., k] int32, gates [.., k] float32): scores
+    ``sigmoid(m w)`` in float32 at full matmul precision; the ``top_k``
+    best of score + ``bias`` [E] are chosen (best first), the gate of a
+    chosen expert is its score without the bias, over the chosen scores'
+    sum under ``norm``, times ``scale``."""
+    f32 = jnp.float32
+    scores = jax.nn.sigmoid(jnp.matmul(m.astype(f32), w.astype(f32),
+                                       precision=_HIGHEST))
+    _, ids = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(bias.astype(f32)), top_k)
+    gates = jnp.take_along_axis(scores, ids, axis=-1)
+    if norm:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    return ids.astype(jnp.int32), scale * gates
+
+
+class SigmoidTopKRouter(Layer):
+    """``forward(m [.., H])`` -> (ids [.., k], gates [.., k]): the k
+    experts of the largest ``sigmoid(m W) + bias``; gates
+    ``route_scale * score / sum of the chosen scores`` (``route_norm``).
+    ``bias`` is a float32 buffer: nothing here moves it."""
+
+    def __init__(self, hidden_size: int, num_experts: int, top_k: int,
+                 route_scale: float = 1.0, route_norm: bool = True):
+        super().__init__()
+        self.top_k, self.scale, self.norm = top_k, route_scale, route_norm
+        self.weight = self.create_parameter(
+            [hidden_size, num_experts],
+            default_initializer=I.Normal(0.0, 0.02))
+        self.register_buffer("bias", Tensor(
+            jnp.zeros([num_experts], jnp.float32), _internal=True))
+
+    def forward(self, m):
+        return apply(
+            lambda m, w, b: sigmoid_topk_router(
+                m, w, b, top_k=self.top_k, scale=self.scale, norm=self.norm),
+            m, self.weight, self.bias, op_name="sigmoid_topk_router")

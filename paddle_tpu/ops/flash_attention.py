@@ -19,9 +19,13 @@ FETCHED through VMEM and pass the vector units a SUB-tile at a time
 (``Sweep``). Under a causal mask ``_spans`` — the one place that knows
 the rule — says which sub-tiles see every pair (no mask is built),
 which straddle the diagonal (masked) and which are dead: no dead
-sub-tile is visited and no dead block fetched. A sequence short enough
-is held whole, and the schedule is then settled when the kernel is
-traced: its tiles unroll into straight-line code (``_sweep``).
+sub-tile is visited and no dead block fetched. A sliding ``window`` is
+a second dead boundary of the same rule, behind the diagonal: sub-tiles
+wholly older than the window are dead, those its edge crosses masked.
+Window calls run under kernel names of their own (``flash_window_*``),
+so a device trace tells them from the causal ones. A sequence short
+enough is held whole, and the schedule is then settled when the kernel
+is traced: its tiles unroll into straight-line code (``_sweep``).
 
 Layouts: public API is paddle's [B, S, H, D]; kernels run [B, H, S, D].
 GQA: the forward indexes kv-heads via h // group — no repeat; the
@@ -119,28 +123,53 @@ def _max(a, b):
     return max(a, b) if _static(a, b) else jnp.maximum(a, b)
 
 
-def _spans(first, rows: int, off: int, sub: int, n: int, over_k: bool):
-    """THE causal rule: query i sees key j iff j <= i + off.
+def _clamp(x, lo, hi):
+    return _min(_max(x, lo), hi)
+
+
+def _spans(first, rows: int, off: int, sub: int, n: int, over_k: bool,
+           window: Optional[int] = None):
+    """THE rule: query i sees key j iff j <= i + off and, under a
+    ``window``, i + off - j < window.
 
     A resident block covers ``rows`` positions from ``first`` on one
     axis; the other axis is cut into ``n`` sub-tiles of ``sub``. Returns
-    ``(full_lo, full_hi, diag_lo, diag_hi)``: sub-tiles [full_lo,
-    full_hi) see every pair of the block, [diag_lo, diag_hi) straddle
-    the diagonal and need the mask, all others are dead. ``over_k``: the
-    block is of queries and keys are swept (the full sub-tiles come
-    first); else the block is of keys and queries are swept (the
-    diagonal comes first). ``first`` is an int or a traced int32 (from a
-    program id) and the result follows it; numerators are held
-    non-negative so ``//`` floors either way.
+    ``(m_lo, f_lo, f_hi, m_hi)``: sub-tiles [f_lo, f_hi) see every pair
+    of the block, [m_lo, f_lo) and [f_hi, m_hi) straddle an edge of the
+    visible band and need the mask, all others are dead. ``over_k``: the
+    block is of queries and keys are swept (the window's edge comes
+    first, the diagonal last); else the block is of keys and queries are
+    swept (the diagonal comes first). ``first`` is an int or a traced
+    int32 (from a program id) and the result follows it; numerators are
+    held non-negative so ``//`` floors either way.
     """
     last = first + rows - 1
     if over_k:
         full = _min(_max(first + off + 1, 0) // sub, n)
         live = _min(_max(last + off + sub, 0) // sub, n)
-        return 0, full, full, live
+        if window is None:
+            return 0, 0, full, live
+        # keys older than every query's window / inside every query's
+        dead = _min(_max(first + off - window + 1, 0) // sub, live)
+        inside = (_max(last + off - window + 1, 0) + sub - 1) // sub
+        f_hi = _clamp(full, dead, live)
+        return dead, _clamp(inside, dead, f_hi), f_hi, live
     live = _min(_max(first - off, 0) // sub, n)
     full = _min((_max(last - off, 0) + sub - 1) // sub, n)
-    return full, n, live, full
+    if window is None:
+        return live, full, n, n
+    # queries whose window still holds every key of the block / any key
+    inside = _max(first - off + window, 0) // sub
+    end = _clamp(_max(last - off + window - 1 + sub, 0) // sub, live, n)
+    f_hi = _clamp(inside, live, end)
+    return live, _clamp(full, live, f_hi), f_hi, end
+
+
+def kernel_names(window: Optional[int] = None):
+    """The ``name=`` of the three kernels (forward, dq, dk/dv): what a
+    device trace shows each call as."""
+    tag = "flash" if window is None else "flash_window"
+    return f"{tag}_fwd", f"{tag}_bwd_dq", f"{tag}_bwd_dkv"
 
 
 class TileCounts(NamedTuple):
@@ -149,21 +178,23 @@ class TileCounts(NamedTuple):
     dead: int    # sub-tiles never visited
 
 
-def tile_plan(sq: int, sk: int, d: int, causal: bool, itemsize: int = 2):
+def tile_plan(sq: int, sk: int, d: int, causal: bool, itemsize: int = 2,
+              window: Optional[int] = None):
     """How often the schedule engages, per head, for a shape:
     ``{kernel name: (Sweep, TileCounts)}``. Static: which sub-tiles are
     masked is fixed when a kernel is traced."""
     plan = {}
-    for name, t, over_k in zip(("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
+    for name, t, over_k in zip(kernel_names(window),
                                _sweeps(sq, sk, d, itemsize),
                                (True, True, False)):
         resident, swept = (sq, sk) if over_k else (sk, sq)
         n = swept // t.sub
         full = masked = 0
         for first in range(0, resident, t.rows):
-            f0, f1, d0, d1 = (_spans(first, t.rows, sk - sq, t.sub, n, over_k)
-                              if causal else (0, n, n, n))
-            full, masked = full + f1 - f0, masked + d1 - d0
+            m0, f0, f1, m1 = (_spans(first, t.rows, sk - sq, t.sub, n,
+                                     over_k, window)
+                              if causal else (0, 0, n, n))
+            full, masked = full + f1 - f0, masked + (m1 - m0) - (f1 - f0)
         total = (resident // t.rows) * n
         plan[name] = (t, TileCounts(full, masked, total - full - masked))
     return plan
@@ -201,7 +232,7 @@ def _rows_at(j, sub: int):
 
 
 def _schedule(t: Sweep, steps, causal: bool, off: int, over_k: bool,
-              block) -> None:
+              block, window: Optional[int]) -> None:
     """One grid step of a kernel whose grid is ``steps`` = (batch, heads,
     held blocks, fetched blocks). For each ``t.rows`` of the held block,
     ``block(r, first)`` — ``r`` their slice of the block, ``first`` their
@@ -209,8 +240,8 @@ def _schedule(t: Sweep, steps, causal: bool, off: int, over_k: bool,
     on the first fetched block, ``finish`` on the last, and between them
     ``tile(at, start, masked)`` on every live sub-tile of this step's
     fetched block (``at`` its slice of the block, ``start`` its position
-    on the swept axis): the full ones bare, the diagonal ones masked, in
-    the order the sweep meets them."""
+    on the swept axis): the full ones bare, those on an edge of the
+    visible band masked, in the order the sweep meets them."""
     ih, ib = _step(2, steps), _step(3, steps)
     per_block = t.fetch // t.sub
     n = steps[3] * per_block
@@ -228,39 +259,43 @@ def _schedule(t: Sweep, steps, causal: bool, off: int, over_k: bool,
 
         _when(ib == 0, init)
         if causal:
-            f0, f1, d0, d1 = _spans(first, t.rows, off, t.sub, n, over_k)
-            runs = [(f0, f1, False), (d0, d1, True)]
-            for lo, hi, masked in (runs if over_k else runs[::-1]):
-                run(inside(lo), inside(hi), masked)
+            m0, f0, f1, m1 = _spans(first, t.rows, off, t.sub, n, over_k,
+                                    window)
+            for lo, hi, masked in ((m0, f0, True), (f0, f1, False),
+                                   (f1, m1, True)):
+                if not (_static(lo, hi) and lo == hi):
+                    run(inside(lo), inside(hi), masked)
         else:
             run(0, per_block, False)
         _when(ib == steps[3] - 1, finish)
 
 
-def _last_live_block(t: Sweep, steps, off: int, iq):
-    """Index of the last fetched k block a held q block has a live pair
-    in."""
-    per_block = t.fetch // t.sub
-    live = _spans(iq * t.held, t.held, off, t.sub, steps[3] * per_block,
-                  True)[3]
-    return _max(live - 1, 0) // per_block
-
-
-def _first_live_block(t: Sweep, steps, off: int, ik):
-    """Index of the first fetched q block a held k block has a live pair
-    in."""
+def _live_block(t: Sweep, steps, off: int, window: Optional[int], held,
+                swept, over_k: bool):
+    """The fetched block grid step (``held``, ``swept``) reads: its own
+    where the held block has a live pair in it, else the nearest that
+    has — the block already in VMEM, so a dead step fetches nothing."""
     per_block = t.fetch // t.sub
     n = steps[3] * per_block
-    live = _spans(ik * t.held, t.held, off, t.sub, n, False)[2]
-    return _min(live, n - 1) // per_block
+    m_lo, _, _, m_hi = _spans(held * t.held, t.held, off, t.sub, n, over_k,
+                              window)
+    if over_k or window is not None:
+        swept = jnp.minimum(swept, _max(m_hi - 1, 0) // per_block)
+    if not over_k or window is not None:
+        swept = jnp.maximum(swept, _min(m_lo, n - 1) // per_block)
+    return swept
 
 
-def _visible(q_first, k_first, shape, off: int, q_axis: int):
+def _visible(q_first, k_first, shape, off: int, q_axis: int,
+             window: Optional[int]):
     """Mask of a sub-tile whose queries start at ``q_first`` along axis
     ``q_axis`` and whose keys start at ``k_first`` along the other."""
     q_abs = q_first + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_abs = k_first + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
-    return q_abs + off >= k_abs
+    if window is None:
+        return q_abs + off >= k_abs
+    behind = q_abs + off - k_abs
+    return (behind >= 0) & (behind < window)
 
 
 def _across(x, width: int):
@@ -294,7 +329,8 @@ _PARALLEL_BUT_LAST = pltpu.CompilerParams(
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale: float, causal: bool, off: int, t: Sweep, steps):
+                *, scale: float, causal: bool, off: int, t: Sweep, steps,
+                window: Optional[int]):
     d = q_ref.shape[3]
 
     def block(r, first):  # t.rows queries from ``first``
@@ -306,7 +342,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         def tile(at, start, masked: bool):
             s = _dot(q_ref[0, 0, r], k_ref[0, 0, at, :], _NT) * scale
             if masked:
-                mask = _visible(first, start, s.shape, off, 0)
+                mask = _visible(first, start, s.shape, off, 0, window)
                 s = jnp.where(mask, s, NEG_INF)          # [rows, sub] f32
             # m and l stay lane-replicated [rows, 128] from tile to tile
             m_prev = m_scr[r]
@@ -316,7 +352,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
             if masked and off < 0:
                 # a fully-masked row (sq > sk only): m_new == NEG_INF
                 # makes exp(s-m) == 1; zero it so the row emits 0 and l
-                # stays 0
+                # stays 0. (A row that a window's edge masks in its
+                # first tiles meets a live key later: alpha == 0 then
+                # wipes what those tiles added.)
                 p = jnp.where(mask, p, 0.0)
             l_scr[r] = l_scr[r] * alpha + jnp.sum(p, axis=1, keepdims=True)
             pv = _dot(p.astype(v_ref.dtype), v_ref[0, 0, at, :], _NN)
@@ -332,11 +370,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
         return init, tile, finish
 
-    _schedule(t, steps, causal, off, True, block)
+    _schedule(t, steps, causal, off, True, block, window)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "causal", "interpret"))
-def _flash_fwd(q, k, v, scale: float, causal: bool, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("scale", "causal", "interpret",
+                                             "window"))
+def _flash_fwd(q, k, v, scale: float, causal: bool, interpret: bool,
+               window: Optional[int]):
     """q: [B, Hq, Sq, D], k/v: [B, Hkv, Sk, D] → (out [B,Hq,Sq,D],
     lse [B,Hq,Sq] in f32). Jitted so that a model's layers share one
     trace and one lowering of the kernel."""
@@ -352,11 +392,11 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, interpret: bool):
 
     def kv_map(b, h, iq, ik):
         if causal:  # a dead step re-uses the block already in VMEM
-            ik = jnp.minimum(ik, _last_live_block(t, grid, off, iq))
+            ik = _live_block(t, grid, off, window, iq, ik, True)
         return (b, h // group, ik, 0)
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               off=off, t=t, steps=grid)
+                               off=off, t=t, steps=grid, window=window)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -380,7 +420,7 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, interpret: bool):
         ],
         compiler_params=_PARALLEL_BUT_LAST,
         interpret=interpret,
-        name="flash_fwd",
+        name=kernel_names(window)[0],
     )(q, k, v)
     return out, lse[:, :, 0, :]
 
@@ -392,7 +432,7 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, interpret: bool):
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    acc_scr, *, scale: float, causal: bool, off: int,
-                   t: Sweep, steps):
+                   t: Sweep, steps, window: Optional[int]):
     def block(r, first):  # t.rows queries from ``first``
         def init():
             acc_scr[r] = jnp.zeros((t.rows, acc_scr.shape[1]), jnp.float32)
@@ -401,7 +441,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             k = k_ref[0, 0, at, :]
             s = _dot(q_ref[0, 0, r], k, _NT) * scale      # [rows, sub]
             if masked:
-                mask = _visible(first, start, s.shape, off, 0)
+                mask = _visible(first, start, s.shape, off, 0, window)
                 s = jnp.where(mask, s, NEG_INF)
             # the residual rows become columns inside the tile, where
             # the scheduler hides the relayout under the matmuls (hoisted
@@ -419,12 +459,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
         return init, tile, finish
 
-    _schedule(t, steps, causal, off, True, block)
+    _schedule(t, steps, causal, off, True, block, window)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, scale: float, causal: bool, off: int, t: Sweep, steps):
+                    *, scale: float, causal: bool, off: int, t: Sweep, steps,
+                    window: Optional[int]):
     """Scores are computed TRANSPOSED, [k rows, q rows]: ``lse`` and
     ``delta`` broadcast along sublanes as the rows they arrive as, and
     dv += p^T do, dk += ds^T q are plain contractions."""
@@ -440,7 +481,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             do = do_ref[0, 0, at, :]
             s = _dot(k_ref[0, 0, r], q, _NT) * scale      # [rows, sub] = s^T
             if masked:
-                mask = _visible(start, first, s.shape, off, 1)
+                mask = _visible(start, first, s.shape, off, 1, window)
                 s = jnp.where(mask, s, NEG_INF)
             p = jnp.exp(s - lse_ref[0, 0, :, at])         # lse: [1, sub]
             if masked and off < 0:
@@ -456,16 +497,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         return init, tile, finish
 
-    _schedule(t, steps, causal, off, False, block)
+    _schedule(t, steps, causal, off, False, block, window)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "causal", "interpret"))
-def _flash_bwd(q, k, v, out, lse, do, scale: float, causal: bool, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("scale", "causal", "interpret",
+                                             "window"))
+def _flash_bwd(q, k, v, out, lse, do, scale: float, causal: bool,
+               interpret: bool, window: Optional[int]):
     """All operands [B, H, S, D] (kv already head-expanded)."""
     batch, h, sq, d = q.shape
     sk = k.shape[2]
     off = sk - sq
     _, tq, tk = _sweeps(sq, sk, d, q.dtype.itemsize)
+    _, name_dq, name_dkv = kernel_names(window)
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
     lse3 = lse[:, :, None, :]      # [B, H, 1, Sq]
     delta3 = delta[:, :, None, :]  # [B, H, 1, Sq]
@@ -477,7 +521,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale: float, causal: bool, interpret: boo
 
     def at_k(b, hh, iq, ik):
         if causal:
-            ik = jnp.minimum(ik, _last_live_block(tq, grid, off, iq))
+            ik = _live_block(tq, grid, off, window, iq, ik, True)
         return (b, hh, ik, 0)
 
     def row_q(b, hh, iq, ik):
@@ -485,7 +529,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale: float, causal: bool, interpret: boo
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, off=off,
-                          t=tq, steps=grid),
+                          t=tq, steps=grid, window=window),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, tq.held, d), at_q),
@@ -500,14 +544,14 @@ def _flash_bwd(q, k, v, out, lse, do, scale: float, causal: bool, interpret: boo
         scratch_shapes=[pltpu.VMEM((tq.held, d), jnp.float32)],
         compiler_params=_PARALLEL_BUT_LAST,
         interpret=interpret,
-        name="flash_bwd_dq",
+        name=name_dq,
     )(q, k, v, do, lse3, delta3)
 
     grid = (batch, h, sk // tk.held, sq // tk.fetch)
 
     def live_q(ik, iq):
         if causal:
-            iq = jnp.maximum(iq, _first_live_block(tk, grid, off, ik))
+            iq = _live_block(tk, grid, off, window, ik, iq, False)
         return iq
 
     def swept_q(b, hh, ik, iq):
@@ -521,7 +565,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale: float, causal: bool, interpret: boo
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          off=off, t=tk, steps=grid),
+                          off=off, t=tk, steps=grid, window=window),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, tk.fetch, d), swept_q),
@@ -545,7 +589,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale: float, causal: bool, interpret: boo
         ],
         compiler_params=_PARALLEL_BUT_LAST,
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name=name_dkv,
     )(q, k, v, do, lse3, delta3)
     return dq, dk, dv
 
@@ -555,28 +599,33 @@ def _flash_bwd(q, k, v, out, lse, do, scale: float, causal: bool, interpret: boo
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None):
     """Fused attention, paddle layout [B, S, H, D]; supports GQA
-    (kv heads dividing q heads) and causal masking."""
-    out, _ = _fa_fwd(q, k, v, causal, scale, interpret)
+    (kv heads dividing q heads), causal masking and, under it, a sliding
+    ``window``: a query sees the ``window`` newest keys up to its own."""
+    out, _ = _fa_fwd(q, k, v, causal, scale, interpret, window)
     return out
 
 
-def _fa_fwd(q, k, v, causal, scale, interpret):
+def _fa_fwd(q, k, v, causal, scale, interpret, window):
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window counts back from the causal diagonal: "
+                         f"causal={causal}, window={window}")
     if interpret is None:
         interpret = _interpret_default()
     s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    out_t, lse = _flash_fwd(qt, kt, vt, s, causal, interpret)
+    out_t, lse = _flash_fwd(qt, kt, vt, s, causal, interpret, window)
     return jnp.swapaxes(out_t, 1, 2), (q, k, v, out_t, lse)
 
 
-def _fa_bwd(causal, scale, interpret, res, g):
+def _fa_bwd(causal, scale, interpret, window, res, g):
     if interpret is None:
         interpret = _interpret_default()
     q, k, v, out_t, lse = res
@@ -590,7 +639,8 @@ def _fa_bwd(causal, scale, interpret, res, g):
         kt = jnp.repeat(kt, group, axis=1)
         vt = jnp.repeat(vt, group, axis=1)
     do_t = jnp.swapaxes(g, 1, 2)
-    dq_t, dk_t, dv_t = _flash_bwd(qt, kt, vt, out_t, lse, do_t, s, causal, interpret)
+    dq_t, dk_t, dv_t = _flash_bwd(qt, kt, vt, out_t, lse, do_t, s, causal,
+                                  interpret, window)
     if group > 1:
         b, _, sk, d = dk_t.shape
         dk_t = dk_t.reshape(b, hkv, group, sk, d).sum(axis=2)

@@ -4,10 +4,15 @@ matrix — as Pallas TPU kernels with a custom VJP.
     grouped_matmul(x [T, K], w [E, K, N], group_sizes [E]) -> [T, N]
 
 Rows ``offset[e] .. offset[e+1]`` of ``x`` (``offset`` = the running sum
-of ``group_sizes``, which must add up to T) are multiplied by ``w[e]``:
-what a dropless mixture-of-experts layer needs once its tokens are
-sorted by expert. No capacity, no padding buffer: the work is T rows
-whatever the routing. XLA's form of the same contraction is
+of ``group_sizes``) are multiplied by ``w[e]``: what a dropless
+mixture-of-experts layer needs once its tokens are sorted by expert. No
+capacity, no padding buffer: the work is the rows the groups hold,
+whatever the routing. The sizes may add up to LESS than T (a layer that
+holds a share of the experts sorts the rows of the absent ones last):
+rows past the last group belong to no group, no kernel visits their
+tiles, and the result's rows there are left unwritten — ``zero_tail``
+makes them read as zero downstream, in the result and in the input
+gradient alike. XLA's form of the same contraction is
 ``jax.lax.ragged_dot``; the algorithm (tiles of rows visited group by
 group, found through scalar-prefetched tables) is the one jax ships as
 ``jax.experimental.pallas.ops.tpu.megablox``, written here for one chip
@@ -77,6 +82,15 @@ class Tiling(NamedTuple):
     tn: int
 
 
+def _widest(size: int, cap: int) -> int:
+    """The largest multiple of 128 that divides ``size`` and is at most
+    ``cap`` (6144 under a cap of 2730: 2048); else the whole axis."""
+    for b in range(min(cap, size) // 128 * 128, 0, -128):
+        if size % b == 0:
+            return b
+    return size
+
+
 def gmm_tiling(t: int, k: int, n: int, itemsize: int) -> Tiling:
     """``moe_gmm`` at [t, k] x [E, k, n]. 128 rows a visit: at a few
     hundred rows a group, taller tiles spend on the rows of the
@@ -85,8 +99,9 @@ def gmm_tiling(t: int, k: int, n: int, itemsize: int) -> Tiling:
     all of its visits and is fetched once — and the block's columns take
     what is left of ``_W_BLOCK_BYTES``: at 2048 x 4096 all of them, so
     the rows are read once and a grid step is one visit (10.9 us of MXU
-    for 0.3 of pipeline)."""
-    tn = _fit(n, max(128, _W_BLOCK_BYTES // (k * itemsize)))
+    for 0.3 of pipeline); at 3072 x 6144 a third (2048), and the walk
+    over the groups is made once a column block."""
+    tn = _widest(n, max(128, _W_BLOCK_BYTES // (k * itemsize)))
     return Tiling(_fit(t, 128), k, tn)
 
 
@@ -350,8 +365,10 @@ def _tgmm(x, dy, group_sizes, tiling: Optional[Tiling], interpret: bool):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def grouped_matmul(x, w, group_sizes, interpret: Optional[bool] = None):
     """``out[rows of e] = x[rows of e] @ w[e]``; ``group_sizes`` (int32
-    [E]) must add up to ``x``'s rows (rows past their sum are left
-    unwritten). Differentiable in ``x`` and ``w``."""
+    [E]) add up to at most ``x``'s rows. Rows past their sum are left
+    unwritten, here and in ``x``'s gradient (``zero_tail``), and must be
+    finite in ``x``: the weight gradient zeroes them on one side of its
+    contraction only. Differentiable in ``x`` and ``w``."""
     return _gm_fwd(x, w, group_sizes, interpret)[0]
 
 
@@ -375,12 +392,24 @@ def _gm_bwd(interpret, res, dy):
 grouped_matmul.defvjp(_gm_fwd, _gm_bwd)
 
 
+def zero_tail(a, rows):
+    """``a`` [T, ...] with the rows from ``rows`` (a traced count) on
+    read as zero — and, a select's transpose being a select, its
+    gradient's rows there as well: around a ``grouped_matmul`` whose
+    sizes add up to ``rows`` < T it keeps what the kernels left unwritten
+    out of everything downstream, forward and backward."""
+    row = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+    return jnp.where(row < rows, a, jnp.zeros_like(a))
+
+
 def grouped_matmul_reference(x, w, group_sizes):
     """The same contraction in plain jnp: every row against the matrix of
-    the group it lies in (a gather of [T, K, N] — for tests and small
-    sizes only)."""
+    the group it lies in, rows past the last group zero (a gather of
+    [T, K, N] — for tests and small sizes only)."""
     ends = jnp.cumsum(group_sizes)
-    group = jnp.searchsorted(ends, jnp.arange(x.shape[0]), side="right")
+    row = jnp.arange(x.shape[0])
+    group = jnp.searchsorted(ends, row, side="right")
     group = jnp.minimum(group, w.shape[0] - 1)
-    return jnp.einsum("tk,tkn->tn", x, w[group],
-                      preferred_element_type=jnp.float32).astype(x.dtype)
+    out = jnp.einsum("tk,tkn->tn", x, w[group],
+                     preferred_element_type=jnp.float32)
+    return jnp.where((row < ends[-1])[:, None], out, 0.0).astype(x.dtype)

@@ -16,7 +16,7 @@ from paddle_tpu.ops.flash_attention import (
     Sweep, TileCounts, flash_attention, tile_plan)
 
 
-def _naive(q, k, v, causal):
+def _naive(q, k, v, causal, window=None):
     hq, hkv = q.shape[2], k.shape[2]
     qh, kh, vh = [jnp.swapaxes(x, 1, 2) for x in (q, k, v)]
     if hq != hkv:
@@ -27,6 +27,8 @@ def _naive(q, k, v, causal):
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq - window)
         s = jnp.where(mask, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.swapaxes(jnp.einsum("bhqk,bhkd->bhqd", p, vh), 1, 2)
@@ -142,24 +144,30 @@ class TestTileSchedule:
             Sweep(held=512, rows=512, fetch=1024, sub=512),
             TileCounts(full=6, masked=4, dead=6))
 
+    @pytest.mark.parametrize("window", [None, 1, 100, 128, 300, 5000])
     @pytest.mark.parametrize("off", [0, 256, -256, 100])
     @pytest.mark.parametrize("over_k", [True, False])
-    def test_spans_against_the_rule_itself(self, off, over_k):
+    def test_spans_against_the_rule_itself(self, off, over_k, window):
         """``_spans`` against a brute-force count of visible pairs, on
-        ints and on traced scalars alike."""
+        ints and on traced scalars alike, with and without a window."""
         rows, sub, n = 256, 128, 8
-        traced = jax.jit(
-            lambda first: kernels._spans(first, rows, off, sub, n, over_k))
+        traced = jax.jit(lambda first: kernels._spans(
+            first, rows, off, sub, n, over_k, window))
         for first in range(0, 1024, rows):
-            f0, f1, d0, d1 = kernels._spans(first, rows, off, sub, n, over_k)
-            assert tuple(int(x) for x in traced(first)) == (f0, f1, d0, d1)
+            m0, f0, f1, m1 = kernels._spans(first, rows, off, sub, n, over_k,
+                                            window)
+            assert tuple(int(x) for x in traced(first)) == (m0, f0, f1, m1)
+            assert 0 <= m0 <= f0 <= f1 <= m1 <= n
             for j in range(n):
                 held = np.arange(first, first + rows)[:, None]
                 swept = np.arange(j * sub, (j + 1) * sub)[None, :]
                 q, k = (held, swept) if over_k else (swept, held)
-                seen = int((k <= q + off).sum())
+                seen = k <= q + off
+                if window is not None:
+                    seen &= q + off - k < window
+                seen = int(seen.sum())
                 kind = ("full" if f0 <= j < f1 else
-                        "masked" if d0 <= j < d1 else "dead")
+                        "masked" if m0 <= j < m1 else "dead")
                 assert kind == ("full" if seen == rows * sub else
                                 "masked" if seen else "dead"), (first, j)
 
@@ -182,18 +190,20 @@ class TestTileSchedule:
         kernels._flash_bwd.clear_cache()
 
     @staticmethod
-    def _parity(sq, sk, hq, hkv, d, causal):
+    def _parity(sq, sk, hq, hkv, d, causal, window=None):
         q = _rand((1, sq, hq, d), seed=0)
         k = _rand((1, sk, hkv, d), seed=1)
         v = _rand((1, sk, hkv, d), seed=2)
-        out = flash_attention(q, k, v, causal, None, True)
+        out = flash_attention(q, k, v, causal, None, True, window)
         np.testing.assert_allclose(
-            np.asarray(out), np.asarray(_naive(q, k, v, causal)), atol=2e-5)
+            np.asarray(out), np.asarray(_naive(q, k, v, causal, window)),
+            atol=2e-5)
         g1 = jax.grad(
-            lambda *a: (flash_attention(*a, causal, None, True) ** 2).sum(),
-            (0, 1, 2))(q, k, v)
+            lambda *a: (flash_attention(*a, causal, None, True, window)
+                        ** 2).sum(), (0, 1, 2))(q, k, v)
         g2 = jax.grad(
-            lambda *a: (_naive(*a, causal) ** 2).sum(), (0, 1, 2))(q, k, v)
+            lambda *a: (_naive(*a, causal, window) ** 2).sum(),
+            (0, 1, 2))(q, k, v)
         for a, b in zip(g1, g2):
             assert a.shape == b.shape
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
@@ -224,6 +234,48 @@ class TestTileSchedule:
         assert all(t.held == t.rows and t.fetch == 128
                    for t, _ in plan.values())
         self._parity(sq, sk, hq, hkv, d, causal)
+
+    @pytest.mark.parametrize("sq, sk, hq, hkv, d, window", [
+        (512, 512, 2, 2, 64, 128),      # a window of one tile
+        (512, 512, 1, 1, 64, 200),      # no multiple of a tile
+        (384, 384, 6, 1, 64, 100),      # GQA 6:1, shorter than a tile
+        (256, 512, 2, 1, 64, 300),      # off > 0
+        (256, 256, 1, 1, 128, 1),       # every query sees itself alone
+    ], ids=["tile", "off-tile", "gqa6", "sq<sk", "one"])
+    @pytest.mark.parametrize("loop", [False, True], ids=["unrolled", "looped"])
+    def test_window_matches_naive(self, request, loop, sq, sk, hq, hkv, d,
+                                  window):
+        """Forward, dq and dk/dv under a sliding window against plain
+        masked attention, held whole and on the loop path."""
+        if loop:
+            request.getfixturevalue("looped")
+        self._parity(sq, sk, hq, hkv, d, True, window)
+
+    def test_a_window_as_long_as_the_sequence_is_causal(self, looped):
+        q, k, v = (_rand((1, 384, 2, 64), seed=i) for i in range(3))
+        for window in (384, 10_000):
+            np.testing.assert_array_equal(
+                np.asarray(flash_attention(q, k, v, True, None, True, window)),
+                np.asarray(flash_attention(q, k, v, True, None, True)))
+        assert tile_plan(384, 384, 64, True, window=384)[
+            "flash_window_fwd"][1] == tile_plan(384, 384, 64, True)[
+            "flash_fwd"][1]
+
+    def test_window_plan_at_the_8k_cell(self):
+        # S = 8192, W = 4096, 512 x 512 tiles on the loop path: a row of
+        # tiles has the diagonal tile, 7 bare ones and the window's edge
+        plan = tile_plan(8192, 8192, 128, True, window=4096)
+        assert set(plan) == {"flash_window_fwd", "flash_window_bwd_dq",
+                             "flash_window_bwd_dkv"}
+        sweep, counts = plan["flash_window_fwd"]
+        assert sweep == Sweep(held=512, rows=512, fetch=2048, sub=512)
+        # rows 0-7 of tiles: 1 masked + r bare; rows 8-15: 2 masked + 7
+        assert counts == TileCounts(full=sum(range(8)) + 8 * 7,
+                                    masked=8 + 16, dead=256 - 84 - 24)
+        assert plan["flash_window_bwd_dkv"][1] == counts
+        with pytest.raises(ValueError, match="causal"):
+            flash_attention(*(jnp.zeros((1, 128, 1, 64)),) * 3, False, None,
+                            True, 64)
 
     @pytest.mark.parametrize("unrolled", [True, False])
     def test_more_queries_than_keys(self, unrolled, monkeypatch):

@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from chipbench.layer_metrics import (flash_bwd_roofline, flash_fwd_roofline,
                                      moe_gmm_roofline)
-from paddle_tpu.ops.flash_attention import flash_attention
+from paddle_tpu.ops.flash_attention import flash_attention, kernel_names
 from paddle_tpu.ops.grouped_matmul import grouped_matmul
 
 # the patterns the benchmark's flash readers find the kernels by in a
@@ -71,6 +71,17 @@ SHAPES = {
     "long-8k": (8192, 8192, 2, 2, 128, True, jnp.bfloat16),
     "noncausal-4k": (4096, 4096, 2, 2, 64, False, jnp.bfloat16),
     "float32": (2048, 2048, 2, 2, 128, True, jnp.float32),
+    # train-trinity-5l-8k's one full layer: GQA 48/8 at 8192, the loop
+    "cell-trinity-full": (8192, 8192, 48, 8, 128, True, jnp.bfloat16),
+}
+
+# (sq, sk, q heads, kv heads, d_head, window, dtype): a sliding window
+WINDOWS = {
+    # train-trinity-5l-8k's four window layers: 4096 of 8192, the loop
+    "cell-trinity-window": (8192, 8192, 48, 8, 128, 4096, jnp.bfloat16),
+    # held whole: the window's edge settled when the kernel is traced
+    "whole-2k": (2048, 2048, 4, 2, 128, 600, jnp.bfloat16),
+    "float32-loop": (4096, 4096, 2, 2, 128, 1000, jnp.float32),
 }
 
 
@@ -93,12 +104,39 @@ def test_grad_of_the_kernel_compiles_for_a_v5e(one_chip, shape):
         assert sum(bool(re.search(pattern, c)) for c in calls) == 1, pattern
 
 
+@pytest.mark.parametrize("shape", WINDOWS.values(), ids=WINDOWS.keys())
+def test_grad_of_the_window_kernels_compiles_for_a_v5e(one_chip, shape):
+    sq, sk, hq, hkv, d, window, dtype = shape
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, True, None, False, window)
+        return out.astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, sq, hq, d), dtype, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, sk, hkv, d), dtype, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    # under names of their own, which the causal readers do not match
+    for name in kernel_names(window):
+        assert sum(bool(re.search(rf"^%[\w.\-]*{name}[\w.\-]* = ", c))
+                   for c in calls) == 1, name
+    for pattern in READERS:
+        assert not any(re.search(pattern, c) for c in calls), pattern
+
+
 # (rows, k, n, groups, dtype): both grouped matmuls of a block of
 # train-zaya1-6l-4k, chip_smoke.py's cases, and float32 operands
 GROUPED = {
     "cell-zaya-gate-up": (4096, 2048, 4096, 16, jnp.bfloat16),
     "cell-zaya-down": (4096, 2048, 2048, 16, jnp.bfloat16),
     "float32": (1024, 512, 1024, 8, jnp.float32),
+    # train-trinity-5l-8k: 8 held experts on 32,768 (token, choice) rows,
+    # matrices over the 16 MB slot: fetched in column blocks
+    "cell-trinity-gate-up": (32768, 3072, 6144, 8, jnp.bfloat16),
+    "cell-trinity-down": (32768, 3072, 3072, 8, jnp.bfloat16),
 }
 
 

@@ -18,7 +18,7 @@ import pytest
 from paddle_tpu.ops import grouped_matmul as gm
 from paddle_tpu.ops.grouped_matmul import (
     Tiling, _gmm, _next_with_rows, _tgmm, _visits, gmm_tiling,
-    grouped_matmul, grouped_matmul_reference, tgmm_tiling)
+    grouped_matmul, grouped_matmul_reference, tgmm_tiling, zero_tail)
 
 # (rows, k, n, group sizes): rows tile by 128 (by 256 in the weight
 # gradient where that divides them) unless no power of two down to 128
@@ -131,6 +131,65 @@ def test_other_tilings_give_the_same_result(tilings):
                         rtol=1e-5, atol=1e-4)
 
 
+# sizes that add up to LESS than the rows: a layer that holds a share of
+# the experts sorts the rows of the absent ones last
+TAILS = {
+    "empty-tail": (256, 128, 128, [100, 56, 100]),
+    "all-tail": (256, 128, 128, [0, 0, 0]),
+    "a-tile-shared-with-the-tail": (384, 128, 128, [100, 60]),
+    "tail-from-a-tile-edge": (384, 128, 128, [128, 0, 128]),
+    "empty-groups-before-the-tail": (512, 128, 256, [0, 130, 0, 0]),
+    "one-row-then-tail": (256, 128, 128, [0, 1]),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("layout", TAILS.values(), ids=TAILS.keys())
+def test_sizes_that_add_up_to_less_than_the_rows(layout, dtype):
+    """Forward, input gradient and weight gradient: the rows past the
+    last group are no group's, are not visited, and read as zero through
+    ``zero_tail``; the weight gradient holds the groups' rows alone."""
+    x, w, dy, sizes = _case(*layout, dtype)
+    total = jnp.sum(sizes)
+
+    def run(x, w):
+        return zero_tail(grouped_matmul(zero_tail(x, total), w, sizes, True),
+                         total)
+
+    out, vjp = jax.vjp(run, x, w)
+    f32 = [a.astype(jnp.float32) for a in (x, w, dy)]
+    with jax.default_matmul_precision("highest"):
+        ref, rvjp = jax.vjp(
+            lambda x, w: grouped_matmul_reference(x, w, sizes), *f32[:2])
+        want = (ref, *rvjp(f32[2]))
+    n = int(total)
+    tol = 1e-5 if dtype == jnp.float32 else 1e-2
+    for g, r, name in zip((out, *vjp(dy)), want, ("out", "dx", "dw")):
+        g = g.astype(jnp.float32)
+        assert bool(jnp.isfinite(g).all()), name
+        if name != "dw":
+            assert not np.asarray(g[n:]).any(), name
+            assert not np.asarray(r[n:]).any(), name
+        scale = max(float(jnp.abs(r).max()), 1e-30)
+        assert float(jnp.abs(g - r).max()) <= tol * scale, name
+
+
+def test_tail_tiles_are_never_visited():
+    sizes = jnp.asarray([100, 60], jnp.int32)
+    (offsets, gid, tid), count = _visits(sizes, 1024, 128, visit_empty=False)
+    n = int(count)
+    assert offsets.tolist() == [0, 100, 160]
+    assert (gid[:n].tolist(), tid[:n].tolist()) == ([0, 1, 1], [0, 0, 1])
+    (_, gid, tid), count = _visits(sizes, 1024, 128, visit_empty=True,
+                                   parts=2)
+    n = int(count)          # two tiles a visit: tiles 0-1 for each group
+    assert (gid[:n].tolist(), tid[:n].tolist()) == ([0, 1], [0, 0])
+    _, count = _visits(jnp.zeros((8,), jnp.int32), 1024, 128,
+                       visit_empty=False)
+    assert int(count) == 0
+
+
 def test_visit_tables():
     sizes = jnp.asarray([100, 0, 56, 100], jnp.int32)
     (offsets, gid, tid), count = _visits(sizes, 256, 128, visit_empty=False)
@@ -185,6 +244,23 @@ def test_the_cells_tilings_hold_a_groups_matrix_block_for_all_its_visits():
         assert acc + 2 * block + rows <= gm._VMEM_LIMIT
     # float32 operands: the same bytes a block, half the columns
     assert tgmm_tiling(1024, 2048, 4096, 4).tn == 1024
+
+
+def test_a_matrix_over_the_slot_is_fetched_in_column_blocks():
+    # the 8k cell: 32,768 (token, choice) rows, an expert's gate-up matrix
+    # 3072 x 6144 (37.7 MB) over the 16 MB slot: the largest block that
+    # divides the columns, not the whole axis
+    for (k, n), tn in (((3072, 6144), 2048), ((6144, 3072), 1024),
+                       ((3072, 3072), 1536)):
+        t = gmm_tiling(32768, k, n, 2)
+        assert (t.tm, t.tk, t.tn) == (128, k, tn)
+        assert 2 * t.tk * t.tn <= gm._W_BLOCK_BYTES
+        ring = gm._W_SLOTS * 2 * t.tk * t.tn
+        assert ring + 2 * 2 * t.tm * (t.tk + t.tn) <= gm._VMEM_LIMIT
+    for n in (6144, 3072):
+        t = tgmm_tiling(32768, 3072, n, 2)
+        assert 3072 % t.tk == 0 and n % t.tn == 0
+        assert 4 * t.tk * t.tn <= 2 * gm._DW_BLOCK_BYTES
 
 
 def test_no_gradient_reaches_the_group_sizes_and_jit_composes():

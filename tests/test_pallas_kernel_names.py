@@ -30,6 +30,15 @@ def _flash_program():
     return _lowered_for_tpu(jax.grad(loss, (0, 1, 2)), q, q, q)
 
 
+def _window_program():
+    def loss(q, k, v):
+        return flash.flash_attention(
+            q, k, v, True, None, False, 100).astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, 256, 4, 128), jnp.bfloat16)
+    return _lowered_for_tpu(jax.grad(loss, (0, 1, 2)), q, q, q)
+
+
 def _grouped_matmul_program():
     def loss(x, w, sizes):
         out = grouped_matmul.grouped_matmul(x, w, sizes, False)
@@ -44,7 +53,8 @@ def _grouped_matmul_program():
 @pytest.mark.parametrize("program, wanted", [
     (_flash_program, chip_smoke.FLASH_KERNELS),
     (_grouped_matmul_program, chip_smoke.MOE_KERNELS),
-], ids=["flash", "grouped_matmul"])
+    (_window_program, flash.kernel_names(100)),
+], ids=["flash", "grouped_matmul", "flash_window"])
 def test_kernel_names_are_the_ones_chip_smoke_requires(program, wanted):
     have = chip_smoke.kernels_in(program())
     assert have == sorted(wanted)
@@ -65,6 +75,43 @@ def test_flash_readers_patterns_match_the_kernel_names():
     for kernel, pattern in patterns.items():
         hits = [k for k, text in shown.items() if re.search(pattern, text)]
         assert hits == [kernel]
+
+
+def _load_reader(name):
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(moe_gmm_roofline.__file__),
+                        name + ".py")
+    spec = importlib.util.spec_from_file_location(
+        "reader_" + name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_window_calls_have_names_the_causal_readers_do_not_match():
+    """A call with a window runs under kernel names of its own: the three
+    causal patterns match none of them (so ``flash_*_roofline.gqa`` keeps
+    reading causal work for causal events), and the window readers'
+    patterns match each its own and no causal kernel."""
+    assert flash.kernel_names() == chip_smoke.FLASH_KERNELS
+    window = flash.kernel_names(4096)
+    assert window == ("flash_window_fwd", "flash_window_bwd_dq",
+                      "flash_window_bwd_dkv")
+    shown = {name: f"%{name}.{i} = bf16[1,48,8192,128]{{3,2,1,0}} custom-call("
+             for i, name in enumerate(chip_smoke.FLASH_KERNELS + window)}
+    fwd = _load_reader("flash_fwd_roofline.window")
+    bwd = _load_reader("flash_bwd_roofline.window")
+    patterns = {"flash_fwd": flash_fwd_roofline.KERNEL,
+                "flash_bwd_dq": flash_bwd_roofline.DQ,
+                "flash_bwd_dkv": flash_bwd_roofline.DKV,
+                "flash_window_fwd": fwd.KERNEL,
+                "flash_window_bwd_dq": bwd.DQ,
+                "flash_window_bwd_dkv": bwd.DKV}
+    for kernel, pattern in patterns.items():
+        hits = [k for k, text in shown.items() if re.search(pattern, text)]
+        assert hits == [kernel], (kernel, hits)
 
 
 def test_grouped_matmul_readers_patterns_match_the_kernel_names():
