@@ -316,3 +316,13 @@ class AfmoeForCausalLM(nn.Layer):
         return jnp.stack([e.pairs_routed._data if "pairs_routed" in e._buffers
                           else jnp.sum(e.tokens_per_expert._data)
                           for e in experts])
+
+    def calls_in_full(self):
+        """[routed blocks] int32: the calls in which a block that holds a
+        share was sent more pairs than its bound on the rows it computes
+        at a time (``nn.RoutedExperts``); where every expert is held
+        there is no bound to pass: zeros."""
+        experts = [layer.mlp.experts for layer in self.routed_layers()]
+        return jnp.stack([e.calls_in_full._data
+                          if "calls_in_full" in e._buffers
+                          else jnp.zeros([], jnp.int32) for e in experts])

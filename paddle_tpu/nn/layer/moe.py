@@ -15,6 +15,23 @@ dropless, whatever number that is: the pairs of the absent experts sort
 last, belong to no group, are never computed and add zero. No code
 stands in for the absent chips or their traffic.
 
+A share computes a BOUNDED number of rows at a time. The sorted order
+puts the held experts' pairs first, so a static window of it holds all
+of them whenever their number (``sum(sizes)``, on the device) is at most
+the window's length: ``row_bound``, four even shares of the layer's
+pairs rounded up to the row tile — 4,096 rows for 32,768 pairs where 8
+of 256 experts are held. The share's path is a loop over such windows
+for as many as hold its pairs (one, unless a routing sends it more than
+its bound; then two, three, ...: no pair is dropped at any routing): a
+window gathers its rows straight from ``x``, runs the grouped matmuls and
+SwiGLU over them and adds each row times its pair's gate into its
+token's row in float32. The trip count is data, so the loop is the
+share's own custom VJP: the backward walks the same windows, runs each
+one's forward again and adds up the gradients; a step holds no value of
+every pair's rows anywhere. Where the bound would not halve the rows, and
+where every expert is held, there is no loop and the layer traces as it
+did without one (a row for every pair).
+
 ``MLPRouter``: a down-projection and a three-layer GELU MLP to E logits,
 computed in float32 whatever the model's storage type, with a selection
 bias ``beta`` (a float32 buffer: no gradient reaches it) that moves
@@ -32,6 +49,7 @@ is the older of the two and is left as it is; ROADMAP D5.)
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -97,25 +115,38 @@ _gradient_tail.defvjp(lambda a, rows: (a, rows),
                       lambda rows, g: (_zero_tail(g, rows), None))
 
 
-def routed_experts(x, w_gu, w_dn, ids, gates, num_experts: int, first: int):
-    """x [T, H], w_gu [E, H, 2F] (gate | up), w_dn [E, F, H], ids / gates
-    [T, k] -> (out [T, H], tokens per expert [E] int32). ``ids`` run over
-    ``num_experts``; the E held ones are ``first .. first + E``."""
+# A share computes rows for this many EVEN shares of the layer's pairs at
+# a time; a routing that sends it more takes a window more. From the
+# records, not a knob: over a training window the held experts got
+# 1.24-1.45 even shares (PERF.md section 6), and the loop is exact at any.
+_BOUND_SHARES = 4
+_ROW_TILE = 256    # a multiple of it satisfies ``gmm_tiling`` and ``tgmm_tiling``
+
+
+def row_bound(pairs: int, held: int, num_experts: int):
+    """The static bound on the rows a share of ``held`` of ``num_experts``
+    computes at a time for ``pairs`` (token, choice) pairs:
+    ``_BOUND_SHARES`` even shares, rounded up to the row tile. None where
+    the bound would not halve the rows (or every expert is held): a row
+    for every pair, no loop."""
+    if held >= num_experts:
+        return None
+    rows = -(-_BOUND_SHARES * pairs * held // num_experts)
+    cap = -(-rows // _ROW_TILE) * _ROW_TILE
+    return cap if 2 * cap <= pairs else None
+
+
+def _swiglu(gu, f: int):
+    return (jax.nn.silu(gu[:, :f].astype(jnp.float32))
+            * gu[:, f:].astype(jnp.float32)).astype(gu.dtype)
+
+
+def _every_pair(x, w_gu, w_dn, gates, order, inverse, sizes, share: bool):
+    """A row for every (token, choice) pair, whatever the routing."""
     from ...ops.grouped_matmul import grouped_matmul
 
-    t, k = ids.shape
-    held, f = w_gu.shape[0], w_dn.shape[1]
-    share = held < num_experts
+    t, k = gates.shape
     with jax.named_scope("moe.permute"):
-        flat = ids.reshape(t * k)
-        if share:   # the absent experts' pairs sort last, in no group
-            flat = jnp.where((flat >= first) & (flat < first + held),
-                             flat - first, held)
-        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
-        sizes = jnp.bincount(flat, length=held + 1 if share else held)
-        sizes = (sizes[:held] if share else sizes).astype(jnp.int32)
         rows = x if k == 1 else jnp.repeat(x, k, axis=0)
         rows = _permute_rows(rows, order, inverse)
     if share:
@@ -130,14 +161,136 @@ def routed_experts(x, w_gu, w_dn, ids, gates, num_experts: int, first: int):
         result = lambda a: a
     with jax.named_scope("moe.experts"):
         gu = result(grouped_matmul(rows, w_gu, sizes))
-        act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
-               * gu[:, f:].astype(jnp.float32)).astype(x.dtype)
-        y = result(grouped_matmul(act, w_dn, sizes))
+        y = result(grouped_matmul(_swiglu(gu, w_dn.shape[1]), w_dn, sizes))
     with jax.named_scope("moe.combine"):
         y = _permute_rows(y, inverse, order).reshape(t, k, -1)
         out = jnp.sum(y.astype(jnp.float32)
                       * gates.astype(jnp.float32)[..., None], axis=1)
-    return out.astype(x.dtype), sizes
+    return out.astype(x.dtype)
+
+
+def _window(order, sizes, cap: int, i):
+    """Window ``i`` of the sorted order, ``cap`` pairs long: (the pairs
+    [cap], the rows each group has inside it [E], their sum)."""
+    lo = i * cap
+    ends = jnp.cumsum(sizes)
+    inside = (jnp.clip(ends, lo, lo + cap)
+              - jnp.clip(ends - sizes, lo, lo + cap)).astype(jnp.int32)
+    return jax.lax.dynamic_slice(order, (lo,), (cap,)), inside, jnp.sum(inside)
+
+
+def _window_rows(rows, w_gu, w_dn, gates, pairs, sizes, total):
+    """A window's rows (the tokens' own, gathered straight from ``x``) to
+    what each adds to its token's output row [cap, H] float32: the two
+    grouped matmuls with their tails (a select costs a pass over the rows,
+    so only where a kernel's unwritten rows would be read), then each row
+    times its pair's gate."""
+    from ...ops.grouped_matmul import grouped_matmul
+
+    with jax.named_scope("moe.experts"):
+        gu = _result_tail(grouped_matmul(rows, w_gu, sizes), total)
+        y = _result_tail(grouped_matmul(
+            _swiglu(gu, w_dn.shape[1]), w_dn, sizes), total)
+    with jax.named_scope("moe.combine"):
+        of_row = gates.reshape(-1)[pairs].astype(jnp.float32)
+        return y.astype(jnp.float32) * of_row[:, None]
+
+
+def _windows(cap: int, order, sizes):
+    """(how many windows of ``cap`` pairs hold the held experts' pairs,
+    the sorted order padded to whole windows)."""
+    return ((jnp.sum(sizes) + cap - 1) // cap,
+            jnp.pad(order, (0, -order.shape[0] % cap)))
+
+
+# Jitted, as the flash kernels' launchers are, so that a model's layers
+# share ONE trace and ONE lowering of the two loops.
+@functools.partial(jax.jit, static_argnums=0)
+def _share_forward(cap, x, w_gu, w_dn, gates, order, sizes):
+    k = gates.shape[1]
+    count, order = _windows(cap, order, sizes)
+
+    def window(i, out):
+        pairs, inside, total = _window(order, sizes, cap, i)
+        with jax.named_scope("moe.permute"):
+            rows = x[pairs // k]
+        h = _window_rows(rows, w_gu, w_dn, gates, pairs, inside, total)
+        with jax.named_scope("moe.combine"):
+            return out.at[pairs // k].add(h)
+
+    out = jax.lax.fori_loop(0, count, window,
+                            jnp.zeros(x.shape, jnp.float32))
+    return out.astype(x.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _share_backward(cap, x, w_gu, w_dn, gates, order, sizes, g):
+    k = gates.shape[1]
+    count, order = _windows(cap, order, sizes)
+
+    def window(i, grads):
+        pairs, inside, total = _window(order, sizes, cap, i)
+        with jax.named_scope("moe.permute"):
+            rows = x[pairs // k]
+        _, pull = jax.vjp(                     # the window's forward again
+            lambda *a: _window_rows(*a, pairs, inside, total),
+            rows, w_gu, w_dn, gates)
+        with jax.named_scope("moe.combine"):
+            d, *new = pull(g[pairs // k].astype(jnp.float32))
+        with jax.named_scope("moe.permute"):
+            # the input-gradient kernel leaves the rows past the window's
+            # last group unwritten
+            dx = grads[0].at[pairs // k].add(
+                _zero_tail(d, total).astype(jnp.float32))
+        return (dx, *[a + b for a, b in zip(grads[1:], new)])
+
+    dx, *rest = jax.lax.fori_loop(
+        0, count, window,
+        (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(w_gu),
+         jnp.zeros_like(w_dn), jnp.zeros_like(gates)))
+    return (dx.astype(x.dtype), *rest)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _bounded_share(cap, x, w_gu, w_dn, gates, order, sizes):
+    """A share's output, window by window of ``cap`` pairs of the sorted
+    order for as many windows as hold the held experts' pairs — one,
+    unless a routing sends the share more than its bound. The trip count
+    is on the device, so the loop has a backward of its own: the same
+    windows again, each running its forward once more (the residuals are
+    the inputs) and adding its part of every gradient."""
+    return _share_forward(cap, x, w_gu, w_dn, gates, order, sizes)
+
+
+_bounded_share.defvjp(
+    lambda cap, *args: (_share_forward(cap, *args), args),
+    lambda cap, args, g: (*_share_backward(cap, *args, g), None, None))
+
+
+def routed_experts(x, w_gu, w_dn, ids, gates, num_experts: int, first: int):
+    """x [T, H], w_gu [E, H, 2F] (gate | up), w_dn [E, F, H], ids / gates
+    [T, k] -> (out [T, H], tokens per expert [E] int32). ``ids`` run over
+    ``num_experts``; the E held ones are ``first .. first + E``."""
+    t, k = ids.shape
+    held = w_gu.shape[0]
+    share = held < num_experts
+    cap = row_bound(t * k, held, num_experts)
+    with jax.named_scope("moe.permute"):
+        flat = ids.reshape(t * k)
+        if share:   # the absent experts' pairs sort last, in no group
+            flat = jnp.where((flat >= first) & (flat < first + held),
+                             flat - first, held)
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        if cap is None:
+            inverse = jnp.zeros_like(order).at[order].set(
+                jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
+        sizes = jnp.bincount(flat, length=held + 1 if share else held)
+        sizes = (sizes[:held] if share else sizes).astype(jnp.int32)
+    if cap is None:
+        out = _every_pair(x, w_gu, w_dn, gates, order, inverse, sizes, share)
+    else:
+        out = _bounded_share(cap, x, w_gu, w_dn, gates, order, sizes)
+    return out, sizes
 
 
 class RoutedExperts(Layer):
@@ -147,8 +300,11 @@ class RoutedExperts(Layer):
     ``tokens_per_expert`` (int32 [held], a buffer on the device) adds up
     how many rows each held expert was given, call by call; a layer that
     holds a share also adds up ``pairs_routed``, every (token, choice)
-    pair it saw (its rows no longer add up to them). Nothing reads them
-    back but whoever asks (``numpy()``)."""
+    pair it saw (its rows no longer add up to them), and
+    ``calls_in_full``, the calls in which the held experts' pairs passed
+    ``row_bound`` and took more than one window of rows (all of its calls
+    where the shapes allow no bound: a row for every pair). Nothing reads
+    them back but whoever asks (``numpy()``)."""
 
     def __init__(self, hidden_size: int, intermediate_size: int,
                  num_experts: int, held: int = None, first: int = 0):
@@ -168,8 +324,9 @@ class RoutedExperts(Layer):
         self.register_buffer("tokens_per_expert", Tensor(
             jnp.zeros([held], jnp.int32), _internal=True))
         if held < num_experts:
-            self.register_buffer("pairs_routed", Tensor(
-                jnp.zeros([], jnp.int32), _internal=True))
+            for name in ("pairs_routed", "calls_in_full"):
+                self.register_buffer(name, Tensor(
+                    jnp.zeros([], jnp.int32), _internal=True))
 
     def compute(self, x, ids, gates):
         """The layer without its counters: (out, rows each held expert
@@ -192,6 +349,9 @@ class RoutedExperts(Layer):
             self.tokens_per_expert._data + sizes._data)
         if "pairs_routed" in self._buffers:
             self.pairs_routed.set_value(self.pairs_routed._data + pairs)
+            cap = row_bound(pairs, sizes.shape[0], self.num_experts)
+            in_full = 1 if cap is None else jnp.sum(sizes._data) > cap
+            self.calls_in_full.set_value(self.calls_in_full._data + in_full)
 
     def forward(self, x, ids, gates):
         out, sizes = self.compute(x, ids, gates)

@@ -162,16 +162,101 @@ def test_the_train_step_compiles_with_recomputed_mlp_halves(cfg, ids):
     assert first.shape == (3, 2, 64)
 
 
+# -- a share small enough to have a bound on its rows --------------------------
+
+
+@pytest.fixture(scope="module")
+def bounded_cfg(cfg):
+    """The toy with 64 experts, four a token, of which experts 9-11 are
+    held: 2 x 64 tokens make 512 pairs a routed block, an even share of
+    24, a bound of 256 rows (``nn.layer.moe.row_bound``), so each block's
+    routed half is the loop over windows of 256 of its 512 pairs. The
+    seeded routing gives the three 10 to 70 pairs a block; a bias can
+    give them up to 384."""
+    return dict(cfg, num_experts=64, num_experts_per_tok=4,
+                held=dict(cfg["held"], experts=3, first_expert=9))
+
+
+@pytest.fixture(scope="module")
+def bounded_wanted(bounded_cfg, ids):
+    reference = afmoe.reference(bounded_cfg, SEED)
+    want = {}
+    for group, grads in reference.loss_and_grads(reference.get, *ids):
+        want.update({f"{group}/{k}": np.asarray(v) for k, v in grads.items()})
+    return reference.loss, want
+
+
+@pytest.mark.parametrize("recompute", ["none", "mlp"])
+def test_a_bounded_share_gives_the_references_loss_and_gradients(
+        bounded_cfg, bounded_wanted, ids, recompute):
+    """One window (256 rows for 512 pairs) in every routed block,
+    recomputed or not: the uncut float32 reference's held part, loss and
+    every leaf's gradient; no call past the bound, the counters as ever."""
+    from paddle_tpu.nn.layer.moe import row_bound
+
+    assert row_bound(2 * 64 * 4, 3, 64) == 256
+    model, params = _program(dict(bounded_cfg,
+                                  training={"recompute": recompute}))
+    loss = model.loss(paddle.to_tensor(ids[0]), paddle.to_tensor(ids[1]))
+    loss.backward()
+    ref_loss, want = bounded_wanted
+    assert abs(float(loss) - ref_loss) <= 1e-5 * ref_loss
+    names = [f"{leaf[0]}/{leaf[1]}" for leaf in afmoe.leaves(bounded_cfg)]
+    assert sorted(names) == sorted(want)
+    for name, p in zip(names, params):
+        scale = np.abs(want[name]).max()
+        assert scale > 0, name
+        assert np.abs(np.asarray(p.grad._data) - want[name]).max() \
+            <= 1e-4 * scale, name
+    assert (np.asarray(model.calls_in_full()) == 0).all()
+    assert (np.asarray(model.pairs_routed()) == 512).all()
+    rows = np.asarray(model.tokens_per_expert()).sum(-1)
+    assert ((0 < rows) & (rows < 256)).all()
+
+
+def test_the_compiled_step_takes_the_bounded_path_and_the_full_one(
+        bounded_cfg, bounded_wanted, ids):
+    """``jit.to_static`` over model and AdamW in float32: step one's loss
+    and every leaf's gradient norm (from the first moment) are the
+    reference's through the compiled loop. Then a bias sends every token
+    to all three held experts (384 pairs, over the bound: a second window,
+    every one of them counted), then to two (256: at the bound)."""
+    o = dict(bounded_cfg["optimizer"], stochastic_rounding=False,
+             moment_dtype="float32")
+    trainer = afmoe.Trainer(
+        dict(bounded_cfg, optimizer=o, training={"recompute": "mlp"},
+             router_balancing={"rate": 0}), SEED)
+    trainer.model.float()     # the seeded bfloat16 weights, widened
+    ref_loss, want = bounded_wanted
+    assert abs(trainer.step(*ids) - ref_loss) <= 1e-5 * ref_loss
+    for name, got in trainer.grad_norms().items():
+        norm = float(np.linalg.norm(want[name]))
+        assert abs(got - norm) <= 1e-5 * norm, name
+    model = trainer.model
+    assert (np.asarray(model.calls_in_full()) == 0).all()
+    for bias_of_third, rows, in_full in ((10.0, 384, 1), (-10.0, 256, 1)):
+        for layer in model.routed_layers():
+            bias = layer.mlp.router.bias
+            bias._data = bias._data.at[9:11].set(10.0).at[11].set(
+                bias_of_third)
+        before = np.asarray(model.tokens_per_expert()).sum(-1)
+        assert np.isfinite(trainer.step(*ids))
+        assert (np.asarray(model.tokens_per_expert()).sum(-1) - before
+                == rows).all()
+        assert (np.asarray(model.calls_in_full()) == in_full).all()
+    assert (np.asarray(model.pairs_routed()) == 3 * 512).all()
+
+
 # -- piece by piece ----------------------------------------------------------
 
 
-def _moe_weights(seed, h=32, f=16, e=8):
+def _moe_weights(seed, h=32, f=16, e=8, t=48):
     ks = jax.random.split(jax.random.key(seed), 8)
     n = lambda k, *s: 0.3 * jax.random.normal(k, s, jnp.float32)
     return {"router.w": n(ks[0], h, e), "router.bias": 0.1 * n(ks[1], e),
             "shared.w1": n(ks[2], h, f), "shared.w3": n(ks[3], h, f),
             "shared.w2": n(ks[4], f, h), "experts.w_gu": n(ks[5], e, h, 2 * f),
-            "experts.w_dn": n(ks[6], e, f, h)}, n(ks[7], 48, h)
+            "experts.w_dn": n(ks[6], e, f, h)}, n(ks[7], t, h)
 
 
 def _share(p, first, held, h=32, f=16, e=8, k=3):
@@ -192,27 +277,39 @@ def _share(p, first, held, h=32, f=16, e=8, k=3):
     return moe
 
 
-@pytest.mark.parametrize("held", [2, 4, 8])
-def test_the_shares_add_up(held):
+@pytest.mark.parametrize("held, e, k, t", [
+    (2, 8, 3, 48), (4, 8, 3, 48), (8, 8, 3, 48), (4, 64, 4, 256)],
+    ids=["2-of-8", "4-of-8", "8-of-8", "4-of-64-bounded"])
+def test_the_shares_add_up(held, e, k, t):
     """The routed parts that all shares give, with the shared expert
     counted once, are the uncut reference's layer: 4 shares of 2 experts
-    of 8 (or 2 of 4, or the one that holds all)."""
-    p, m = _moe_weights(5)
+    of 8 (or 2 of 4, or the one that holds all); and 16 shares of 4 of
+    64, each with a bound of 256 rows on its 1,024 pairs, where a bias
+    towards experts 0-3 sends the first share past its bound."""
+    p, m = _moe_weights(5, e=e, t=t)
+    if e == 64:
+        p["router.bias"] = p["router.bias"].at[:4].add(0.5)
     with jax.default_matmul_precision("highest"):
-        whole, _ = ar.moe(p, m, top_k=3, scale=2.448, first=0)
+        whole, _ = ar.moe(p, m, top_k=k, scale=2.448, first=0)
         shared = ar.swiglu(m, p["shared.w1"], p["shared.w3"], p["shared.w2"],
                            "f32")
-        total, rows = 0.0, 0
+        total, rows, in_full = 0.0, 0, []
         with no_grad():
-            for first in range(0, 8, held):
-                moe = _share(p, first, held)
+            for first in range(0, e, held):
+                moe = _share(p, first, held, e=e, k=k)
                 f, ids, sizes = moe.compute(paddle.to_tensor(m))
                 total = total + (f._data - shared)
                 rows += int(sizes._data.sum())
                 assert sizes.shape == [held]
-    assert rows == 48 * 3                 # every pair met exactly one share
+                if held < e:
+                    moe.experts.count(sizes, t * k)
+                    in_full.append(int(moe.experts.calls_in_full._data))
+    assert rows == t * k                  # every pair met exactly one share
     assert float(jnp.abs(total + shared - whole).max()) \
         <= 1e-5 * float(jnp.abs(whole).max())
+    # a share of the 8 has no bound (it would not halve its rows); of the
+    # 64, the first alone passed its bound and took a second window
+    assert in_full == ([1] + [0] * 15 if e == 64 else [1] * len(in_full))
 
 
 def test_a_share_with_none_of_a_batchs_experts_adds_the_shared_expert_alone():

@@ -137,6 +137,10 @@ GROUPED = {
     # matrices over the 16 MB slot: fetched in column blocks
     "cell-trinity-gate-up": (32768, 3072, 6144, 8, jnp.bfloat16),
     "cell-trinity-down": (32768, 3072, 3072, 8, jnp.bfloat16),
+    # ... and on the 4,096 rows a share's bound gives them (PR 33:
+    # ``nn.layer.moe.row_bound``), the step's usual path
+    "cell-trinity-gate-up-bounded": (4096, 3072, 6144, 8, jnp.bfloat16),
+    "cell-trinity-down-bounded": (4096, 3072, 3072, 8, jnp.bfloat16),
 }
 
 

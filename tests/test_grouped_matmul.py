@@ -305,3 +305,176 @@ def test_routed_experts_is_dropless_and_matches_a_dense_loop():
     counts = np.asarray(layer.tokens_per_expert._data)
     assert counts.sum() == t * k                 # every pair computed once
     assert (counts == np.bincount(ids.reshape(-1), minlength=e)).all()
+
+
+# -- a share of the experts computes a bounded number of rows ----------------
+
+# 256 tokens x 4 choices over 64 experts, 2 of them held: 1,024 pairs, an even
+# share of 32, so the share computes 256 rows (the row tile) for 1,024
+BOUND = dict(t=256, h=32, f=16, e=64, held=2, k=4, first=5)
+
+
+def _bounded_case(held_pairs, seed=0):
+    """(x, w_gu, w_dn, ids, gates): the first ``held_pairs`` (token, choice)
+    pairs of choices 0-1 go to the two held experts, every other pair to
+    an absent one."""
+    b = BOUND
+    rng = np.random.default_rng(seed)
+    n = lambda *s: jnp.asarray(0.3 * rng.standard_normal(s), jnp.float32)
+    ids = np.tile(np.arange(20, 20 + b["k"], dtype=np.int32), (b["t"], 1))
+    mine = np.arange(held_pairs)
+    ids[mine // 2, mine % 2] = b["first"] + mine % 2
+    gates = jnp.asarray(rng.uniform(0.1, 1.0, ids.shape), jnp.float32)
+    return (n(b["t"], b["h"]), n(b["held"], b["h"], 2 * b["f"]),
+            n(b["held"], b["f"], b["h"]), jnp.asarray(ids), gates)
+
+
+def _dense_loop(x, w_gu, w_dn, ids, gates, first):
+    """Every held expert on every token, kept where chosen."""
+    f, out = w_dn.shape[1], jnp.zeros_like(x)
+    for e in range(w_gu.shape[0]):
+        gu = x @ w_gu[e]
+        y = (jax.nn.silu(gu[:, :f]) * gu[:, f:]) @ w_dn[e]
+        out = out + jnp.sum(jnp.where(ids == first + e, gates, 0.0),
+                            axis=-1)[:, None] * y
+    return out
+
+
+@pytest.mark.parametrize("held_pairs, in_full",
+                         [(0, 0), (40, 0), (256, 0), (257, 1), (512, 1)],
+                         ids=["none", "few", "at-the-bound", "one-over",
+                              "every-token-twice"])
+def test_a_bounded_share_drops_no_pair_at_the_bound_or_beyond_it(
+        held_pairs, in_full):
+    """Up to the bound the share computes one window of 256 rows, past
+    it as many as hold its pairs: the output and every gradient are the
+    dense loop's either way, and ``calls_in_full`` counts the calls that
+    passed the bound."""
+    import paddle_tpu as paddle
+    from paddle_tpu import nn
+    from paddle_tpu.nn.layer import moe
+
+    b = BOUND
+    assert moe.row_bound(b["t"] * b["k"], b["held"], b["e"]) == 256
+    x, w_gu, w_dn, ids, gates = _bounded_case(held_pairs)
+    layer = nn.RoutedExperts(b["h"], b["f"], b["e"], b["held"], b["first"])
+    layer.w_gu._data, layer.w_dn._data = w_gu, w_dn
+    t = paddle.to_tensor
+    for _ in range(2):
+        out = layer(t(x), t(ids), t(gates))._data
+    assert int(layer.calls_in_full._data) == 2 * in_full
+    assert int(layer.pairs_routed._data) == 2 * b["t"] * b["k"]
+    counts = np.asarray(layer.tokens_per_expert._data)
+    assert (counts == [held_pairs + 1 >> 1 << 1, held_pairs >> 1 << 1]).all()
+
+    def loss(run):
+        return lambda *a: jnp.sum(jnp.square(run(*a)))
+
+    program = lambda x, w_gu, w_dn, gates: moe.routed_experts(
+        x, w_gu, w_dn, ids, gates, b["e"], b["first"])[0]
+    dense = lambda x, w_gu, w_dn, gates: _dense_loop(
+        x, w_gu, w_dn, ids, gates, b["first"])
+    with jax.default_matmul_precision("highest"):
+        want = dense(x, w_gu, w_dn, gates)
+        want_grads = jax.grad(loss(dense), (0, 1, 2, 3))(x, w_gu, w_dn, gates)
+    grads = jax.jit(jax.grad(loss(program), (0, 1, 2, 3)))(
+        x, w_gu, w_dn, gates)
+    scale = float(jnp.abs(want).max())
+    assert float(jnp.abs(out - want).max()) <= 1e-5 * max(scale, 1.0)
+    for got, w, name in zip(grads, want_grads, ("x", "w_gu", "w_dn", "gates")):
+        assert bool(jnp.isfinite(got).all()), name
+        assert float(jnp.abs(got - w).max()) \
+            <= 1e-5 * max(float(jnp.abs(w).max()), 1.0), name
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for j in (v if isinstance(v, (tuple, list)) else (v,)):
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _shapes(jaxpr, loops):
+    """The shape of every value in ``jaxpr`` and all it holds; ``loops``
+    is given each ``while`` met that holds a grouped matmul."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "while" and "moe_gmm" in str(eqn):
+            loops.append(eqn)
+        for v in eqn.outvars:
+            yield tuple(getattr(v.aval, "shape", ()))
+        for sub in _sub_jaxprs(eqn):
+            yield from _shapes(sub, loops)
+
+
+@pytest.mark.parametrize("recompute", [False, True],
+                         ids=["none", "recomputed"])
+def test_a_bounded_share_holds_no_row_for_every_pair(recompute):
+    """Forward + backward of a bounded share: no value of T*k rows (and
+    H, F or 2F columns) anywhere, residuals included — the windows are
+    256 rows — and the loops over windows are the forward's (twice where
+    its output is needed again) and the backward's."""
+    from paddle_tpu.nn.layer import moe
+
+    b = BOUND
+    x, w_gu, w_dn, ids, gates = _bounded_case(40)
+    run = lambda x, w_gu, w_dn, gates: moe.routed_experts(
+        x, w_gu, w_dn, ids, gates, b["e"], b["first"])[0]
+    if recompute:   # ... of a function whose backward needs the output
+        share, run = run, jax.checkpoint(lambda *a: jnp.tanh(share(*a)))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(jnp.square(run(*a))), (0, 1, 2, 3)))(
+            x, w_gu, w_dn, gates)
+    loops = []
+    shapes = set(_shapes(jaxpr.jaxpr, loops))
+    pairs = b["t"] * b["k"]
+    assert not {s for s in shapes if len(s) == 2 and s[0] == pairs
+                and s[1] > 1}
+    assert {(256, b["h"]), (256, b["f"]), (256, 2 * b["f"])} <= shapes
+    assert len(loops) == (3 if recompute else 2)
+
+
+def test_with_every_expert_held_the_trace_is_the_one_before_shares():
+    """``routed_experts`` with all experts held (``models/zaya.py``'s
+    use, and every test that holds all) traces, forward and backward,
+    to the ops it traced to before a share had a bound or a branch: the
+    body below is that one, as PR 32's parent had it."""
+    from paddle_tpu.nn.layer import moe
+
+    def before(x, w_gu, w_dn, ids, gates):
+        t, k = ids.shape
+        held, f = w_gu.shape[0], w_dn.shape[1]
+        flat = ids.reshape(t * k)
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
+        sizes = jnp.bincount(flat, length=held).astype(jnp.int32)
+        rows = x if k == 1 else jnp.repeat(x, k, axis=0)
+        rows = moe._permute_rows(rows, order, inverse)
+        gu = grouped_matmul(rows, w_gu, sizes)
+        act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
+               * gu[:, f:].astype(jnp.float32)).astype(x.dtype)
+        y = grouped_matmul(act, w_dn, sizes)
+        y = moe._permute_rows(y, inverse, order).reshape(t, k, -1)
+        out = jnp.sum(y.astype(jnp.float32)
+                      * gates.astype(jnp.float32)[..., None], axis=1)
+        return out.astype(x.dtype), sizes
+
+    rng = np.random.default_rng(1)
+    for k in (1, 2):
+        t, h, f, e = 128, 32, 16, 4
+        n = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.bfloat16)
+        ids = jnp.asarray(rng.integers(0, e, (t, k)), jnp.int32)
+        gates = jnp.asarray(rng.uniform(0.1, 1.0, (t, k)), jnp.float32)
+        args = (n(t, h), n(e, h, 2 * f), n(e, f, h))
+
+        def traced(run):
+            def loss(x, w_gu, w_dn, gates):
+                out, sizes = run(x, w_gu, w_dn, ids, gates)
+                return jnp.sum(jnp.square(out.astype(jnp.float32))), sizes
+            return str(jax.make_jaxpr(jax.value_and_grad(
+                loss, (0, 1, 2, 3), has_aux=True))(*args, gates))
+
+        now = traced(lambda *a: moe.routed_experts(*a, e, 0))
+        assert now == traced(before), k
+        assert "moe_gmm" in now
