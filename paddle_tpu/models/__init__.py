@@ -5,7 +5,10 @@ positions (config #4); bert: bidirectional encoder + MLM head
 (config #2); zaya: compressed convolutional attention + top-1 routed
 experts, training path only; afmoe: window and full gated attention
 mixed, a sigmoid top-k router beside a shared expert, a held share of the
-experts, training path only; vision models live in paddle_tpu.vision (config #1).
+experts, training path only; qwen3_next: Gated DeltaNet and gated full
+attention mixed 3:1, a softmax top-k router beside a gated shared expert,
+a held share, training path only; vision models live in paddle_tpu.vision
+(config #1).
 """
 from .llama import (  # noqa: F401
     LlamaConfig,
@@ -31,6 +34,12 @@ from .afmoe import (  # noqa: F401
     AfmoeDecoderLayer,
     AfmoeForCausalLM,
     AfmoeModel,
+)
+from .qwen3_next import (  # noqa: F401
+    Qwen3NextConfig,
+    Qwen3NextDecoderLayer,
+    Qwen3NextForCausalLM,
+    Qwen3NextModel,
 )
 from .unet import UNet2DConditionModel, UNetConfig  # noqa: F401
 from .generation import generate  # noqa: F401

@@ -444,3 +444,41 @@ class SigmoidTopKRouter(Layer):
             lambda m, w, b: sigmoid_topk_router(
                 m, w, b, top_k=self.top_k, scale=self.scale, norm=self.norm),
             m, self.weight, self.bias, op_name="sigmoid_topk_router")
+
+
+def softmax_topk_router(m, w, *, top_k: int, norm: bool):
+    """m [.., H] -> (ids [.., k] int32, gates [.., k] float32):
+    probabilities ``softmax(m w)`` over ALL the experts in float32 at full
+    matmul precision; the ``top_k`` largest are chosen (best first), the
+    gate of a chosen expert is its probability, over the chosen ones' sum
+    under ``norm``."""
+    f32 = jnp.float32
+    probs = jax.nn.softmax(jnp.matmul(m.astype(f32), w.astype(f32),
+                                      precision=_HIGHEST), axis=-1)
+    gates, ids = jax.lax.top_k(probs, top_k)
+    if norm:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return ids.astype(jnp.int32), gates
+
+
+class SoftmaxTopKRouter(Layer):
+    """``forward(m [.., H])`` -> (ids [.., k], gates [.., k]): softmax
+    over all the experts, THEN the k largest, their probabilities
+    renormalised to sum to one (``norm_topk_prob``)."""
+
+    def __init__(self, hidden_size: int, num_experts: int, top_k: int,
+                 norm_topk_prob: bool = True):
+        super().__init__()
+        self.top_k, self.norm = top_k, norm_topk_prob
+        self.weight = self.create_parameter(
+            [hidden_size, num_experts],
+            default_initializer=I.Normal(0.0, 0.02))
+
+    def forward(self, m):
+        return apply(
+            lambda m, w: softmax_topk_router(
+                m, w, top_k=self.top_k, norm=self.norm),
+            m, self.weight, op_name="softmax_topk_router")
+
+
+__all__ += ["SoftmaxTopKRouter"]

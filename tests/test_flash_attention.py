@@ -217,8 +217,9 @@ class TestTileSchedule:
         (128, 77, 2, 1, 64, False),     # cross-attention, sk whole
         (256, 256, 1, 1, 256, True),    # D = 256
         (256, 256, 1, 1, 128, True),    # D = 128
+        (256, 256, 16, 2, 256, True),   # D = 256 under GQA 16 : 2
     ], ids=["sq<sk", "rect-partly-dead", "dead-tile", "gqa", "noncausal",
-            "cross77", "d256", "d128"])
+            "cross77", "d256", "d128", "d256-gqa16:2"])
     def test_unrolled_schedule_matches_naive(self, sq, sk, hq, hkv, d, causal):
         self._parity(sq, sk, hq, hkv, d, causal)
 
@@ -227,7 +228,8 @@ class TestTileSchedule:
         (256, 512, 2, 1, 64, True),     # off > 0, GQA
         (384, 384, 1, 1, 64, True),     # 128 x 128, dead blocks clamped
         (256, 384, 1, 1, 64, False),    # non-causal through the loop
-    ], ids=["rect", "sq<sk-gqa", "dead-blocks", "noncausal"])
+        (256, 256, 8, 1, 256, True),    # D = 256, GQA 8 : 1, the loop
+    ], ids=["rect", "sq<sk-gqa", "dead-blocks", "noncausal", "d256-gqa8"])
     def test_looped_schedule_matches_naive(self, looped, sq, sk, hq, hkv, d,
                                            causal):
         plan = tile_plan(sq, sk, d, causal, itemsize=4)

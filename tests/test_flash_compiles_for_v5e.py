@@ -23,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from chipbench.layer_metrics import (flash_bwd_roofline, flash_fwd_roofline,
                                      moe_gmm_roofline)
 from paddle_tpu.ops.flash_attention import flash_attention, kernel_names
+from paddle_tpu.ops.gated_delta_rule import gated_delta_rule
 from paddle_tpu.ops.grouped_matmul import grouped_matmul
 
 # the patterns the benchmark's flash readers find the kernels by in a
@@ -73,6 +74,9 @@ SHAPES = {
     "float32": (2048, 2048, 2, 2, 128, True, jnp.float32),
     # train-trinity-5l-8k's one full layer: GQA 48/8 at 8192, the loop
     "cell-trinity-full": (8192, 8192, 48, 8, 128, True, jnp.bfloat16),
+    # train-qwen3next-4l-16k's one full layer: GQA 16/2 at d 256 and
+    # 16,384 keys: the loop, on tiles half as long as d 128's
+    "cell-qwen3next-full": (16384, 16384, 16, 2, 256, True, jnp.bfloat16),
 }
 
 # (sq, sk, q heads, kv heads, d_head, window, dtype): a sliding window
@@ -141,6 +145,10 @@ GROUPED = {
     # ``nn.layer.moe.row_bound``), the step's usual path
     "cell-trinity-gate-up-bounded": (4096, 3072, 6144, 8, jnp.bfloat16),
     "cell-trinity-down-bounded": (4096, 3072, 3072, 8, jnp.bfloat16),
+    # train-qwen3next-4l-16k: 64 held experts of width 512 on a window of
+    # 81,920 rows (four even shares of 163,840 pairs), ~320 live a group
+    "cell-qwen3next-gate-up": (81920, 2048, 1024, 64, jnp.bfloat16),
+    "cell-qwen3next-down": (81920, 512, 2048, 64, jnp.bfloat16),
 }
 
 
@@ -164,3 +172,37 @@ def test_grad_of_the_grouped_matmul_compiles_for_a_v5e(one_chip, shape):
     assert sum(bool(re.search(moe_gmm_roofline.GMM, c)) for c in calls) == 2
     assert sum(bool(re.search(moe_gmm_roofline.TGMM, c)) for c in calls) == 1
     assert len(calls) == 3
+
+
+# (sequence, key heads, value heads, dtype): the gated delta rule
+RECURRENCES = {
+    # train-qwen3next-4l-16k's three linear layers: 16 key / 32 value
+    # heads of 128 over 16,384 tokens, 64 grid steps of four chunks a head
+    "cell-qwen3next": (16384, 16, 32, jnp.bfloat16),
+    "float32": (1024, 2, 4, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("shape", RECURRENCES.values(),
+                         ids=RECURRENCES.keys())
+def test_grad_of_the_gated_delta_rule_compiles_for_a_v5e(one_chip, shape):
+    from chipbench.layer_metrics import gdn_bwd_roofline, gdn_fwd_roofline
+
+    s, hk, hv, dtype = shape
+
+    def loss(q, k, v, g, beta):
+        out = gated_delta_rule(q, k, v, g, beta, False)
+        return out.astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, s, hk, 128), dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, s, hv, 128), dtype, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((1, s, hv), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(
+        q, q, v, g, g).compile()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2  # one forward, one backward kernel
+    for reader in (gdn_fwd_roofline, gdn_bwd_roofline):
+        for pattern in (reader.KERNELS, reader.WRITER):
+            assert sum(bool(re.search(pattern, c)) for c in calls) == 1

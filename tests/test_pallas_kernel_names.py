@@ -13,7 +13,7 @@ import chip_smoke
 from chipbench.layer_metrics import (flash_bwd_roofline, flash_fwd_roofline,
                                      moe_gmm_roofline)
 from paddle_tpu.ops import flash_attention as flash
-from paddle_tpu.ops import grouped_matmul
+from paddle_tpu.ops import gated_delta_rule, grouped_matmul
 
 
 def _lowered_for_tpu(fn, *args):
@@ -50,11 +50,23 @@ def _grouped_matmul_program():
     return _lowered_for_tpu(jax.grad(loss, (0, 1)), x, w, sizes)
 
 
+def _gated_delta_rule_program():
+    def loss(q, k, v, g, beta):
+        return gated_delta_rule.gated_delta_rule(
+            q, k, v, g, beta, False).astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 256, 4, 128), jnp.bfloat16)
+    g = jax.ShapeDtypeStruct((1, 256, 4), jnp.float32)
+    return _lowered_for_tpu(jax.grad(loss, (0, 1, 2, 3, 4)), q, q, v, g, g)
+
+
 @pytest.mark.parametrize("program, wanted", [
     (_flash_program, chip_smoke.FLASH_KERNELS),
     (_grouped_matmul_program, chip_smoke.MOE_KERNELS),
     (_window_program, flash.kernel_names(100)),
-], ids=["flash", "grouped_matmul", "flash_window"])
+    (_gated_delta_rule_program, gated_delta_rule.KERNELS),
+], ids=["flash", "grouped_matmul", "flash_window", "gated_delta_rule"])
 def test_kernel_names_are_the_ones_chip_smoke_requires(program, wanted):
     have = chip_smoke.kernels_in(program())
     assert have == sorted(wanted)
@@ -129,3 +141,31 @@ def test_grouped_matmul_readers_patterns_match_the_kernel_names():
     # a fusion that only USES a kernel's result is not the kernel
     user = "%fusion.9 = bf16[4096,2048]{1,0} fusion(bf16[4096,4096] %moe_gmm.7)"
     assert not any(re.search(p, user) for p in patterns.values())
+
+
+def test_gated_delta_rule_readers_patterns_match_the_kernel_names():
+    """Every forward kernel's name starts ``gdn_fwd``, every backward
+    one's ``gdn_bwd`` (the launchers are jitted: the trace shows the
+    pallas_call's own name), and each reader finds its direction's
+    kernels, and as passes the one that writes the result."""
+    fwd = _load_reader("gdn_fwd_roofline")
+    bwd = _load_reader("gdn_bwd_roofline")
+    assert gated_delta_rule.KERNELS == ("gdn_fwd", "gdn_bwd")
+    shown = {name: f"%{name}.{i} = (bf16[1,16384,4096]{{2,1,0}}) custom-call("
+             for i, name in enumerate(gated_delta_rule.KERNELS)}
+    for reader, kernel in ((fwd, "gdn_fwd"), (bwd, "gdn_bwd")):
+        for pattern in (reader.KERNELS, reader.WRITER):
+            hits = [k for k, text in shown.items()
+                    if re.search(pattern, text)]
+            assert hits == [kernel], (kernel, pattern)
+        # a later helper kernel of the same direction counts in the time
+        # and is no pass
+        helper = f"%{kernel}_states.4 = f32[32,256,128,128]{{3,2,1,0}} "\
+            "custom-call("
+        assert re.search(reader.KERNELS, helper)
+        assert not re.search(reader.WRITER, helper)
+    other = chip_smoke.FLASH_KERNELS + chip_smoke.MOE_KERNELS
+    for name in other:
+        text = f"%{name}.1 = bf16[8]{{0}} custom-call("
+        assert not any(re.search(p, text) for r in (fwd, bwd)
+                       for p in (r.KERNELS, r.WRITER))
