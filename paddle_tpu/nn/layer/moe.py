@@ -18,11 +18,13 @@ stands in for the absent chips or their traffic.
 A share computes a BOUNDED number of rows at a time. The sorted order
 puts the held experts' pairs first, so a static window of it holds all
 of them whenever their number (``sum(sizes)``, on the device) is at most
-the window's length: ``row_bound``, four even shares of the layer's
-pairs rounded up to the row tile — 4,096 rows for 32,768 pairs where 8
-of 256 experts are held. The share's path is a loop over such windows
-for as many as hold its pairs (one, unless a routing sends it more than
-its bound; then two, three, ...: no pair is dropped at any routing): a
+the window's length: ``row_bound``, one even share of the layer's pairs
+and an excess that falls with the square root of the number of experts
+held, rounded up to the row tile — 4,096 rows (4 even shares) for 32,768
+pairs where 8 of 256 experts are held, 42,240 (2.06) for 163,840 where
+64 of 512 are. The share's path is a loop over such windows for as many
+as hold its pairs (one, unless a routing sends it more than its bound;
+then two, three, ...: no pair is dropped at any routing): a
 window gathers its rows straight from ``x``, runs the grouped matmuls and
 SwiGLU over them and adds each row times its pair's gate into its
 token's row in float32. The trip count is data, so the loop is the
@@ -115,23 +117,30 @@ _gradient_tail.defvjp(lambda a, rows: (a, rows),
                       lambda rows, g: (_zero_tail(g, rows), None))
 
 
-# A share computes rows for this many EVEN shares of the layer's pairs at
-# a time; a routing that sends it more takes a window more. From the
-# records, not a knob: over a training window the held experts got
-# 1.24-1.45 even shares (PERF.md section 6), and the loop is exact at any.
-_BOUND_SHARES = 4
+# A share's load is a sum over the experts it holds, so its spread about
+# one even share of the layer's pairs falls as 1 / sqrt(held). A window is
+# one even share plus ``_EXCESS`` shares at ``_EXCESS_AT`` held experts;
+# a routing that sends the share more takes a window more (the loop is
+# exact at any number). From the records, not a knob (PERF.md section 6):
+# 8 held experts of 256 under a balancing rule carry 1.1-1.5 even shares
+# over a training window but pass 2 in a tenth of their calls and reach
+# 3.1-3.9 in the first steps on seeded weights, so their window is 4; 64
+# of 512 with no rule stay at 1.00-1.02, and theirs is 2.06. Every pass
+# that is not a kernel pays for a window's whole length.
+_EXCESS, _EXCESS_AT = 3, 8
 _ROW_TILE = 256    # a multiple of it satisfies ``gmm_tiling`` and ``tgmm_tiling``
 
 
 def row_bound(pairs: int, held: int, num_experts: int):
     """The static bound on the rows a share of ``held`` of ``num_experts``
     computes at a time for ``pairs`` (token, choice) pairs:
-    ``_BOUND_SHARES`` even shares, rounded up to the row tile. None where
-    the bound would not halve the rows (or every expert is held): a row
-    for every pair, no loop."""
-    if held >= num_experts:
+    ``1 + _EXCESS * sqrt(_EXCESS_AT / held)`` even shares, rounded up to
+    the row tile. None where the bound would not halve the rows (or every
+    expert is held, or none): a row for every pair, no loop."""
+    if not 0 < held < num_experts:
         return None
-    rows = -(-_BOUND_SHARES * pairs * held // num_experts)
+    shares = 1 + _EXCESS * math.sqrt(_EXCESS_AT / held)
+    rows = math.ceil(shares * pairs * held / num_experts)
     cap = -(-rows // _ROW_TILE) * _ROW_TILE
     return cap if 2 * cap <= pairs else None
 

@@ -284,7 +284,7 @@ def test_the_shares_add_up(held, e, k, t):
     """The routed parts that all shares give, with the shared expert
     counted once, are the uncut reference's layer: 4 shares of 2 experts
     of 8 (or 2 of 4, or the one that holds all); and 16 shares of 4 of
-    64, each with a bound of 256 rows on its 1,024 pairs, where a bias
+    64, each with a bound of 512 rows on its 1,024 pairs, where a bias
     towards experts 0-3 sends the first share past its bound."""
     p, m = _moe_weights(5, e=e, t=t)
     if e == 64:

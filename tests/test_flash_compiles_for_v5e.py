@@ -145,10 +145,16 @@ GROUPED = {
     # ``nn.layer.moe.row_bound``), the step's usual path
     "cell-trinity-gate-up-bounded": (4096, 3072, 6144, 8, jnp.bfloat16),
     "cell-trinity-down-bounded": (4096, 3072, 3072, 8, jnp.bfloat16),
-    # train-qwen3next-4l-16k: 64 held experts of width 512 on a window of
-    # 81,920 rows (four even shares of 163,840 pairs), ~320 live a group
+    # train-qwen3next-4l-16k: 64 held experts of width 512 on 81,920 rows
+    # (PR 34's window, four even shares of 163,840 pairs), ~320 live a
+    # group: kept as a length a share's rows may have
     "cell-qwen3next-gate-up": (81920, 2048, 1024, 64, jnp.bfloat16),
     "cell-qwen3next-down": (81920, 512, 2048, 64, jnp.bfloat16),
+    # ... and on the 42,240 rows ``row_bound`` gives that cell since PR 35
+    # (2.06 even shares: 165 row tiles), the step's usual path; a call
+    # past the bound runs the same shapes once more
+    "cell-qwen3next-gate-up-window": (42240, 2048, 1024, 64, jnp.bfloat16),
+    "cell-qwen3next-down-window": (42240, 512, 2048, 64, jnp.bfloat16),
 }
 
 

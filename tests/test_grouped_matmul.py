@@ -314,18 +314,18 @@ def test_routed_experts_is_dropless_and_matches_a_dense_loop():
 BOUND = dict(t=256, h=32, f=16, e=64, held=2, k=4, first=5)
 
 
-def _bounded_case(held_pairs, seed=0):
-    """(x, w_gu, w_dn, ids, gates): the first ``held_pairs`` (token, choice)
-    pairs of choices 0-1 go to the two held experts, every other pair to
-    an absent one."""
+def _bounded_case(held_pairs, seed=0, t=BOUND["t"]):
+    """(x, w_gu, w_dn, ids, gates) for ``t`` tokens: the first
+    ``held_pairs`` (token, choice) pairs of choices 0-1 go to the two held
+    experts, every other pair to an absent one."""
     b = BOUND
     rng = np.random.default_rng(seed)
     n = lambda *s: jnp.asarray(0.3 * rng.standard_normal(s), jnp.float32)
-    ids = np.tile(np.arange(20, 20 + b["k"], dtype=np.int32), (b["t"], 1))
+    ids = np.tile(np.arange(20, 20 + b["k"], dtype=np.int32), (t, 1))
     mine = np.arange(held_pairs)
     ids[mine // 2, mine % 2] = b["first"] + mine % 2
     gates = jnp.asarray(rng.uniform(0.1, 1.0, ids.shape), jnp.float32)
-    return (n(b["t"], b["h"]), n(b["held"], b["h"], 2 * b["f"]),
+    return (n(t, b["h"]), n(b["held"], b["h"], 2 * b["f"]),
             n(b["held"], b["f"], b["h"]), jnp.asarray(ids), gates)
 
 
@@ -340,30 +340,33 @@ def _dense_loop(x, w_gu, w_dn, ids, gates, first):
     return out
 
 
-@pytest.mark.parametrize("held_pairs, in_full",
-                         [(0, 0), (40, 0), (256, 0), (257, 1), (512, 1)],
-                         ids=["none", "few", "at-the-bound", "one-over",
-                              "every-token-twice"])
+@pytest.mark.parametrize(
+    "held_pairs, in_full, tokens, experts",
+    [(0, 0, 256, 64), (40, 0, 256, 64), (256, 0, 256, 64), (257, 1, 256, 64),
+     (512, 1, 256, 64), (700, 1, 768, 256), (1200, 1, 768, 256)],
+    ids=["none", "few", "at-the-bound", "one-over", "every-token-twice",
+         "three-windows", "five-windows"])
 def test_a_bounded_share_drops_no_pair_at_the_bound_or_beyond_it(
-        held_pairs, in_full):
+        held_pairs, in_full, tokens, experts):
     """Up to the bound the share computes one window of 256 rows, past
-    it as many as hold its pairs: the output and every gradient are the
-    dense loop's either way, and ``calls_in_full`` counts the calls that
-    passed the bound."""
+    it as many as hold its pairs (two; of 768 tokens' pairs over 256
+    experts three and five): the output and every gradient are the dense
+    loop's either way, and ``calls_in_full`` counts the calls that passed
+    the bound."""
     import paddle_tpu as paddle
     from paddle_tpu import nn
     from paddle_tpu.nn.layer import moe
 
-    b = BOUND
-    assert moe.row_bound(b["t"] * b["k"], b["held"], b["e"]) == 256
-    x, w_gu, w_dn, ids, gates = _bounded_case(held_pairs)
+    b = dict(BOUND, e=experts)
+    assert moe.row_bound(tokens * b["k"], b["held"], b["e"]) == 256
+    x, w_gu, w_dn, ids, gates = _bounded_case(held_pairs, t=tokens)
     layer = nn.RoutedExperts(b["h"], b["f"], b["e"], b["held"], b["first"])
     layer.w_gu._data, layer.w_dn._data = w_gu, w_dn
     t = paddle.to_tensor
     for _ in range(2):
         out = layer(t(x), t(ids), t(gates))._data
     assert int(layer.calls_in_full._data) == 2 * in_full
-    assert int(layer.pairs_routed._data) == 2 * b["t"] * b["k"]
+    assert int(layer.pairs_routed._data) == 2 * tokens * b["k"]
     counts = np.asarray(layer.tokens_per_expert._data)
     assert (counts == [held_pairs + 1 >> 1 << 1, held_pairs >> 1 << 1]).all()
 
@@ -385,6 +388,33 @@ def test_a_bounded_share_drops_no_pair_at_the_bound_or_beyond_it(
         assert bool(jnp.isfinite(got).all()), name
         assert float(jnp.abs(got - w).max()) \
             <= 1e-5 * max(float(jnp.abs(w).max()), 1.0), name
+
+
+@pytest.mark.parametrize("pairs, held, num_experts, rows", [
+    (32768, 8, 256, 4096),          # train-trinity-5l-8k: 4 even shares
+    (163840, 64, 512, 42240),       # train-qwen3next-4l-16k: 2.06
+    (1024, 2, 64, 256),             # 7 shares of 32: one row tile
+    (8192, 16, 256, 1792),          # 3.12 shares of 512
+    (2048, 2, 32, 1024),            # 7 shares of 128 = 896: half the rows
+    (2048, 2, 28, 1024),            # 7 shares of 146.3 = 1024: at the half
+    (2048, 2, 27, None),            # ... and over it: would not halve
+    (1536, 2, 16, None),            # 7 shares of 192: far over the half
+    (4096, 16, 16, None)],          # every expert held (train-zaya1-6l-4k)
+    ids=["trinity", "qwen3next", "one-tile", "sixteen-held", "under-a-half",
+         "at-a-half", "over-a-half", "an-eighth-of-16",
+         "every-expert"])
+def test_a_shares_window_is_one_even_share_and_an_excess_by_the_held(
+        pairs, held, num_experts, rows):
+    """``row_bound``: one even share of the pairs plus three at 8 held
+    experts, falling as 1 / sqrt(held), in whole row tiles; None where
+    that would not halve the rows (a row for every pair)."""
+    from paddle_tpu.nn.layer import moe
+
+    assert moe.row_bound(pairs, held, num_experts) == rows
+    if rows is not None:
+        assert rows % moe._ROW_TILE == 0 and 2 * rows <= pairs
+        shares = 1 + 3 * (8 / held) ** 0.5
+        assert 0 <= rows - shares * pairs * held / num_experts < moe._ROW_TILE
 
 
 def _sub_jaxprs(eqn):

@@ -214,26 +214,29 @@ def _shared(p, m):
         m, p["shared.w1"], p["shared.w3"], p["shared.w2"], "f32")
 
 
-@pytest.mark.parametrize("held, t", [(2, 48), (16, 48), (2, 512)],
-                         ids=["8-shares", "whole", "8-shares-bounded"])
-def test_the_shares_add_up(held, t):
+@pytest.mark.parametrize("held, e, t", [(2, 16, 48), (16, 16, 48),
+                                        (4, 64, 512)],
+                         ids=["8-shares", "whole", "16-shares-bounded"])
+def test_the_shares_add_up(held, e, t):
     """The routed parts that all shares give, with the gated shared
     expert counted once, are the uncut reference's layer: 8 shares of 2
-    experts of 16, three a token (and the one that holds all); at 512
-    tokens a share has a bound of 768 rows on its 1,536 pairs: exactly
-    at ``row_bound``'s edge (2 x cap <= pairs), as the cell's is."""
+    experts of 16, three a token (and the one that holds all); 16 shares
+    of 4 of 64 at 512 tokens have a bound of 512 rows each on the 1,536
+    pairs (5.24 even shares of 96, rounded up to the row tile). The
+    cell's bound is 42,240 rows of its 163,840 pairs (2.06 even shares
+    of 20,480): no longer at ``row_bound``'s edge (2 x cap <= pairs)."""
     from paddle_tpu.nn.layer.moe import row_bound
 
-    p, m = _moe_weights(5, t=t)
-    assert row_bound(t * 3, held, 16) == (768 if t == 512 else None)
-    assert row_bound(16384 * 10, 64, 512) == 81920 == 16384 * 10 // 2
+    p, m = _moe_weights(5, e=e, t=t)
+    assert row_bound(t * 3, held, e) == (512 if t == 512 else None)
+    assert row_bound(16384 * 10, 64, 512) == 42240
     with jax.default_matmul_precision("highest"):
         whole, _ = qr.moe(p, m, top_k=3, first=0)
         shared = _shared(p, m)
         total, rows = 0.0, 0
         with no_grad():
-            for first in range(0, 16, held):
-                f, ids, sizes = _share(p, first, held).compute(
+            for first in range(0, e, held):
+                f, ids, sizes = _share(p, first, held, e=e).compute(
                     paddle.to_tensor(m))
                 total = total + (f._data - shared)
                 rows += int(sizes._data.sum())
