@@ -114,9 +114,8 @@ def collective_contract(store, rank, world_size, *, last_n=32,
     return _fr.contract(store, rank, world_size, last_n=last_n,
                         deadline=deadline, recorder_=recorder, tag=tag)
 
-# Public: the obs compile-event hook (paddle_tpu/obs/compile.py) listens
-# on the SAME seam, so the guard and the timeline can never disagree
-# about what counts as a compilation.
+# The guard's own seam: jax's compile log. (The obs timeline's compile
+# spans come from jax.monitoring instead: paddle_tpu/obs/compile.py.)
 COMPILE_LOGGERS = ("jax._src.interpreters.pxla",)
 COMPILING_RE = re.compile(
     r"Compiling (\S+) with global shapes and types (.+?)"

@@ -464,6 +464,9 @@ class StaticFunction:
                        trace_id=f"{self._qualname}:{self._n_calls}",
                        fn=self._qualname) as call:
             self._call_span = call  # pure() parents its trace span on it
+            # jax's lowering and compile events inside this call become
+            # its to_static.lower / to_static.compile legs (obs/compile.py)
+            _obs.compile.call_opened(call)
             try:
                 with _leg("revalidate", call):
                     if self._needs_discovery:
@@ -567,11 +570,18 @@ class StaticFunction:
                     if trace_runs != 1:
                         for o in self._stepped_optimizers:
                             o._global_step += 1 - trace_runs
+                if trace_runs:
+                    # what the compiler reckons the executable needs, on
+                    # the call that compiled it (jax's caches hold it by
+                    # now: a lookup, no trace or compile; obs/compile.py)
+                    call.args["memory"] = _obs.compile.executable_memory(
+                        jitted, state, lrs, flat_arrays)
                 return tree_util.tree_map(
                     lambda a: Tensor(a, _internal=True) if isinstance(a, jax.Array) else a, out_arrays
                 )
             finally:
                 self._call_span = None
+                _obs.compile.call_closed()
                 call.args.update(traces=self._pure_runs - runs_before,
                                  leaves=self._n_leaves)
 
@@ -809,22 +819,40 @@ class StaticFunction:
                 scanned, donate_argnums=(0,) if self._donate_state else ()
             )
             self._jit_cache[key] = jitted
+        # the same call span as __call__'s, with the legs this path has
+        self._n_calls += 1
         runs_before = self._pure_runs
-        outs, new_state = jitted(state, lrs_stacked, flat_arrays)
-        trace_runs = self._pure_runs - runs_before
-        self._write_state(new_state)
-        self._sanitize_grads()
-        # host-side step counter: this call represents n steps for each
-        # optimizer that steps in the traced program; tracing already
-        # advanced _global_step once per pure() execution (scan traces
-        # its body at least once)
-        correction = n - trace_runs
-        if correction:
-            for o in self._stepped_optimizers:
-                o._global_step += correction
-        return tree_util.tree_map(
-            lambda a: Tensor(a, _internal=True) if isinstance(a, jax.Array) else a, outs
-        )
+        with _obs.span("to_static.call", tid="to_static",
+                       trace_id=f"{self._qualname}:{self._n_calls}",
+                       fn=self._qualname) as call:
+            self._call_span = call
+            _obs.compile.call_opened(call)
+            try:
+                with _leg("dispatch", call):
+                    outs, new_state = jitted(state, lrs_stacked, flat_arrays)
+                trace_runs = self._pure_runs - runs_before
+                with _leg("write_state", call):
+                    self._write_state(new_state)
+                    self._sanitize_grads()
+                    # host-side step counter: this call represents n steps
+                    # for each optimizer that steps in the traced program;
+                    # tracing already advanced _global_step once per pure()
+                    # execution (scan traces its body at least once)
+                    correction = n - trace_runs
+                    if correction:
+                        for o in self._stepped_optimizers:
+                            o._global_step += correction
+                if trace_runs:
+                    call.args["memory"] = _obs.compile.executable_memory(
+                        jitted, state, lrs_stacked, flat_arrays)
+                return tree_util.tree_map(
+                    lambda a: Tensor(a, _internal=True) if isinstance(a, jax.Array) else a, outs
+                )
+            finally:
+                self._call_span = None
+                _obs.compile.call_closed()
+                call.args.update(traces=self._pure_runs - runs_before,
+                                 leaves=self._n_leaves)
 
     # -- inspection -----------------------------------------------------
     def concrete_program(self):

@@ -17,10 +17,14 @@ One subsystem, three planes, one timeline:
   header, collected in a bounded per-process ring, exported as Chrome
   trace-event JSON (Perfetto-loadable) and stitched across worker
   processes by trace_id.
-- **Device/compile events** (:mod:`.compile`): XLA compile count +
-  wall time from the same jax compile-log seam ``recompile_guard``
-  uses; dispatch→harvest spans from the serving engine's async copy
-  ring; supervisor watchdog / rollback / chaos instants.
+- **Device/compile events** (:mod:`.compile`): the repository's one
+  ``jax.monitoring`` listener turns each lowering and each backend
+  compile into a finished span — ``to_static.lower`` /
+  ``to_static.compile`` under the ``to_static.call`` that was open,
+  ``xla.lower`` / ``xla.compile`` outside any — with the persistent
+  cache's verdict (``cache``: hit / miss / off); dispatch→harvest spans
+  from the serving engine's async copy ring; supervisor watchdog /
+  rollback / chaos instants.
 
 - **Reaction** (:mod:`.alerts`, :mod:`.regress` — ISSUE 15): the layer
   that converts the planes above into decisions. Declarative alert
@@ -45,11 +49,7 @@ from .alerts import (
     default_training_rules,
     set_default_manager,
 )
-from .compile import (
-    compile_events_installed,
-    install_compile_events,
-    uninstall_compile_events,
-)
+from . import compile  # noqa: F401  (registers the jax.monitoring listener)
 from .metrics import (
     Counter,
     Gauge,
@@ -73,6 +73,7 @@ from .trace import (
     finish_span,
     instant,
     new_trace_id,
+    record_span,
     ring,
     set_enabled,
     set_process_label,
@@ -86,11 +87,10 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricAttr", "MetricsRegistry",
     "registry", "labels_of",
     "Span", "TraceRing", "new_trace_id", "span", "start_span",
-    "finish_span", "instant", "trace_ctx", "ring", "set_enabled",
+    "finish_span", "record_span", "instant", "trace_ctx", "ring",
+    "set_enabled",
     "enabled", "set_process_label", "export_chrome_trace",
     "stitch_traces",
-    "install_compile_events", "uninstall_compile_events",
-    "compile_events_installed",
     "slo_summary", "tenant_slo_table",
     "HEALTH_SCHEMA_VERSION", "health_envelope",
     "ThresholdRule", "AbsenceRule", "BurnRateRule", "AlertManager",

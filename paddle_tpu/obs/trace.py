@@ -14,10 +14,12 @@ Model:
   Use the :func:`span` context manager for synchronous legs and the
   explicit :func:`start_span`/:func:`finish_span` pair for async legs
   (the overlap copy ring issues a dispatch span at submit time and
-  finishes it at harvest, possibly many steps later).
+  finishes it at harvest, possibly many steps later);
+  :func:`record_span` records a leg someone else timed, from its start
+  and duration (``obs/compile.py``: jax's lowering and compile events).
 - An **instant** is a zero-duration event (watchdog escalation,
-  rollback, chaos injection, XLA compile start) that lands on the same
-  timeline as the request spans.
+  rollback, chaos injection) that lands on the same timeline as the
+  request spans.
 
 Recording is a deque append — bounded (``TraceRing``), allocation-light,
 and togglable: :func:`set_enabled(False)` turns every record into a
@@ -61,6 +63,7 @@ __all__ = [
     "span",
     "start_span",
     "finish_span",
+    "record_span",
     "instant",
     "trace_ctx",
     "ring",
@@ -265,6 +268,23 @@ def finish_span(sp: Optional[Span], **args) -> Optional[Span]:
     return sp
 
 
+def record_span(name: str, start: float, dur: float, *,
+                trace_id: Optional[str] = None, parent=None,
+                tid: str = "main", **args) -> Optional[Span]:
+    """Record a span that is already over: ``start`` on the wall clock
+    (``time.time()``), ``dur`` in seconds, both as someone else measured
+    them (jax's monitoring events give a compile's start and end after
+    it has ended). ``parent`` as for :func:`start_span`. The ring only:
+    a profiler annotation cannot be opened in the past."""
+    if not _ENABLED:
+        return None
+    sp = start_span(name, trace_id=trace_id, parent=parent, tid=tid, **args)
+    sp._t0 = start - _WALL0
+    sp.dur = dur
+    _RING.record(sp)
+    return sp
+
+
 # prefix of every annotation this module writes into a profiler trace:
 # a reader that filters host events by its own bare names (``harvest``,
 # ``train.step``) never picks up a program span of the same name
@@ -315,8 +335,8 @@ def span(name: str, *, trace_id: Optional[str] = None, parent=None,
 
 def instant(name: str, *, trace_id: Optional[str] = None, parent=None,
             tid: str = "main", **args) -> None:
-    """Record a zero-duration event (watchdog/rollback/chaos/compile
-    markers) on the same timeline as the spans."""
+    """Record a zero-duration event (watchdog/rollback/chaos markers)
+    on the same timeline as the spans."""
     if not _ENABLED:
         return
     ptrace, pspan = _resolve_parent(trace_id, parent)
