@@ -13,9 +13,11 @@ recurrence otherwise (``"linear_attention"``); every MLP is routed
 Gated DeltaNet (``GatedDeltaNet``; a = the normed stream)::
 
     [q | k | v | z] = a W_qkvz;   [b | alpha] = a W_ba
-    [q | k | v] <- silu(causal depthwise conv, 4 taps, no bias)   float32
-    beta = sigmoid(b);   g = -exp(A_log) softplus(alpha + dt_bias)
+    [q | k | v] <- silu(causal depthwise conv, 4 taps, no bias)
     q <- l2norm(q) / sqrt(d_k);   k <- l2norm(k)     per head, eps 1e-6
+            both lines are ONE op, ops/gdn_inputs.py::conv_silu_l2norm:
+            float32 inside, and that float32 lives in VMEM alone
+    beta = sigmoid(b);   g = -exp(A_log) softplus(alpha + dt_bias)
     o = gated_delta_rule(q, k, v, g, beta)           ops/gated_delta_rule.py
     out = (RMSNorm_dv(o) w silu(z)) W_o              w [d_v], starts at 1
 
@@ -63,7 +65,7 @@ from ..base.tape import apply
 from ..nn import functional as F
 from ..nn import initializer as I
 from .afmoe import AfmoeForCausalLM, AfmoeMLP
-from .zaya import _rope, _shift
+from .zaya import _rope
 
 LINEAR, FULL = "linear_attention", "full_attention"
 
@@ -155,28 +157,20 @@ def gdn_inputs(qkv, ba, w, a_log, dt_bias, *, hk: int, hv: int, dk: int,
                dv: int):
     """The projections to what the recurrence takes. ``qkv`` [B, S, C]
     passes a causal depthwise convolution (``w`` [taps, C], tap 0 the
-    oldest position, zeros before the sequence) and SiLU, in float32;
-    then q, k [B, S, hk, dk] are normalised (q also scaled by 1 /
-    sqrt(dk)) and returned with v [B, S, hv, dv] in ``qkv``'s type, g and
-    beta [B, S, hv] in float32."""
-    b, s, _ = qkv.shape
-    taps = w.shape[0]
-    x = qkv.astype(jnp.float32)
-    c = jax.nn.silu(sum(_shift(x, taps - 1 - j) * w[j].astype(jnp.float32)
-                        for j in range(taps)))
-    q, k, v = jnp.split(c, [hk * dk, 2 * hk * dk], axis=-1)
+    oldest position, zeros before the sequence) and SiLU; then q, k [B,
+    S, hk, dk] are normalised (q also scaled by 1 / sqrt(dk)) and
+    returned with v [B, S, hv, dv] in ``qkv``'s type: one pass over
+    ``qkv`` forward and one backward, every step of it float32 inside the
+    kernels of ``ops/gdn_inputs.py``, which keep ``qkv`` and ``w`` alone
+    for the backward pass. g and beta [B, S, hv] are float32."""
+    from ..ops.gdn_inputs import conv_silu_l2norm
 
-    def unit(x):
-        x = x.reshape(b, s, hk, dk)
-        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
-
+    q, k, v = conv_silu_l2norm(qkv, w, hk, hv, dk, dv)
     ba = ba.astype(jnp.float32)
     beta = jax.nn.sigmoid(ba[..., :hv])
     g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
         ba[..., hv:] + dt_bias.astype(jnp.float32))
-    return ((unit(q) / math.sqrt(dk)).astype(qkv.dtype),
-            unit(k).astype(qkv.dtype),
-            v.reshape(b, s, hv, dv).astype(qkv.dtype), g, beta)
+    return q, k, v, g, beta
 
 
 def gdn_gate(o, z, w, eps: float):
@@ -224,13 +218,12 @@ class GatedDeltaNet(nn.Layer):
 
         with jax.named_scope("gdn.project"):
             qkvz, ba = self.in_proj_qkvz(a), self.in_proj_ba(a)
-        # the elementwise steps keep their inputs alone for the backward
-        # pass (``jax.checkpoint``): their float32 insides are [S, 8192]
+        # the [S, 8192] part is two kernels whose custom VJP keeps its
+        # inputs alone; g and beta keep their [S, 32] float32 insides
         with jax.named_scope("gdn.conv"):
             q, k, v, g, beta = apply(
-                jax.checkpoint(functools.partial(
-                    gdn_inputs, hk=self.hk, hv=self.hv, dk=self.dk,
-                    dv=self.dv)),
+                functools.partial(gdn_inputs, hk=self.hk, hv=self.hv,
+                                  dk=self.dk, dv=self.dv),
                 qkvz[:, :, :self.conv_dim], ba, self.conv1d_weight,
                 self.A_log, self.dt_bias, op_name="gdn_inputs")
         with jax.named_scope("gdn.scan"):

@@ -24,6 +24,8 @@ from chipbench.layer_metrics import (flash_bwd_roofline, flash_fwd_roofline,
                                      moe_gmm_roofline)
 from paddle_tpu.ops.flash_attention import flash_attention, kernel_names
 from paddle_tpu.ops.gated_delta_rule import gated_delta_rule
+from paddle_tpu.ops.gdn_inputs import KERNELS as INPUTS_KERNELS
+from paddle_tpu.ops.gdn_inputs import conv_silu_l2norm
 from paddle_tpu.ops.grouped_matmul import grouped_matmul
 
 # the patterns the benchmark's flash readers find the kernels by in a
@@ -212,3 +214,26 @@ def test_grad_of_the_gated_delta_rule_compiles_for_a_v5e(one_chip, shape):
     for reader in (gdn_fwd_roofline, gdn_bwd_roofline):
         for pattern in (reader.KERNELS, reader.WRITER):
             assert sum(bool(re.search(pattern, c)) for c in calls) == 1
+
+
+@pytest.mark.parametrize("shape", RECURRENCES.values(),
+                         ids=RECURRENCES.keys())
+def test_grad_of_the_recurrences_inputs_compiles_for_a_v5e(one_chip, shape):
+    """``ops/gdn_inputs.py`` at the same shapes: the convolution, SiLU
+    and norms before the recurrence."""
+    s, hk, hv, dtype = shape
+    c = (2 * hk + hv) * 128
+
+    def loss(qkv, w):
+        return sum(o.astype(jnp.float32).sum()
+                   for o in conv_silu_l2norm(qkv, w, hk, hv, 128, 128, False))
+
+    qkv = jax.ShapeDtypeStruct((1, s, c), dtype, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((4, c), dtype, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        qkv, w).compile()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(re.match(r"%(\w+?)\.\d+ = ", c).group(1) for c in calls) \
+        == sorted(INPUTS_KERNELS)

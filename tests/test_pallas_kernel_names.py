@@ -13,7 +13,7 @@ import chip_smoke
 from chipbench.layer_metrics import (flash_bwd_roofline, flash_fwd_roofline,
                                      moe_gmm_roofline)
 from paddle_tpu.ops import flash_attention as flash
-from paddle_tpu.ops import gated_delta_rule, grouped_matmul
+from paddle_tpu.ops import gated_delta_rule, gdn_inputs, grouped_matmul
 
 
 def _lowered_for_tpu(fn, *args):
@@ -61,12 +61,25 @@ def _gated_delta_rule_program():
     return _lowered_for_tpu(jax.grad(loss, (0, 1, 2, 3, 4)), q, q, v, g, g)
 
 
+def _gdn_inputs_program():
+    def loss(qkv, w):
+        return sum(o.astype(jnp.float32).sum() for o in
+                   gdn_inputs.conv_silu_l2norm(qkv, w, 2, 4, 128, 128, False))
+
+    qkv = jax.ShapeDtypeStruct((1, 256, 1024), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((4, 1024), jnp.bfloat16)
+    # the backward needs no result of the forward: the value keeps it
+    return _lowered_for_tpu(jax.value_and_grad(loss, (0, 1)), qkv, w)
+
+
 @pytest.mark.parametrize("program, wanted", [
     (_flash_program, chip_smoke.FLASH_KERNELS),
     (_grouped_matmul_program, chip_smoke.MOE_KERNELS),
     (_window_program, flash.kernel_names(100)),
     (_gated_delta_rule_program, gated_delta_rule.KERNELS),
-], ids=["flash", "grouped_matmul", "flash_window", "gated_delta_rule"])
+    (_gdn_inputs_program, gdn_inputs.KERNELS),
+], ids=["flash", "grouped_matmul", "flash_window", "gated_delta_rule",
+        "gdn_inputs"])
 def test_kernel_names_are_the_ones_chip_smoke_requires(program, wanted):
     have = chip_smoke.kernels_in(program())
     assert have == sorted(wanted)
