@@ -7,7 +7,10 @@ experts, training path only; afmoe: window and full gated attention
 mixed, a sigmoid top-k router beside a shared expert, a held share of the
 experts, training path only; qwen3_next: Gated DeltaNet and gated full
 attention mixed 3:1, a softmax top-k router beside a gated shared expert,
-a held share, training path only; vision models live in paddle_tpu.vision
+a held share, training path only; minicpm_sala: lightning linear attention
+and block-sparse softmax attention mixed by a published list, dense
+SwiGLU, muP scalings, a held share of layers and vocabulary, training path
+only; vision models live in paddle_tpu.vision
 (config #1).
 """
 from .llama import (  # noqa: F401
@@ -40,6 +43,12 @@ from .qwen3_next import (  # noqa: F401
     Qwen3NextDecoderLayer,
     Qwen3NextForCausalLM,
     Qwen3NextModel,
+)
+from .minicpm_sala import (  # noqa: F401
+    MiniCPMSALAConfig,
+    MiniCPMSALADecoderLayer,
+    MiniCPMSALAForCausalLM,
+    MiniCPMSALAModel,
 )
 from .unet import UNet2DConditionModel, UNetConfig  # noqa: F401
 from .generation import generate  # noqa: F401

@@ -237,3 +237,92 @@ def test_grad_of_the_recurrences_inputs_compiles_for_a_v5e(one_chip, shape):
              if 'custom_call_target="tpu_custom_call"' in line]
     assert sorted(re.match(r"%(\w+?)\.\d+ = ", c).group(1) for c in calls) \
         == sorted(INPUTS_KERNELS)
+
+
+# (sequence, heads, dtype): lightning attention
+LIGHTNING = {
+    # train-minicpmsala-4l-16k's three lightning layers: 32 heads of 128
+    # over 16,384 tokens, 32 grid steps of four chunks a head
+    "cell-minicpmsala": (16384, 32, jnp.bfloat16),
+    "float32": (1024, 4, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("shape", LIGHTNING.values(), ids=LIGHTNING.keys())
+def test_grad_of_lightning_attention_compiles_for_a_v5e(one_chip, shape):
+    from chipbench.layer_metrics import (lightning_bwd_roofline,
+                                         lightning_fwd_roofline)
+    from paddle_tpu.ops.lightning_attention import lightning_attention
+
+    s, h, dtype = shape
+
+    def loss(q, k, v, slopes):
+        out = lightning_attention(q, k, v, slopes, None, False)
+        return out.astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, s, h, 128), dtype, sharding=one_chip)
+    slopes = jax.ShapeDtypeStruct((h,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        q, q, q, slopes).compile()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2  # one forward, one backward kernel
+    for reader in (lightning_fwd_roofline, lightning_bwd_roofline):
+        for pattern in (reader.KERNELS, reader.WRITER):
+            assert sum(bool(re.search(pattern, c)) for c in calls) == 1
+
+
+# (sequence, query heads, kv groups, blocks a token, dtype): block-sparse
+# attention
+SPARSE = {
+    # train-minicpmsala-4l-16k's one sparse layer: 32 / 2 heads of 128,
+    # 64 blocks a token of the 256; a group's K, V (4 MB each) and their
+    # float32 gradients (8 MB each) resident in VMEM
+    "cell-minicpmsala": (16384, 32, 2, 64, jnp.bfloat16),
+    "short": (2048, 32, 2, 8, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("shape", SPARSE.values(), ids=SPARSE.keys())
+def test_grad_of_block_sparse_attention_compiles_for_a_v5e(one_chip, shape):
+    from chipbench.layer_metrics import (sparse_attn_bwd_roofline,
+                                         sparse_attn_fwd_roofline)
+    from paddle_tpu.ops.sparse_attention import block_sparse_attention
+
+    s, h, g, picks, dtype = shape
+
+    def loss(q, k, v, table):
+        out = block_sparse_attention(q, k, v, table, False)
+        return out.astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, s, h, 128), dtype, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, s, g, 128), dtype, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((1, g, s, picks), jnp.int32,
+                                 sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        q, k, k, table).compile()
+    text = compiled.as_text()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2  # one forward, one backward kernel
+    for reader in (sparse_attn_fwd_roofline, sparse_attn_bwd_roofline):
+        for pattern in (reader.KERNELS, reader.WRITER):
+            assert sum(bool(re.search(pattern, c)) for c in calls) == 1
+    # work proportional to the table: no [S, S] array anywhere
+    assert not re.search(rf"\[(\d+,)*{s},{s}\]", text)
+
+
+def test_the_selection_compiles_for_a_v5e_without_its_scores_in_hbm(one_chip):
+    """``select_blocks`` at the cell's shapes: no [S, 32, S / 16] array
+    (2.1 GB in float32) in the compiled program, whose temporaries stay
+    under a chunk of queries' worth."""
+    from paddle_tpu.ops.sparse_attention import select_blocks
+
+    s = 16384
+    q = jax.ShapeDtypeStruct((1, s, 32, 128), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, s, 2, 128), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(lambda q, k: select_blocks(q, k)).lower(q, k).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+    assert not re.search(rf"\[(\d+,)*{s},(\d+,)*1023\]", compiled.as_text())

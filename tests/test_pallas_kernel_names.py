@@ -13,7 +13,8 @@ import chip_smoke
 from chipbench.layer_metrics import (flash_bwd_roofline, flash_fwd_roofline,
                                      moe_gmm_roofline)
 from paddle_tpu.ops import flash_attention as flash
-from paddle_tpu.ops import gated_delta_rule, gdn_inputs, grouped_matmul
+from paddle_tpu.ops import (gated_delta_rule, gdn_inputs, grouped_matmul,
+                            lightning_attention, sparse_attention)
 
 
 def _lowered_for_tpu(fn, *args):
@@ -72,14 +73,37 @@ def _gdn_inputs_program():
     return _lowered_for_tpu(jax.value_and_grad(loss, (0, 1)), qkv, w)
 
 
+def _lightning_program():
+    def loss(q, k, v, slopes):
+        return lightning_attention.lightning_attention(
+            q, k, v, slopes, None, False).astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, 256, 4, 128), jnp.bfloat16)
+    slopes = jax.ShapeDtypeStruct((4,), jnp.float32)
+    return _lowered_for_tpu(jax.grad(loss, (0, 1, 2)), q, q, q, slopes)
+
+
+def _sparse_attention_program():
+    def loss(q, k, v, table):
+        return sparse_attention.block_sparse_attention(
+            q, k, v, table, False).astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, 256, 32, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16)
+    table = jax.ShapeDtypeStruct((1, 2, 256, 4), jnp.int32)
+    return _lowered_for_tpu(jax.grad(loss, (0, 1, 2)), q, k, k, table)
+
+
 @pytest.mark.parametrize("program, wanted", [
     (_flash_program, chip_smoke.FLASH_KERNELS),
     (_grouped_matmul_program, chip_smoke.MOE_KERNELS),
     (_window_program, flash.kernel_names(100)),
     (_gated_delta_rule_program, gated_delta_rule.KERNELS),
     (_gdn_inputs_program, gdn_inputs.KERNELS),
+    (_lightning_program, lightning_attention.KERNELS),
+    (_sparse_attention_program, sparse_attention.KERNELS),
 ], ids=["flash", "grouped_matmul", "flash_window", "gated_delta_rule",
-        "gdn_inputs"])
+        "gdn_inputs", "lightning_attention", "sparse_attention"])
 def test_kernel_names_are_the_ones_chip_smoke_requires(program, wanted):
     have = chip_smoke.kernels_in(program())
     assert have == sorted(wanted)
@@ -181,4 +205,31 @@ def test_gated_delta_rule_readers_patterns_match_the_kernel_names():
     for name in other:
         text = f"%{name}.1 = bf16[8]{{0}} custom-call("
         assert not any(re.search(p, text) for r in (fwd, bwd)
+                       for p in (r.KERNELS, r.WRITER))
+
+
+@pytest.mark.parametrize("module, stem", [
+    (lightning_attention, "lightning"), (sparse_attention, "sparse_attn")],
+    ids=["lightning", "sparse_attn"])
+def test_minicpm_salas_readers_patterns_match_the_kernel_names(module, stem):
+    """Every forward kernel's name starts ``<stem>_fwd``, every backward
+    one's ``<stem>_bwd``, and each reader finds its direction's kernels,
+    and as passes the one that writes the result."""
+    assert module.KERNELS == (f"{stem}_fwd", f"{stem}_bwd")
+    readers = {k: _load_reader(f"{k}_roofline") for k in module.KERNELS}
+    shown = {name: f"%{name}.{i} = (bf16[1,16384,4096]{{2,1,0}}) custom-call("
+             for i, name in enumerate(module.KERNELS)}
+    for kernel, reader in readers.items():
+        for pattern in (reader.KERNELS, reader.WRITER):
+            hits = [k for k, text in shown.items()
+                    if re.search(pattern, text)]
+            assert hits == [kernel], (kernel, pattern)
+        helper = f"%{kernel}_dkv.4 = f32[2,16384,128]{{2,1,0}} custom-call("
+        assert re.search(reader.KERNELS, helper)
+        assert not re.search(reader.WRITER, helper)
+    other = (chip_smoke.FLASH_KERNELS + chip_smoke.MOE_KERNELS
+             + gated_delta_rule.KERNELS)
+    for name in other:
+        text = f"%{name}.1 = bf16[8]{{0}} custom-call("
+        assert not any(re.search(p, text) for r in readers.values()
                        for p in (r.KERNELS, r.WRITER))
