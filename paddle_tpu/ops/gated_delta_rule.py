@@ -28,16 +28,40 @@ tokens, so none exceeds one whatever ``g`` is. The matmuls take their
 operands in the inputs' type and add up in float32; the state and every
 elementwise step are float32.
 
-``gdn_fwd`` (grid: batch, blocks of the sequence in order, value heads)
-carries every head's state in float32 scratch from block to block and
-writes, beside ``o``, the state ENTERING each chunk in the inputs' type.
-``gdn_bwd`` walks the blocks and the chunks in them in reverse, carries
-``dS`` the same way, and computes everything else of a chunk again from
-the inputs and that state: A, T, W, U and V' are kept nowhere. The two
-value heads of a key head run in consecutive grid steps, so ``q`` and
-``k`` are fetched once for both and their gradients are added up in the
-output block while it is resident. The decays' sums inside a chunk and
-their transpose (a reverse sum) are two small XLA ops around the
+The chip's compiler keeps a product's result close behind its operands
+in the program, so a dependent product waits the MXU's whole round trip out
+(~230 cycles a level of the inverse, measured) whatever its shape, and a
+chunk is a chain of such products: the six levels of the inverse, then W
+and U, then what needs the state. Both kernels therefore walk a grid step
+STAGE BY STAGE over all of its chunks and value heads at once (``_chunks``:
+``_BLOCK`` tokens of a key head's ``Hv / Hk`` heads, two chunks of two heads
+= 4 bodies where there are two heads to a key head): every body's decays,
+then every inverse level by level (``_inverses``), then every W and U, so
+that products next to each other in the program are independent and
+pipeline. What carries the state is the only chain left, two products a
+chunk: ``W S`` -> V' -> ``S'`` in the forward, ``(K f) dS`` -> dV' -> ``dS'``
+in the backward, whose other products sit in stages before and after it.
+
+Beside that, products that share an operand are issued as ONE, their
+other operands side by side: ``[q; k] k^T`` (once for all the value heads of
+a key head: neither depends on the head), the inverse with its running
+product one factor behind the power (``[out; p] p`` gives ``out (I + p)``
+and ``p p`` at once: six products for ten), ``T [k beta e | v beta]``, and in
+the backward ``[do; d_new] S^T``, ``T^T [d_w | d_new]``, ``[d_kb | d_vb] [w |
+u]^T``, ``[q e; w]^T [do; -d_new]`` and, summed over the key head's value
+heads first, ``[d_qk; d_kk] k`` and ``[d_qk; d_kk]^T [q; k]``. The forward's
+chain keeps its product as small as it can be: ``(Q e) S`` runs beside
+``W S``, not in it (merged it cost 7% of the kernel).
+
+``gdn_fwd`` (grid: batch, blocks of the sequence in order, key heads)
+holds a key head's value heads in one grid step, carries every head's state
+in float32 scratch from block to block and writes, beside ``o``, the state
+ENTERING each chunk in the inputs' type. ``gdn_bwd`` walks the blocks and
+the chunks in them in reverse, carries ``dS`` the same way, and computes
+everything else of a chunk again from the inputs and that state: A, T, W, U
+and V' are kept nowhere. ``dq`` and ``dk`` are summed over the key head's
+value heads in registers and written once. The decays' sums inside a chunk
+and their transpose (a reverse sum) are two small XLA ops around the
 kernels.
 
 ``S`` has to be a multiple of the chunk: anything else is a
@@ -59,7 +83,12 @@ __all__ = ["gated_delta_rule", "CHUNK", "KERNELS"]
 
 CHUNK = 64                       # the kernels' own constant, not a knob
 KERNELS = ("gdn_fwd", "gdn_bwd")
-_BLOCK = 256                     # tokens a grid step holds (whole chunks)
+# Tokens a grid step holds (whole chunks), of at most ``_BODIES`` (chunk,
+# head) bodies. Every body is written out in the trace: 256 tokens (8 bodies
+# at two heads a key head) read 25% less kernel time than 128 and 3.4 s more
+# set-up, so 128 it is until the bodies are a batched dimension (PERF.md §6).
+_BLOCK = 128
+_BODIES = 8
 
 _NN = (((1,), (0,)), ((), ()))   # a @ b
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
@@ -85,55 +114,59 @@ def _column(block, head):
     return jnp.sum(jnp.where(lane == head, block, 0.0), axis=1, keepdims=True)
 
 
-def _put_column(ref, head, column, first):
-    """Write ``column`` [rows, 1] into column ``head`` of the resident
-    block ``ref`` [1, rows, heads]; the first head to visit clears it."""
+def _put_column(ref, head, columns, first):
+    """Write ``columns`` (each [rows, 1]) into the columns from ``head`` on
+    of the resident block ``ref`` [1, rows, heads]; the first grid step to
+    visit clears it."""
     lane = jax.lax.broadcasted_iota(jnp.int32, ref.shape[1:], 1)
     held = jnp.where(first, 0.0, ref[0])
-    ref[0] = jnp.where(lane == head, column, held)
+    for j, column in enumerate(columns):
+        held = jnp.where(lane == head + j, column, held)
+    ref[0] = held
+
+
+def _inverses(mats, dtype):
+    """(I + a)^-1 for every strictly lower triangular a [C, C] of the list:
+    with b = -a, (I + b)(I + b^2)(I + b^4) ... up to the power that is
+    zero. The factors commute, so the running product stays one factor
+    behind the power and both advance in one product, ``[out; p] @ p``;
+    the list advances level by level, so that neighbours in the program
+    are independent products."""
+    n = mats[0].shape[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    powers = [-a for a in mats]
+    outs = [jnp.where(eye, 1.0, 0.0) + p for p in powers]
+    if n <= 2:
+        return outs
+    powers, reach = [_mm(p, p, _NN, dtype) for p in powers], 2
+    while 2 * reach < n:            # outs lack the factor (I + b^reach)
+        both = [_mm(jnp.concatenate([o, p], axis=0), p, _NN, dtype)
+                for o, p in zip(outs, powers)]
+        outs = [o + x[:n] for o, x in zip(outs, both)]
+        powers, reach = [x[n:] for x in both], 2 * reach
+    return [o + _mm(o, p, _NN, dtype) for o, p in zip(outs, powers)]
 
 
 def _inverse(a, dtype):
-    """(I + a)^-1 for a strictly lower triangular [C, C]: with b = -a,
-    (I + b)(I + b^2)(I + b^4) ... up to the power that is zero."""
-    n = a.shape[0]
-    eye = (jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
-           == jax.lax.broadcasted_iota(jnp.int32, a.shape, 1))
-    power = -a
-    out = jnp.where(eye, 1.0, 0.0) + power
-    reach = 2
-    while reach < n:
-        power = _mm(power, power, _NN, dtype)
-        out = out + _mm(out, power, _NN, dtype)
-        reach *= 2
-    return out
+    """(I + a)^-1 of one matrix: six products at 64."""
+    return _inverses([a], dtype)[0]
 
 
-class _Chunk:
-    """What both kernels compute of one chunk from its inputs alone
-    (float32 unless said): the masks, the decays, A, T, W and U."""
+class _Keys:
+    """What the value heads of a key head share in one chunk: the masks,
+    q and k, and ``[q; k] k^T``."""
 
-    def __init__(self, q, k, v, gam, beta):
-        dt, c = q.dtype, q.shape[0]
+    def __init__(self, q, k):
+        c = q.shape[0]
         row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
         self.eye, self.lower, self.strict = row == col, row >= col, row > col
-        self.q, self.k, self.beta = q, k, beta             # beta [C, 1]
-        self.kf, self.vf = k.astype(jnp.float32), v.astype(jnp.float32)
-        across = self.to_row(gam)                          # [1, C]
-        # exp(gam_i - gam_j) where i >= j: a decay, never above one
-        self.decay = jnp.where(
-            self.lower, jnp.exp(jnp.minimum(gam - across, 0.0)), 0.0)
-        self.e = jnp.exp(gam)                              # [C, 1]
-        last = gam[c - 1:c, :]
-        self.e_last = jnp.exp(last)                        # [1, 1]
-        self.f = jnp.exp(last - gam)                       # [C, 1]
-        self.kk = _mm(k, k, _NT, dt)
-        self.a = jnp.where(self.strict, beta * self.kk * self.decay, 0.0)
-        self.t = _inverse(self.a, dt)
-        self.w = _mm(self.t, self.kf * (beta * self.e), _NN, dt)   # [C, dk]
-        self.u = _mm(self.t, self.vf * beta, _NN, dt)              # [C, dv]
-        self.p = jnp.where(self.lower, _mm(q, k, _NT, dt) * self.decay, 0.0)
+        self.k = k
+        self.qf, self.kf = q.astype(jnp.float32), k.astype(jnp.float32)
+        self.qk = jnp.concatenate([q, k], axis=0)                  # [2C, dk]
+        both = _mm(self.qk, k, _NT, q.dtype)                       # [2C, C]
+        self.qkt, self.kk = both[:c], both[c:]
 
     def to_row(self, column):
         """[C, 1] -> [1, C] (a sum down the diagonal: no relayout)."""
@@ -145,113 +178,190 @@ class _Chunk:
                        keepdims=True)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, h_ref, s_scr):
-    head = pl.program_id(2)
+class _Chunk:
+    """What both kernels compute of one chunk and value head from its
+    inputs alone (float32 unless said), in two stages: the decays and A;
+    then, given T = (I + A)^-1, W, U and P."""
+
+    def __init__(self, keys, v, gam, beta, at, lanes):
+        c = keys.k.shape[0]
+        self.keys, self.beta = keys, beta                  # beta [C, 1]
+        self.at, self.lanes = at, lanes    # its rows and lanes in a block
+        self.vf = v.astype(jnp.float32)
+        across = keys.to_row(gam)                          # [1, C]
+        # exp(gam_i - gam_j) where i >= j: a decay, never above one
+        self.decay = jnp.where(
+            keys.lower, jnp.exp(jnp.minimum(gam - across, 0.0)), 0.0)
+        self.e = jnp.exp(gam)                              # [C, 1]
+        last = gam[c - 1:c, :]
+        self.e_last = jnp.exp(last)                        # [1, 1]
+        self.f = jnp.exp(last - gam)                       # [C, 1]
+        self.a = jnp.where(keys.strict, beta * keys.kk * self.decay, 0.0)
+
+    def finish(self, t):
+        keys, dk = self.keys, self.keys.k.shape[1]
+        self.t = t
+        self.wu = _mm(t, jnp.concatenate(
+            [keys.kf * (self.beta * self.e), self.vf * self.beta], axis=1),
+            _NN, keys.k.dtype)
+        self.w, self.u = self.wu[:, :dk], self.wu[:, dk:]  # [C, dk], [C, dv]
+        self.p = jnp.where(keys.lower, keys.qkt * self.decay, 0.0)
+        self.qe = keys.qf * self.e
+        self.qe_w = jnp.concatenate([self.qe, self.w], axis=0)
+        self.kf_f = keys.kf * self.f
+
+
+def _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, group):
+    """{(chunk, head of the group): _Chunk} of a grid step, every body's
+    first stage, then every inverse, then every second stage."""
+    first = pl.program_id(2) * group               # the step's value heads
+    dv = v_ref.shape[2] // group
+    out = {}
+    for c in range(q_ref.shape[1] // CHUNK):
+        at = slice(c * CHUNK, (c + 1) * CHUNK)
+        keys = _Keys(q_ref[0, at, :], k_ref[0, at, :])
+        for j in range(group):
+            lanes = slice(j * dv, (j + 1) * dv)
+            out[c, j] = _Chunk(keys, v_ref[0, at, lanes],
+                               _column(g_ref[0, at, :], first + j),
+                               _column(b_ref[0, at, :], first + j), at, lanes)
+    for ch, t in zip(out.values(), _inverses(
+            [ch.a for ch in out.values()], q_ref.dtype)):
+        ch.finish(t)
+    return out
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, h_ref, s_scr, *,
+                group):
+    first = pl.program_id(2) * group               # the step's value heads
+    dt = q_ref.dtype
 
     @pl.when(pl.program_id(1) == 0)
     def _():
-        s_scr[head] = jnp.zeros(s_scr.shape[1:], jnp.float32)
+        for j in range(group):
+            s_scr[first + j] = jnp.zeros(s_scr.shape[1:], jnp.float32)
 
-    dt = q_ref.dtype
-    gam_all, beta_all = _column(g_ref[0], head), _column(b_ref[0], head)
-    state = s_scr[head]
-    for c in range(q_ref.shape[1] // CHUNK):
-        at = slice(c * CHUNK, (c + 1) * CHUNK)
-        ch = _Chunk(q_ref[0, at, :], k_ref[0, at, :], v_ref[0, at, :],
-                    gam_all[at], beta_all[at])
-        h_ref[0, 0, c] = state.astype(h_ref.dtype)
-        new = ch.u - _mm(ch.w, state, _NN, dt)                     # V'
-        o = (_mm(ch.q.astype(jnp.float32) * ch.e, state, _NN, dt)
-             + _mm(ch.p, new, _NN, dt))
-        o_ref[0, at, :] = o.astype(o_ref.dtype)
-        state = ch.e_last * state + _mm(ch.kf * ch.f, new, _TN, dt)
-    s_scr[head] = state
+    state = [s_scr[first + j] for j in range(group)]
+    for (c, j), ch in _chunks(q_ref, k_ref, v_ref, g_ref, b_ref,
+                              group).items():
+        h_ref[0, j, c] = state[j].astype(h_ref.dtype)
+        new = ch.u - _mm(ch.w, state[j], _NN, dt)                      # V'
+        o = _mm(ch.qe, state[j], _NN, dt) + _mm(ch.p, new, _NN, dt)
+        o_ref[0, ch.at, ch.lanes] = o.astype(o_ref.dtype)
+        state[j] = ch.e_last * state[j] + _mm(ch.kf_f, new, _TN, dt)
+    for j in range(group):
+        s_scr[first + j] = state[j]
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, h_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_scr, *, group):
-    head = pl.program_id(2)
+    first = pl.program_id(2) * group
+    dt = q_ref.dtype
 
     @pl.when(pl.program_id(1) == 0)
     def _():
-        ds_scr[head] = jnp.zeros(ds_scr.shape[1:], jnp.float32)
+        for j in range(group):
+            ds_scr[first + j] = jnp.zeros(ds_scr.shape[1:], jnp.float32)
 
-    dt = q_ref.dtype
-    gam_all, beta_all = _column(g_ref[0], head), _column(b_ref[0], head)
-    d_state = ds_scr[head]
-    d_gam, d_beta = [], []
-    first_of_group = head % group == 0
-    for c in reversed(range(q_ref.shape[1] // CHUNK)):
-        at = slice(c * CHUNK, (c + 1) * CHUNK)
-        ch = _Chunk(q_ref[0, at, :], k_ref[0, at, :], v_ref[0, at, :],
-                    gam_all[at], beta_all[at])
-        state, do = h_ref[0, 0, c], do_ref[0, at, :]
-        qf, beta, e, f = ch.q.astype(jnp.float32), ch.beta, ch.e, ch.f
-        new = ch.u - _mm(ch.w, state, _NN, dt)                     # V'
-        # O = (Q e) S + P V';  S' = e_last S + (K f)^T V'
-        d_new = (_mm(ch.p, do, _TN, dt)
-                 + _mm(ch.kf * f, d_state, _NN, dt))               # [C, dv]
-        d_p = jnp.where(ch.lower, _mm(do, new, _NT, dt), 0.0)
-        d_qe = _mm(do, state, _NT, dt)                             # [C, dk]
-        d_kf = _mm(new, d_state, _NT, dt)                          # [C, dk]
-        d_last = jnp.sum(d_state * state.astype(jnp.float32), keepdims=True)
-        # V' = U - W S
-        d_w = -_mm(d_new, state, _NT, dt)                          # [C, dk]
-        d_state = (_mm(qf * e, do, _TN, dt) + ch.e_last * d_state
-                   - _mm(ch.w, d_new, _TN, dt))
-        # W = T (K beta e), U = T (V beta), T = (I + A)^-1
-        d_kb = _mm(ch.t, d_w, _TN, dt)                             # [C, dk]
-        d_vb = _mm(ch.t, d_new, _TN, dt)                           # [C, dv]
-        d_a = jnp.where(ch.strict, -(_mm(d_kb, ch.w, _NT, dt)
-                                     + _mm(d_vb, ch.u, _NT, dt)), 0.0)
+    chunks = _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, group)
+    ids = list(chunks)[::-1]                       # the chunks in reverse
+    state = {(c, j): h_ref[0, j, c] for c, j in ids}
+    do = {i: do_ref[0, chunks[i].at, chunks[i].lanes].astype(jnp.float32)
+          for i in ids}
+    # what needs no dS.  O = (Q e) S + P V';  V' = U - W S
+    new = {i: chunks[i].u - _mm(chunks[i].w, state[i], _NN, dt) for i in ids}
+    p_do = {i: _mm(chunks[i].p, do[i], _TN, dt) for i in ids}
+    d_p = {i: jnp.where(chunks[i].keys.lower, _mm(do[i], new[i], _NT, dt),
+                        0.0) for i in ids}
+    # the chain.  S' = e_last S + (K f)^T V'
+    d_state = [ds_scr[first + j] for j in range(group)]
+    d_new, d_kf, d_last = {}, {}, {}
+    for i in ids:
+        ch, j = chunks[i], i[1]
+        d_new[i] = p_do[i] + _mm(ch.kf_f, d_state[j], _NN, dt)     # [C, dv]
+        d_kf[i] = _mm(new[i], d_state[j], _NT, dt)                 # [C, dk]
+        d_last[i] = jnp.sum(d_state[j] * state[i].astype(jnp.float32),
+                            keepdims=True)
+        d_state[j] = ch.e_last * d_state[j] + _mm(
+            ch.qe_w, jnp.concatenate([do[i], -d_new[i]], axis=0), _TN, dt)
+    for j in range(group):
+        ds_scr[first + j] = d_state[j]
+    # what hangs off it.  W = T (K beta e), U = T (V beta), T = (I + A)^-1
+    both = {i: _mm(jnp.concatenate([do[i], d_new[i]], axis=0), state[i], _NT,
+                   dt) for i in ids}
+    d_qe = {i: both[i][:CHUNK] for i in ids}                       # [C, dk]
+    d_w = {i: -both[i][CHUNK:] for i in ids}
+    d_kb_vb = {i: _mm(chunks[i].t, jnp.concatenate([d_w[i], d_new[i]],
+                                                   axis=1), _TN, dt)
+               for i in ids}
+    d_a = {i: jnp.where(chunks[i].keys.strict,
+                        -_mm(d_kb_vb[i], chunks[i].wu, _NT, dt), 0.0)
+           for i in ids}
+    d_gam, d_beta = [[] for _ in range(group)], [[] for _ in range(group)]
+    # summed over a key head's value heads: dq, dk without their products
+    # against q and k, and [d_qk; d_kk] for those
+    sums = {}
+    for i in ids:
+        (c, j), ch, keys = i, chunks[i], chunks[i].keys
+        beta, e, f = ch.beta, ch.e, ch.f
+        dk_ = keys.k.shape[1]
+        d_kb, d_vb = d_kb_vb[i][:, :dk_], d_kb_vb[i][:, dk_:]
         # A = beta KK Gam (strict), P = QK Gam (lower)
-        d_kk = d_a * beta * ch.decay
-        d_qk = d_p * ch.decay
-        through = d_a * ch.a + d_p * ch.p        # dGam * Gam
-        kb_k = jnp.sum(d_kb * ch.kf, axis=1, keepdims=True)
-        kf_k = f * jnp.sum(d_kf * ch.kf, axis=1, keepdims=True)
-        d_beta.append(
-            jnp.sum(d_a * ch.kk * ch.decay, axis=1, keepdims=True)
+        d_kk = d_a[i] * beta * ch.decay
+        d_qk = d_p[i] * ch.decay
+        through = d_a[i] * ch.a + d_p[i] * ch.p      # dGam * Gam
+        kb_k = jnp.sum(d_kb * keys.kf, axis=1, keepdims=True)
+        kf_k = f * jnp.sum(d_kf[i] * keys.kf, axis=1, keepdims=True)
+        d_beta[j].append(
+            jnp.sum(d_a[i] * keys.kk * ch.decay, axis=1, keepdims=True)
             + e * kb_k + jnp.sum(d_vb * ch.vf, axis=1, keepdims=True))
         dg = (jnp.sum(through, axis=1, keepdims=True)
-              - ch.to_column(jnp.sum(through, axis=0, keepdims=True))
+              - keys.to_column(jnp.sum(through, axis=0, keepdims=True))
               + beta * e * kb_k
-              + e * jnp.sum(d_qe * qf, axis=1, keepdims=True) - kf_k)
+              + e * jnp.sum(d_qe[i] * keys.qf, axis=1, keepdims=True) - kf_k)
         is_last = jax.lax.broadcasted_iota(jnp.int32, dg.shape, 0) \
             == CHUNK - 1
-        d_gam.append(dg + jnp.where(
-            is_last, jnp.sum(kf_k, keepdims=True) + ch.e_last * d_last, 0.0))
-        dq = e * d_qe + _mm(d_qk, ch.k, _NN, dt)
-        dk = (_mm(d_kk, ch.k, _NN, dt) + _mm(d_kk, ch.k, _TN, dt)
-              + _mm(d_qk, ch.q, _TN, dt) + beta * e * d_kb + f * d_kf)
-        dv_ref[0, at, :] = (beta * d_vb).astype(dv_ref.dtype)
-        # a key head's value heads come one after the other: the first
-        # writes, the others add
-        for ref, val in ((dq_ref, dq), (dk_ref, dk)):
-            held = jnp.where(first_of_group, 0.0,
-                             ref[0, at, :].astype(jnp.float32))
-            ref[0, at, :] = (held + val).astype(ref.dtype)
-    ds_scr[head] = d_state
-    _put_column(dg_ref, head, jnp.concatenate(d_gam[::-1], axis=0), head == 0)
-    _put_column(db_ref, head, jnp.concatenate(d_beta[::-1], axis=0),
-                head == 0)
+        d_gam[j].append(dg + jnp.where(
+            is_last, jnp.sum(kf_k, keepdims=True) + ch.e_last * d_last[i],
+            0.0))
+        dv_ref[0, ch.at, ch.lanes] = (beta * d_vb).astype(dv_ref.dtype)
+        dq, dk, d_both = sums.get(c, (0.0, 0.0, 0.0))
+        sums[c] = (dq + e * d_qe[i], dk + beta * e * d_kb + f * d_kf[i],
+                   d_both + jnp.concatenate([d_qk, d_kk], axis=0))
+    # dq += d_qk k;  dk += d_kk k + d_kk^T k + d_qk^T q
+    both = {c: _mm(d_both, chunks[c, 0].keys.k, _NN, dt)           # [2C, dk]
+            for c, (_, _, d_both) in sums.items()}
+    across = {c: _mm(d_both, chunks[c, 0].keys.qk, _TN, dt)
+              for c, (_, _, d_both) in sums.items()}
+    for c, (dq, dk, _) in sums.items():
+        at = chunks[c, 0].at
+        dq_ref[0, at, :] = (dq + both[c][:CHUNK]).astype(dq_ref.dtype)
+        dk_ref[0, at, :] = (dk + both[c][CHUNK:] + across[c]).astype(
+            dk_ref.dtype)
+    clear = pl.program_id(2) == 0
+    _put_column(dg_ref, first,
+                [jnp.concatenate(d[::-1], axis=0) for d in d_gam], clear)
+    _put_column(db_ref, first,
+                [jnp.concatenate(d[::-1], axis=0) for d in d_beta], clear)
 
 
-def _block(s: int) -> int:
-    """Tokens a grid step holds: whole chunks, dividing ``s``."""
+def _block(s: int, group: int) -> int:
+    """Tokens a grid step holds: whole chunks, dividing ``s``, and no more
+    (chunk, value head) bodies than ``_BODIES`` where fewer chunks do."""
     for b in (_BLOCK, _BLOCK // 2, CHUNK):
-        if s % b == 0:
+        if s % b == 0 and (b // CHUNK * group <= _BODIES or b == CHUNK):
             return b
     raise ValueError(f"sequence {s} is no multiple of the chunk {CHUNK}")
 
 
 def _specs(blk, dk, dv, hv, group, block_of):
-    """The blocks of a grid step (batch i, step t, value head h) in the
-    arrays [B, S, heads * d]: a key head's, a value head's, and all the
-    heads' columns of g / beta; ``block_of(t)``: the sequence block."""
-    return (pl.BlockSpec((1, blk, dk),
-                         lambda i, t, h: (i, block_of(t), h // group)),
-            pl.BlockSpec((1, blk, dv), lambda i, t, h: (i, block_of(t), h)),
+    """The blocks of a grid step (batch i, step t, key head h) in the
+    arrays [B, S, heads * d]: the key head's, its ``group`` value heads',
+    and all the heads' columns of g / beta; ``block_of(t)``: the sequence
+    block."""
+    return (pl.BlockSpec((1, blk, dk), lambda i, t, h: (i, block_of(t), h)),
+            pl.BlockSpec((1, blk, group * dv),
+                         lambda i, t, h: (i, block_of(t), h)),
             pl.BlockSpec((1, blk, hv), lambda i, t, h: (i, block_of(t), 0)))
 
 
@@ -269,7 +379,8 @@ def _gdn_fwd(q, k, v, g, beta, interpret: bool):
     that a model's layers share one trace and lowering."""
     b, s, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
-    group, blk = hv // hk, _block(s)
+    group = hv // hk
+    blk = _block(s, group)
     gam = _within_chunks(g.astype(jnp.float32))
     beta = beta.astype(jnp.float32)
     steps = blk // CHUNK
@@ -277,12 +388,12 @@ def _gdn_fwd(q, k, v, g, beta, interpret: bool):
     key, value, heads = _specs(blk, dk, dv, hv, group, lambda t: t)
 
     o, states = pl.pallas_call(
-        _fwd_kernel,
-        grid=(b, s // blk, hv),
+        functools.partial(_fwd_kernel, group=group),
+        grid=(b, s // blk, hk),
         in_specs=[key, key, value, heads, heads],
         out_specs=[
             value,
-            pl.BlockSpec((1, 1, steps, dk, dv),
+            pl.BlockSpec((1, group, steps, dk, dv),
                          lambda i, t, h: (i, h, t, 0, 0)),
         ],
         out_shape=[
@@ -302,17 +413,18 @@ def _gdn_fwd(q, k, v, g, beta, interpret: bool):
 def _gdn_bwd(q, k, v, gam, beta, states, do, interpret: bool):
     b, s, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
-    group, blk = hv // hk, _block(s)
+    group = hv // hk
+    blk = _block(s, group)
     last, steps = s // blk - 1, blk // CHUNK
     beta = beta.astype(jnp.float32)
 
     key, value, heads = _specs(blk, dk, dv, hv, group, lambda t: last - t)
     dq, dk_, dv_, d_gam, d_beta = pl.pallas_call(
         functools.partial(_bwd_kernel, group=group),
-        grid=(b, s // blk, hv),
+        grid=(b, s // blk, hk),
         in_specs=[
             key, key, value, heads, heads,
-            pl.BlockSpec((1, 1, steps, dk, dv),
+            pl.BlockSpec((1, group, steps, dk, dv),
                          lambda i, t, h: (i, h, last - t, 0, 0)),
             value,
         ],
@@ -345,10 +457,11 @@ def gated_delta_rule(q, k, v, g, beta, interpret: Optional[bool] = None):
 
 
 def _rule_fwd(q, k, v, g, beta, interpret):
-    _block(q.shape[1])        # a ValueError where S is no multiple of the chunk
     if v.shape[2] % q.shape[2]:
         raise ValueError(f"{v.shape[2]} value heads over {q.shape[2]} key "
                          "heads")
+    # a ValueError where S is no multiple of the chunk
+    _block(q.shape[1], v.shape[2] // q.shape[2])
     if interpret is None:
         interpret = _interpret_default()
     o, states, gam = _gdn_fwd(q, k, v, g, beta, interpret)

@@ -185,9 +185,12 @@ def test_grad_of_the_grouped_matmul_compiles_for_a_v5e(one_chip, shape):
 # (sequence, key heads, value heads, dtype): the gated delta rule
 RECURRENCES = {
     # train-qwen3next-4l-16k's three linear layers: 16 key / 32 value
-    # heads of 128 over 16,384 tokens, 64 grid steps of four chunks a head
+    # heads of 128 over 16,384 tokens, 128 grid steps of two chunks a key
+    # head, its two value heads in one step
     "cell-qwen3next": (16384, 16, 32, jnp.bfloat16),
     "float32": (1024, 2, 4, jnp.float32),
+    # one value head a key head: the same body with a group of one
+    "group1": (1024, 4, 4, jnp.bfloat16),
 }
 
 

@@ -1,9 +1,13 @@
 """``ops/gated_delta_rule.py`` (interpret mode, CPU) against the
 recurrence it computes, token by token: forward and all five gradients,
-at two and four chunks, with two value heads to a key head and decays
-drawn so that what survives a chunk spans 0.05 to 0.99 over the heads.
+at two and four chunks with two value heads to a key head, and at two
+chunks with one and with four (a grid step holds a key head's value
+heads, however many), with decays drawn so that what survives a chunk
+spans 0.05 to 0.99 over the heads.
 A scan that DROPS its carried state at each chunk's start — the one
-fault a chunked scan invites — must fail the same tolerance.
+fault a chunked scan invites — must fail the same tolerance. The
+inverse of a chunk's triangle is held against ``jnp.linalg.inv`` and to
+its count of products.
 
 Tolerances: kernel and recurrence both run in float32 here, so only the
 order of sums differs: 1e-4 of each array's largest entry (the readings
@@ -13,7 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.ops.gated_delta_rule import CHUNK, gated_delta_rule
+from paddle_tpu.ops.gated_delta_rule import (CHUNK, _inverse,
+                                             gated_delta_rule)
 
 TOL = 1e-4
 NAMES = ("q", "k", "v", "g", "beta")
@@ -67,9 +72,15 @@ def _worst(got, want):
     return float(jnp.abs(got - want).max() / jnp.abs(want).max())
 
 
-@pytest.fixture(scope="module", params=[128, 256], ids=["s128", "s256"])
+# (sequence, key heads, value heads): a key head's group of 2, 1 and 4
+CASES = {"s128": (128, 2, 4), "s256": (256, 2, 4), "group1": (128, 2, 2),
+         "group4": (128, 1, 4)}
+
+
+@pytest.fixture(scope="module", params=CASES.values(), ids=CASES.keys())
 def case(request):
-    args, weight = _inputs(request.param)
+    s, hk, hv = request.param
+    args, weight = _inputs(s, hk=hk, hv=hv)
     loss = lambda fn: (lambda *a: jnp.sum(fn(*a) * weight))
     both = lambda fn: jax.value_and_grad(loss(fn), argnums=(0, 1, 2, 3, 4))
     return {"args": args, "want_o": recurrence(*args),
@@ -80,8 +91,8 @@ def test_forward_is_the_recurrence(case):
     got = gated_delta_rule(*case["args"])
     assert got.shape == case["want_o"].shape
     assert _worst(got, case["want_o"]) <= TOL
-    left = np.exp(np.asarray(case["args"][3]).reshape(
-        2, -1, CHUNK, 4).sum(2))
+    g = np.asarray(case["args"][3])
+    left = np.exp(g.reshape(2, -1, CHUNK, g.shape[-1]).sum(2))
     assert left.min() < 0.08 and left.max() > 0.98     # the decays' spread
 
 
@@ -98,6 +109,23 @@ def test_a_scan_that_drops_its_state_fails_the_same_tolerance(case):
     assert _worst(dropped_state(*case["args"]), case["want_o"]) > 100 * TOL
     got = case["both"](dropped_state)(*case["args"])[1]
     assert all(_worst(a, b) > 100 * TOL for a, b in zip(got, case["want"]))
+
+
+@pytest.mark.parametrize("n, products", [(64, 6), (16, 4)])
+def test_the_inverse_of_a_chunks_triangle(n, products):
+    """``_inverse`` is (I + a)^-1 for a strictly lower triangular ``a``
+    of a chunk's scale (entries of beta k.k' Gam: below one), in the
+    products its factors need when the running product stays one factor
+    behind the power: six at 64, not ten."""
+    a = jnp.tril(jax.random.uniform(jax.random.key(n), (n, n), minval=-1.0),
+                 -1) / np.sqrt(n)
+    want = jnp.linalg.inv(jnp.eye(n) + a)
+    got = _inverse(a, jnp.float32)
+    assert float(jnp.abs(got - want).max()) <= 1e-5 * float(
+        jnp.abs(want).max())
+    assert np.allclose(got @ (jnp.eye(n) + a), jnp.eye(n), atol=1e-5)
+    eqns = jax.make_jaxpr(lambda x: _inverse(x, jnp.float32))(a).jaxpr.eqns
+    assert sum(e.primitive.name == "dot_general" for e in eqns) == products
 
 
 def test_a_sequence_that_is_no_multiple_of_the_chunk_is_an_error():
