@@ -10,7 +10,10 @@ attention mixed 3:1, a softmax top-k router beside a gated shared expert,
 a held share, training path only; minicpm_sala: lightning linear attention
 and block-sparse softmax attention mixed by a published list, dense
 SwiGLU, muP scalings, a held share of layers and vocabulary, training path
-only; vision models live in paddle_tpu.vision
+only; granite_hybrid: Mamba-2 state-space layers and position-free
+grouped-query attention mixed by a published list, dense SwiGLU, Granite's
+four multipliers, a tied head, a held share of layers and vocabulary,
+training path only; vision models live in paddle_tpu.vision
 (config #1).
 """
 from .llama import (  # noqa: F401
@@ -49,6 +52,12 @@ from .minicpm_sala import (  # noqa: F401
     MiniCPMSALADecoderLayer,
     MiniCPMSALAForCausalLM,
     MiniCPMSALAModel,
+)
+from .granite_hybrid import (  # noqa: F401
+    GraniteHybridConfig,
+    GraniteHybridDecoderLayer,
+    GraniteHybridForCausalLM,
+    GraniteHybridModel,
 )
 from .unet import UNet2DConditionModel, UNetConfig  # noqa: F401
 from .generation import generate  # noqa: F401

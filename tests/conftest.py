@@ -204,6 +204,17 @@ _OUTDATED = {
     "test_chipbench_compile_spans.py::test_the_entries_in_benchmark_json":
         "PR 38 appended five per-layer metrics after PR 36's six (PERF.md "
         "section 7)",
+    # and two PRs on again: PR 38's test cuts the lists where ITS entries
+    # begin and then runs PR 34's body, which pops the last name of every
+    # list that holds PR 34's cell; issue 40 has a cell appended to the
+    # two ``.gqa`` flash lists, which hold PR 34's cell and not PR 38's.
+    # tests/chipbench/test_chipbench_granite.py::
+    # test_the_cells_before_it_are_as_their_prs_left_them calls its body
+    # and PR 38's own ``gains`` on the benchmark without PR 40's entries.
+    "test_chipbench_minicpm_sala.py::"
+    "test_the_cells_before_it_are_as_their_prs_left_them":
+        "PR 40 appended its cell to the two .gqa flash lists after PR 34's "
+        "(PERF.md section 7)",
 }
 
 

@@ -79,6 +79,9 @@ SHAPES = {
     # train-qwen3next-4l-16k's one full layer: GQA 16/2 at d 256 and
     # 16,384 keys: the loop, on tiles half as long as d 128's
     "cell-qwen3next-full": (16384, 16384, 16, 2, 256, True, jnp.bfloat16),
+    # train-granite4h-10l-16k's one attention layer: GQA 32/8 at d 64 (half
+    # the MXU's depth a contraction) and 16,384 keys, the loop
+    "cell-granite4h-full": (16384, 16384, 32, 8, 64, True, jnp.bfloat16),
 }
 
 # (sq, sk, q heads, kv heads, d_head, window, dtype): a sliding window
@@ -272,6 +275,75 @@ def test_grad_of_lightning_attention_compiles_for_a_v5e(one_chip, shape):
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 2  # one forward, one backward kernel
     for reader in (lightning_fwd_roofline, lightning_bwd_roofline):
+        for pattern in (reader.KERNELS, reader.WRITER):
+            assert sum(bool(re.search(pattern, c)) for c in calls) == 1
+
+
+# (sequence, heads, state, dtype): the state-space scan, heads of 64
+SCANS = {
+    # train-granite4h-10l-16k's nine Mamba layers: 64 heads of 64 over
+    # 16,384 tokens, state 128; 32 x 8 grid steps of four chunks
+    "cell-granite4h": (16384, 64, 128, jnp.bfloat16),
+    "float32": (1024, 4, 128, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("shape", SCANS.values(), ids=SCANS.keys())
+def test_grad_of_the_state_space_scan_compiles_for_a_v5e(one_chip, shape):
+    from chipbench.layer_metrics import ssd_bwd_roofline, ssd_fwd_roofline
+    from paddle_tpu.ops.mamba2_ssd import ssd
+
+    s, h, n, dtype = shape
+
+    def loss(x, dt, a, b, c, d):
+        return ssd(x, dt, a, b, c, d, False).astype(jnp.float32).sum()
+
+    def of(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(jax.grad(loss, tuple(range(6)))).lower(
+        of((1, s, h * 64), dtype), of((1, s, h)), of((h,)),
+        of((1, s, n), dtype), of((1, s, n), dtype), of((h,))).compile()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2  # one forward, one backward kernel
+    for reader in (ssd_fwd_roofline, ssd_bwd_roofline):
+        for pattern in (reader.KERNELS, reader.WRITER):
+            assert sum(bool(re.search(pattern, c)) for c in calls) == 1
+
+
+# (sequence, channels, dtype): the Mamba layers' convolution
+CONVS = {
+    # train-granite4h-10l-16k: x, B and C side by side, 4096 + 2 x 128
+    # channels in 17 column blocks of 256
+    "cell-granite4h": (16384, 4352, jnp.bfloat16),
+    "float32": (1024, 512, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("shape", CONVS.values(), ids=CONVS.keys())
+def test_grad_of_the_convolution_compiles_for_a_v5e(one_chip, shape):
+    from chipbench.layer_metrics import (conv_silu_bwd_roofline,
+                                         conv_silu_fwd_roofline)
+    from paddle_tpu.ops.conv_silu import conv_silu
+
+    s, c, dtype = shape
+
+    def loss(x, w, b):
+        return conv_silu(x, w, b, False).astype(jnp.float32).sum()
+
+    def of(shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    # the backward needs no result of the forward: the value keeps it
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+        of((1, s, c)), of((4, c)), of((c,))).compile()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2  # one forward, one backward kernel
+    for reader in (conv_silu_fwd_roofline, conv_silu_bwd_roofline):
         for pattern in (reader.KERNELS, reader.WRITER):
             assert sum(bool(re.search(pattern, c)) for c in calls) == 1
 

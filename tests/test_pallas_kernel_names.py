@@ -13,8 +13,9 @@ import chip_smoke
 from chipbench.layer_metrics import (flash_bwd_roofline, flash_fwd_roofline,
                                      moe_gmm_roofline)
 from paddle_tpu.ops import flash_attention as flash
-from paddle_tpu.ops import (gated_delta_rule, gdn_inputs, grouped_matmul,
-                            lightning_attention, sparse_attention)
+from paddle_tpu.ops import (conv_silu, gated_delta_rule, gdn_inputs,
+                            grouped_matmul, lightning_attention, mamba2_ssd,
+                            sparse_attention)
 
 
 def _lowered_for_tpu(fn, *args):
@@ -94,6 +95,30 @@ def _sparse_attention_program():
     return _lowered_for_tpu(jax.grad(loss, (0, 1, 2)), q, k, k, table)
 
 
+def _ssd_program():
+    def loss(x, dt, a, b, c, d):
+        return mamba2_ssd.ssd(x, dt, a, b, c, d,
+                              False).astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((1, 256, 4 * 64), jnp.bfloat16)
+    dt = jax.ShapeDtypeStruct((1, 256, 4), jnp.float32)
+    a = jax.ShapeDtypeStruct((4,), jnp.float32)
+    b = jax.ShapeDtypeStruct((1, 256, 128), jnp.bfloat16)
+    return _lowered_for_tpu(jax.grad(loss, (0, 1, 2, 3, 4, 5)),
+                            x, dt, a, b, b, a)
+
+
+def _conv_silu_program():
+    def loss(x, w, b):
+        return conv_silu.conv_silu(x, w, b, False).astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((1, 256, 512), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((4, 512), jnp.bfloat16)
+    b = jax.ShapeDtypeStruct((512,), jnp.bfloat16)
+    # the backward needs no result of the forward: the value keeps it
+    return _lowered_for_tpu(jax.value_and_grad(loss, (0, 1, 2)), x, w, b)
+
+
 @pytest.mark.parametrize("program, wanted", [
     (_flash_program, chip_smoke.FLASH_KERNELS),
     (_grouped_matmul_program, chip_smoke.MOE_KERNELS),
@@ -102,8 +127,11 @@ def _sparse_attention_program():
     (_gdn_inputs_program, gdn_inputs.KERNELS),
     (_lightning_program, lightning_attention.KERNELS),
     (_sparse_attention_program, sparse_attention.KERNELS),
+    (_ssd_program, mamba2_ssd.KERNELS),
+    (_conv_silu_program, conv_silu.KERNELS),
 ], ids=["flash", "grouped_matmul", "flash_window", "gated_delta_rule",
-        "gdn_inputs", "lightning_attention", "sparse_attention"])
+        "gdn_inputs", "lightning_attention", "sparse_attention",
+        "mamba2_ssd", "conv_silu"])
 def test_kernel_names_are_the_ones_chip_smoke_requires(program, wanted):
     have = chip_smoke.kernels_in(program())
     assert have == sorted(wanted)
@@ -209,8 +237,9 @@ def test_gated_delta_rule_readers_patterns_match_the_kernel_names():
 
 
 @pytest.mark.parametrize("module, stem", [
-    (lightning_attention, "lightning"), (sparse_attention, "sparse_attn")],
-    ids=["lightning", "sparse_attn"])
+    (lightning_attention, "lightning"), (sparse_attention, "sparse_attn"),
+    (mamba2_ssd, "ssd"), (conv_silu, "conv_silu")],
+    ids=["lightning", "sparse_attn", "ssd", "conv_silu"])
 def test_minicpm_salas_readers_patterns_match_the_kernel_names(module, stem):
     """Every forward kernel's name starts ``<stem>_fwd``, every backward
     one's ``<stem>_bwd``, and each reader finds its direction's kernels,
@@ -228,7 +257,10 @@ def test_minicpm_salas_readers_patterns_match_the_kernel_names(module, stem):
         assert re.search(reader.KERNELS, helper)
         assert not re.search(reader.WRITER, helper)
     other = (chip_smoke.FLASH_KERNELS + chip_smoke.MOE_KERNELS
-             + gated_delta_rule.KERNELS)
+             + gated_delta_rule.KERNELS + gdn_inputs.KERNELS
+             + tuple(k for m in (lightning_attention, sparse_attention,
+                                 mamba2_ssd, conv_silu) if m is not module
+                     for k in m.KERNELS))
     for name in other:
         text = f"%{name}.1 = bf16[8]{{0}} custom-call("
         assert not any(re.search(p, text) for r in readers.values()
