@@ -208,8 +208,11 @@ class LightningAttention(nn.Layer):
 class BlockSparseAttention(nn.Layer):
     """The sparse softmax mixer (module docstring). ``blocks_chosen`` and
     ``query_rows`` hold, on the device, the blocks the LAST forward's
-    table named and the (token, kv group) rows it named them for (int32:
-    a sum over a run's steps would wrap). Training forward only."""
+    table named and the (token, kv group) rows it named them for,
+    ``band_blocks`` how many of those block reads the kernels' band pass
+    served, once a query block (0 where the shapes keep it from
+    engaging) (int32: a sum over a run's steps would wrap). Training
+    forward only."""
 
     def __init__(self, config: MiniCPMSALAConfig):
         super().__init__()
@@ -217,6 +220,10 @@ class BlockSparseAttention(nn.Layer):
         self.nq, self.nkv, self.d = (c.num_attention_heads,
                                      c.num_key_value_heads, c.head_dim)
         self.eps, self.rule = c.rms_norm_eps, dict(c.sparse_config)
+        # the band the rule forces, as the kernels are told it
+        self.band = dict(init_blocks=self.rule["init_blocks"],
+                         window_blocks=(self.rule["window_size"]
+                                        // self.rule["block_size"]))
         h, q, k = c.hidden_size, self.nq * self.d, self.nkv * self.d
         self.q_proj = nn.Linear(h, q, bias_attr=False)
         self.k_proj = nn.Linear(h, k, bias_attr=False)
@@ -225,14 +232,15 @@ class BlockSparseAttention(nn.Layer):
         self.o_proj = nn.Linear(q, h, bias_attr=False)
         self.q_norm_weight = _gain(self, self.d)
         self.k_norm_weight = _gain(self, self.d)
-        for name in ("blocks_chosen", "query_rows"):
+        for name in ("blocks_chosen", "query_rows", "band_blocks"):
             self.register_buffer(name, Tensor(jnp.zeros([], jnp.int32),
                                               _internal=True))
 
     def forward(self, a, routing=None):
         """``routing``: a list that is given the table, block ids [B, kv
         groups, S, topk]."""
-        from ..ops.sparse_attention import (block_sparse_attention,
+        from ..ops.sparse_attention import (band_blocks,
+                                            block_sparse_attention,
                                             select_blocks)
         from ..tensor import manipulation as M
 
@@ -253,11 +261,14 @@ class BlockSparseAttention(nn.Layer):
             jnp.sum(table._data >= 0, dtype=jnp.int32))
         self.query_rows.set_value(
             jnp.asarray(math.prod(table.shape[:3]), jnp.int32))
+        self.band_blocks.set_value(jnp.asarray(
+            b * self.nkv * band_blocks(s, table.shape[3], **self.band),
+            jnp.int32))
         if routing is not None:
             routing.append(table)
         with jax.named_scope("sparse.attend"):
-            o = apply(block_sparse_attention, q, k, v, table,
-                      op_name="block_sparse_attention")
+            o = apply(functools.partial(block_sparse_attention, **self.band),
+                      q, k, v, table, op_name="block_sparse_attention")
         with jax.named_scope("sparse.gate"):
             out = M.reshape(o, [b, s, self.nq * self.d]) * F.sigmoid(z)
             return self.o_proj(out)
@@ -351,3 +362,8 @@ class MiniCPMSALAForCausalLM(nn.Layer):
         """[sparse blocks] int32: the (token, kv group) rows they named
         them for."""
         return jnp.stack([a.query_rows._data for a in self.sparse_layers()])
+
+    def band_blocks(self):
+        """[sparse blocks] int32: the block reads of ``blocks_chosen``
+        that the kernels' band pass served."""
+        return jnp.stack([a.band_blocks._data for a in self.sparse_layers()])

@@ -348,14 +348,18 @@ def test_grad_of_the_convolution_compiles_for_a_v5e(one_chip, shape):
             assert sum(bool(re.search(pattern, c)) for c in calls) == 1
 
 
-# (sequence, query heads, kv groups, blocks a token, dtype): block-sparse
+# (sequence, query heads, kv groups, blocks a token, dtype, the rule's
+# band: first blocks and window blocks forced, or none): block-sparse
 # attention
 SPARSE = {
     # train-minicpmsala-4l-16k's one sparse layer: 32 / 2 heads of 128,
     # 64 blocks a token of the 256; a group's K, V (4 MB each) and their
-    # float32 gradients (8 MB each) resident in VMEM
-    "cell-minicpmsala": (16384, 32, 2, 64, jnp.bfloat16),
-    "short": (2048, 32, 2, 8, jnp.bfloat16),
+    # float32 gradients (8 MB each) resident in VMEM — as the cell runs
+    # it, with the published rule's band (1 first block, a window of 32),
+    # and on any table
+    "cell-minicpmsala": (16384, 32, 2, 64, jnp.bfloat16, (1, 32)),
+    "cell-minicpmsala-any-table": (16384, 32, 2, 64, jnp.bfloat16, None),
+    "short": (2048, 32, 2, 8, jnp.bfloat16, None),
 }
 
 
@@ -365,10 +369,10 @@ def test_grad_of_block_sparse_attention_compiles_for_a_v5e(one_chip, shape):
                                          sparse_attn_fwd_roofline)
     from paddle_tpu.ops.sparse_attention import block_sparse_attention
 
-    s, h, g, picks, dtype = shape
+    s, h, g, picks, dtype, band = shape
 
     def loss(q, k, v, table):
-        out = block_sparse_attention(q, k, v, table, False)
+        out = block_sparse_attention(q, k, v, table, False, *(band or ()))
         return out.astype(jnp.float32).sum()
 
     q = jax.ShapeDtypeStruct((1, s, h, 128), dtype, sharding=one_chip)
@@ -381,10 +385,12 @@ def test_grad_of_block_sparse_attention_compiles_for_a_v5e(one_chip, shape):
     calls = [line.strip().removeprefix("ROOT ")
              for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 2  # one forward, one backward kernel
+    # one forward, one backward kernel, and with a band its pass each way
+    assert len(calls) == (4 if band else 2)
     for reader in (sparse_attn_fwd_roofline, sparse_attn_bwd_roofline):
-        for pattern in (reader.KERNELS, reader.WRITER):
-            assert sum(bool(re.search(pattern, c)) for c in calls) == 1
+        found = {pattern: sum(bool(re.search(pattern, c)) for c in calls)
+                 for pattern in (reader.KERNELS, reader.WRITER)}
+        assert found == {reader.KERNELS: 2 if band else 1, reader.WRITER: 1}
     # work proportional to the table: no [S, S] array anywhere
     assert not re.search(rf"\[(\d+,)*{s},{s}\]", text)
 

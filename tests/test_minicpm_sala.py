@@ -141,9 +141,11 @@ def test_loss_and_every_leafs_gradient_agree(cfg, program, wanted, ids):
 
 def _counters_are_one_forwards(model):
     # 2 rows x 2 kv groups x 256 tokens a sparse layer; 1, 2, 3, 3 blocks
-    # a token of the four key blocks
+    # a token of the four key blocks, of which the band (the first block
+    # and a window of one) serves 1, 2, 2, 2
     assert (np.asarray(model.query_rows()) == 2 * 2 * SEQ).all()
     assert (np.asarray(model.blocks_chosen()) == 2.25 * 2 * 2 * SEQ).all()
+    assert (np.asarray(model.band_blocks()) == 1.75 * 2 * 2 * SEQ).all()
 
 
 def test_the_counters_are_the_last_forwards_and_do_not_add_up(program, ids):

@@ -95,6 +95,19 @@ def _sparse_attention_program():
     return _lowered_for_tpu(jax.grad(loss, (0, 1, 2)), q, k, k, table)
 
 
+def _sparse_band_program():
+    """The same with the rule that made the table: 4 blocks of keys, 1
+    first block and a window of 2 forced, one free column."""
+    def loss(q, k, v, table):
+        return sparse_attention.block_sparse_attention(
+            q, k, v, table, False, 1, 2).astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, 256, 32, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16)
+    table = jax.ShapeDtypeStruct((1, 2, 256, 4), jnp.int32)
+    return _lowered_for_tpu(jax.grad(loss, (0, 1, 2)), q, k, k, table)
+
+
 def _ssd_program():
     def loss(x, dt, a, b, c, d):
         return mamba2_ssd.ssd(x, dt, a, b, c, d,
@@ -127,11 +140,13 @@ def _conv_silu_program():
     (_gdn_inputs_program, gdn_inputs.KERNELS),
     (_lightning_program, lightning_attention.KERNELS),
     (_sparse_attention_program, sparse_attention.KERNELS),
+    (_sparse_band_program,
+     sparse_attention.KERNELS + sparse_attention.BAND_KERNELS),
     (_ssd_program, mamba2_ssd.KERNELS),
     (_conv_silu_program, conv_silu.KERNELS),
 ], ids=["flash", "grouped_matmul", "flash_window", "gated_delta_rule",
         "gdn_inputs", "lightning_attention", "sparse_attention",
-        "mamba2_ssd", "conv_silu"])
+        "sparse_attention_band", "mamba2_ssd", "conv_silu"])
 def test_kernel_names_are_the_ones_chip_smoke_requires(program, wanted):
     have = chip_smoke.kernels_in(program())
     assert have == sorted(wanted)
@@ -265,3 +280,24 @@ def test_minicpm_salas_readers_patterns_match_the_kernel_names(module, stem):
         text = f"%{name}.1 = bf16[8]{{0}} custom-call("
         assert not any(re.search(p, text) for r in readers.values()
                        for p in (r.KERNELS, r.WRITER))
+
+
+@pytest.mark.parametrize("way", range(2), ids=["fwd", "bwd"])
+def test_the_band_kernels_count_in_their_readers_time_and_are_no_pass(way):
+    """The band program shows two events a direction: the reader's
+    ``KERNELS`` pattern finds both (their time is the layer's), its
+    ``WRITER`` the one that writes ``o`` / ``dq`` (a pass), and neither
+    the other direction's."""
+    assert sparse_attention.KERNELS == ("sparse_attn_fwd", "sparse_attn_bwd")
+    assert sparse_attention.BAND_KERNELS == ("sparse_attn_fwd_band",
+                                             "sparse_attn_bwd_band")
+    reader = _load_reader(sparse_attention.KERNELS[way] + "_roofline")
+    shown = {name: f"%{name}.{i} = (f32[1,2,262144,128]{{3,2,1,0}}) "
+             "custom-call("
+             for i, name in enumerate(sparse_attention.KERNELS
+                                      + sparse_attention.BAND_KERNELS)}
+    hits = lambda pattern: [k for k, text in shown.items()
+                            if re.search(pattern, text)]
+    assert hits(reader.KERNELS) == [sparse_attention.KERNELS[way],
+                                    sparse_attention.BAND_KERNELS[way]]
+    assert hits(reader.WRITER) == [sparse_attention.KERNELS[way]]

@@ -8,15 +8,27 @@ forward and all three gradients, against a masked softmax over [S, S]
 for a RANDOM table (entries in any order, padding anywhere, blocks after
 the token's own, rows without a key), for a table whose neighbouring
 rows share no block beyond what they must, and for the selected one.
+The band path (the rule that made the table handed to the op: the forced
+blocks once a query block, the free ones a token) against the any-table
+path and the same masked softmax, on selected tables.
 
 Tolerances: kernel and masked softmax both run in float32 here, so only
 the order of sums differs: 1e-5 of each array's largest entry."""
+import importlib.util
+import json
+import os
+import re
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.ops.sparse_attention import (BLOCK, block_sparse_attention,
+from paddle_tpu.ops import sparse_attention
+from paddle_tpu.ops.sparse_attention import (BAND_KERNELS, BLOCK, KERNELS,
+                                             band_blocks, band_engages,
+                                             block_sparse_attention,
                                              select_blocks)
 
 TOL = 1e-5
@@ -214,3 +226,185 @@ def test_neighbouring_rows_of_the_disjoint_table_share_only_their_own():
         if t % BLOCK:
             shared = set(tab[t]) & set(tab[t - 1]) - {-1}
             assert shared <= {t // BLOCK}
+
+
+# -- the band path -----------------------------------------------------------
+
+# (seed, init_blocks, keys a tile of the band pass holds): 8 blocks of keys
+# for a topk of 3 + init_blocks and a window of 2, so the blocks up to
+# init_blocks + 1 are all band and the later ones pick one free block of
+# those between; the window as one tile, or as two (a whole one, then the
+# one with the tokens' own block)
+BANDS = {f"seed{seed}-init{init}-tiles-of-{keys}": (seed, init, keys)
+         for seed, keys in ((0, 2048), (1, 64)) for init in (1, 2)}
+
+
+def _band_of(rule):
+    return dict(init_blocks=rule["init_blocks"],
+                window_blocks=rule["window_size"] // rule["block_size"])
+
+
+def _forced(rule):
+    """[S, blocks] bool: the blocks the rule forces on each token."""
+    own = (np.arange(S) // BLOCK)[:, None]
+    at = np.arange(S // BLOCK)[None, :]
+    band = _band_of(rule)
+    return (at <= own) & ((at < band["init_blocks"])
+                          | (at > own - band["window_blocks"]))
+
+
+def _kernels(fn, *args):
+    """The names of the kernels the gradient of ``fn`` calls."""
+    program = jax.make_jaxpr(jax.grad(
+        lambda q, k, v, table: jnp.sum(fn(q, k, v, table)), (0, 1, 2)))(*args)
+    return sorted(set(re.findall(r"sparse_attn_\w+", str(program))))
+
+
+@pytest.fixture(scope="module", params=BANDS.values(), ids=BANDS.keys())
+def band_case(request):
+    seed, init, keys = request.param
+    launchers = (sparse_attention._sparse_fwd, sparse_attention._sparse_bwd)
+    was, sparse_attention._BAND_KEYS = sparse_attention._BAND_KEYS, (keys,
+                                                                     keys)
+    for fn in launchers:             # the constant is no part of their key
+        fn.clear_cache()
+    yield _band_case(seed, init)
+    sparse_attention._BAND_KEYS = was
+    for fn in launchers:
+        fn.clear_cache()
+
+
+def _band_case(seed, init):
+    rule = dict(RULE, init_blocks=init, topk=3 + init)
+    ks = jax.random.split(jax.random.key(seed), 4)
+    q = jax.random.normal(ks[0], (B, S, H, D))
+    k, v = (jax.random.normal(key, (B, S, G, D)) for key in ks[1:3])
+    weight = jax.random.normal(ks[3], (B, S, H, D))
+    table = select_blocks(q, k, **rule)
+    band = lambda q, k, v, table: block_sparse_attention(
+        q, k, v, table, **_band_of(rule))
+    both = lambda fn: jax.value_and_grad(
+        lambda *a: jnp.sum(fn(*a, table) * weight), argnums=(0, 1, 2))
+    return {"args": (q, k, v), "rule": rule, "table": table, "band": band,
+            "got": both(band)(q, k, v)[1],
+            "plain": both(masked_softmax)(q, k, v)[1],
+            "any": both(block_sparse_attention)(q, k, v)[1]}
+
+
+def test_the_band_engages_on_the_rules_tables(band_case):
+    rule, table = band_case["rule"], band_case["table"]
+    assert band_engages(S, table.shape[-1], **_band_of(rule))
+    assert _kernels(band_case["band"], *band_case["args"], table) \
+        == sorted(KERNELS + BAND_KERNELS)
+    assert _kernels(block_sparse_attention, *band_case["args"], table) \
+        == sorted(KERNELS)
+
+
+def test_select_blocks_meets_the_bands_contract(band_case):
+    """Every row holds each of its valid forced blocks and at most
+    ``K - init_blocks - window_blocks`` others, for every token."""
+    rule, table = band_case["rule"], np.asarray(band_case["table"])
+    forced = _forced(rule)                                  # [S, blocks]
+    named = np.zeros(table.shape[:3] + (S // BLOCK + 1,), bool)
+    np.put_along_axis(named, np.where(table >= 0, table, S // BLOCK), True, -1)
+    named = named[..., :-1]
+    assert (named | ~forced).all()
+    band = _band_of(rule)
+    free = table.shape[-1] - band["init_blocks"] - band["window_blocks"]
+    assert free >= 1
+    assert ((named & ~forced).sum(-1) <= free).all()
+    assert (named & ~forced).any()                  # and some are chosen
+
+
+def test_the_band_forward_is_the_any_table_paths_and_the_softmaxs(band_case):
+    q, k, v = band_case["args"]
+    table = band_case["table"]
+    got = band_case["band"](q, k, v, table)
+    assert _worst(got, masked_softmax(q, k, v, table)) <= TOL
+    assert _worst(got, block_sparse_attention(q, k, v, table)) <= TOL
+
+
+@pytest.mark.parametrize("leaf", range(3), ids=NAMES)
+def test_every_band_gradient_is_the_any_table_paths_and_the_softmaxs(
+        band_case, leaf):
+    got = band_case["got"][leaf]
+    for other in ("plain", "any"):
+        want = band_case[other][leaf]
+        assert got.shape == want.shape and got.dtype == want.dtype
+        assert _worst(got, want) <= TOL, (NAMES[leaf], other)
+
+
+def test_a_band_path_that_drops_its_free_blocks_fails_the_same_tolerance(
+        band_case):
+    """The benchmark's ``local`` fault at kernel level: every row cut to
+    its forced blocks."""
+    q, k, v = band_case["args"]
+    table = np.asarray(band_case["table"])
+    forced = np.take_along_axis(
+        np.broadcast_to(_forced(band_case["rule"]),
+                        table.shape[:3] + (S // BLOCK,)),
+        np.maximum(table, 0), -1)
+    local = jnp.asarray(np.where(forced, table, -1))
+    got = band_case["band"](q, k, v, local)
+    assert _worst(got, masked_softmax(q, k, v, local)) <= TOL
+    assert _worst(got, masked_softmax(q, k, v, band_case["table"])) \
+        > 1000 * TOL
+
+
+# (table columns, sequence, init_blocks, window_blocks): no free column;
+# a sequence no longer than the band; a rule half given
+FALLBACKS = {"no-free-column": (3, S, 1, 2), "short-sequence": (4, 192, 1, 2),
+             "half-a-rule": (4, S, None, 2)}
+
+
+@pytest.mark.parametrize("shape", FALLBACKS.values(), ids=FALLBACKS.keys())
+def test_where_the_band_cannot_engage_the_any_table_path_runs(arrays, shape):
+    width, s, init, window = shape
+    assert not band_engages(s, width, init, window)
+    assert band_blocks(s, width, init, window) == 0
+    q, k, v = (a[:, :s] for a in arrays[:3])
+    table = jnp.broadcast_to(jnp.arange(width, dtype=jnp.int32),
+                             (B, G, s, width))
+    given = lambda q, k, v, table: block_sparse_attention(
+        q, k, v, table, init_blocks=init, window_blocks=window)
+    assert _kernels(given, q, k, v, table) == sorted(KERNELS)
+    assert (given(q, k, v, table)
+            == block_sparse_attention(q, k, v, table)).all()
+
+
+def test_band_blocks_is_the_rules_count():
+    """A token of block b: the window's min(b + 1, 2) blocks and the
+    first block where the window no longer holds it."""
+    assert band_blocks(S, 4, 1, 2) == BLOCK * (1 + 2 + 6 * 3)
+    assert band_blocks(S, 5, 2, 2) == BLOCK * (1 + 2 + 3 + 5 * 4)
+    assert band_blocks(S, 4, 1, 2) == _forced(RULE).sum()
+    # the cell's rule at its length: 30.94 of a query's 56.125 blocks
+    cell = band_blocks(16384, 64, 1, 32)
+    assert cell == 506_880 and round(cell / 16384, 2) == 30.94
+    assert band_blocks(16384, 64, None, None) == 0
+
+
+def test_the_bench_script_rehearses_off_the_chip(monkeypatch, tmp_path):
+    """``benchmarks/sparse_bench.py``: without a chip it times nothing —
+    it refuses, or with ``--rehearse`` walks both ways of the layer at a
+    toy size and writes their agreement with the float32 masked softmax."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "sparse_bench.py")
+    spec = importlib.util.spec_from_file_location("sparse_bench", path)
+    bench = importlib.util.module_from_spec(spec)
+    monkeypatch.setattr(sys, "path", list(sys.path))
+    spec.loader.exec_module(bench)
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setattr(sys, "argv", ["sparse_bench.py"])
+    with pytest.raises(SystemExit, match="no chip here"):
+        bench.main()
+    monkeypatch.setattr(sys, "argv", ["sparse_bench.py", "--rehearse"])
+    bench.main()
+    with open(tmp_path / "chiprun_out" / "sparse_bench.json") as fh:
+        (row,) = json.load(fh)
+    assert row["kernels"] == "repository" and "ms" not in row
+    ways = row["agreement_bf16_toy"]
+    assert sorted(ways) == ["band", "columns_64"]
+    for gaps in ways.values():       # bfloat16 against float32: ~2^-8
+        assert sorted(gaps) == ["k", "o", "q", "v"]
+        assert all(0 < gap < 0.02 for gap in gaps.values())
