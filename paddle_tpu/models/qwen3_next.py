@@ -20,6 +20,8 @@ Gated DeltaNet (``GatedDeltaNet``; a = the normed stream)::
     beta = sigmoid(b);   g = -exp(A_log) softplus(alpha + dt_bias)
     o = gated_delta_rule(q, k, v, g, beta)           ops/gated_delta_rule.py
     out = (RMSNorm_dv(o) w silu(z)) W_o              w [d_v], starts at 1
+            the gate is ONE op, ops/gated_norm.py::gated_rms_norm, float32
+            inside its kernels (``gdn_gate`` is its plain form)
 
 Full attention (``Qwen3NextAttention``)::
 
@@ -175,7 +177,8 @@ def gdn_inputs(qkv, ba, w, a_log, dt_bias, *, hk: int, hv: int, dk: int,
 
 def gdn_gate(o, z, w, eps: float):
     """``RMSNorm(o) w silu(z)`` per head [.., dv] (float32), heads
-    merged."""
+    merged: the plain form of ``ops/gated_norm.py``, which the layer
+    runs; the tests hold the kernels to it."""
     f = o.astype(jnp.float32)
     f = f * jax.lax.rsqrt(jnp.mean(jnp.square(f), -1, keepdims=True) + eps)
     f = f * w.astype(jnp.float32) * jax.nn.silu(
@@ -215,6 +218,7 @@ class GatedDeltaNet(nn.Layer):
 
     def forward(self, a):
         from ..ops.gated_delta_rule import gated_delta_rule
+        from ..ops.gated_norm import gated_rms_norm
 
         with jax.named_scope("gdn.project"):
             qkvz, ba = self.in_proj_qkvz(a), self.in_proj_ba(a)
@@ -229,9 +233,11 @@ class GatedDeltaNet(nn.Layer):
         with jax.named_scope("gdn.scan"):
             o = apply(gated_delta_rule, q, k, v, g, beta,
                       op_name="gated_delta_rule")
+        # two kernels whose custom VJP keeps o, z and the gain alone
         with jax.named_scope("gdn.gate"):
             out = apply(
-                jax.checkpoint(functools.partial(gdn_gate, eps=self.eps)),
+                lambda o, z, w: gated_rms_norm(
+                    o.reshape(z.shape), z, w, self.eps),
                 o, qkvz[:, :, self.conv_dim:], self.norm_weight,
                 op_name="gdn_gate")
             return self.out_proj(out)

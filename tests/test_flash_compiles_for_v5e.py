@@ -24,6 +24,8 @@ from chipbench.layer_metrics import (flash_bwd_roofline, flash_fwd_roofline,
                                      moe_gmm_roofline)
 from paddle_tpu.ops.flash_attention import flash_attention, kernel_names
 from paddle_tpu.ops.gated_delta_rule import gated_delta_rule
+from paddle_tpu.ops.gated_norm import KERNELS as GATE_KERNELS
+from paddle_tpu.ops.gated_norm import gated_rms_norm
 from paddle_tpu.ops.gdn_inputs import KERNELS as INPUTS_KERNELS
 from paddle_tpu.ops.gdn_inputs import conv_silu_l2norm
 from paddle_tpu.ops.grouped_matmul import grouped_matmul
@@ -243,6 +245,28 @@ def test_grad_of_the_recurrences_inputs_compiles_for_a_v5e(one_chip, shape):
              if 'custom_call_target="tpu_custom_call"' in line]
     assert sorted(re.match(r"%(\w+?)\.\d+ = ", c).group(1) for c in calls) \
         == sorted(INPUTS_KERNELS)
+
+
+@pytest.mark.parametrize("shape", RECURRENCES.values(),
+                         ids=RECURRENCES.keys())
+def test_grad_of_the_recurrences_gate_compiles_for_a_v5e(one_chip, shape):
+    """``ops/gated_norm.py`` at the same shapes: the norm over each value
+    head, its gain and the SiLU gate after the recurrence."""
+    s, _, hv, dtype = shape
+
+    def run(o, z, w, dy):
+        out, back = jax.vjp(
+            lambda *a: gated_rms_norm(*a, 1e-6, False), o, z, w)
+        return out, back(dy)
+
+    o = jax.ShapeDtypeStruct((1, s, hv * 128), dtype, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((128,), dtype, sharding=one_chip)
+    compiled = jax.jit(run).lower(o, o, w, o).compile()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(re.match(r"%(\w+?)\.\d+ = ", c).group(1) for c in calls) \
+        == sorted(GATE_KERNELS)
 
 
 # (sequence, heads, dtype): lightning attention

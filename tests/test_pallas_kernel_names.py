@@ -13,9 +13,9 @@ import chip_smoke
 from chipbench.layer_metrics import (flash_bwd_roofline, flash_fwd_roofline,
                                      moe_gmm_roofline)
 from paddle_tpu.ops import flash_attention as flash
-from paddle_tpu.ops import (conv_silu, gated_delta_rule, gdn_inputs,
-                            grouped_matmul, lightning_attention, mamba2_ssd,
-                            sparse_attention)
+from paddle_tpu.ops import (conv_silu, gated_delta_rule, gated_norm,
+                            gdn_inputs, grouped_matmul, lightning_attention,
+                            mamba2_ssd, sparse_attention)
 
 
 def _lowered_for_tpu(fn, *args):
@@ -72,6 +72,17 @@ def _gdn_inputs_program():
     w = jax.ShapeDtypeStruct((4, 1024), jnp.bfloat16)
     # the backward needs no result of the forward: the value keeps it
     return _lowered_for_tpu(jax.value_and_grad(loss, (0, 1)), qkv, w)
+
+
+def _gated_norm_program():
+    def run(o, z, w, dy):
+        out, back = jax.vjp(
+            lambda *a: gated_norm.gated_rms_norm(*a, 1e-6, False), o, z, w)
+        return out, back(dy)
+
+    o = jax.ShapeDtypeStruct((1, 256, 512), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((128,), jnp.bfloat16)
+    return _lowered_for_tpu(run, o, o, w, o)
 
 
 def _lightning_program():
@@ -138,6 +149,7 @@ def _conv_silu_program():
     (_window_program, flash.kernel_names(100)),
     (_gated_delta_rule_program, gated_delta_rule.KERNELS),
     (_gdn_inputs_program, gdn_inputs.KERNELS),
+    (_gated_norm_program, gated_norm.KERNELS),
     (_lightning_program, lightning_attention.KERNELS),
     (_sparse_attention_program, sparse_attention.KERNELS),
     (_sparse_band_program,
@@ -145,7 +157,7 @@ def _conv_silu_program():
     (_ssd_program, mamba2_ssd.KERNELS),
     (_conv_silu_program, conv_silu.KERNELS),
 ], ids=["flash", "grouped_matmul", "flash_window", "gated_delta_rule",
-        "gdn_inputs", "lightning_attention", "sparse_attention",
+        "gdn_inputs", "gated_norm", "lightning_attention", "sparse_attention",
         "sparse_attention_band", "mamba2_ssd", "conv_silu"])
 def test_kernel_names_are_the_ones_chip_smoke_requires(program, wanted):
     have = chip_smoke.kernels_in(program())
@@ -273,6 +285,7 @@ def test_minicpm_salas_readers_patterns_match_the_kernel_names(module, stem):
         assert not re.search(reader.WRITER, helper)
     other = (chip_smoke.FLASH_KERNELS + chip_smoke.MOE_KERNELS
              + gated_delta_rule.KERNELS + gdn_inputs.KERNELS
+             + gated_norm.KERNELS
              + tuple(k for m in (lightning_attention, sparse_attention,
                                  mamba2_ssd, conv_silu) if m is not module
                      for k in m.KERNELS))

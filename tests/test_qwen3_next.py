@@ -134,6 +134,38 @@ def test_loss_and_every_leafs_gradient_agree(cfg, program, wanted, ids,
     assert np.asarray(model.tokens_per_expert()).sum() > 0
 
 
+def test_the_gates_kernels_are_the_plain_form_inside_the_model(
+        cfg, ids, monkeypatch):
+    """The layer has ONE path, ``ops/gated_norm.py``'s kernels; with the
+    op swapped for the plain formula (``gdn_gate``, float32 under
+    autodiff) the same model gives the same loss and the same gradient in
+    every leaf."""
+    model, params = _program(cfg)
+
+    def run():
+        loss = model.loss(paddle.to_tensor(ids[0]), paddle.to_tensor(ids[1]))
+        loss.backward()
+        grads = [np.asarray(p.grad._data) for p in params]
+        model.clear_gradients()
+        return float(loss), grads
+
+    calls = []
+
+    def plain(o, z, w, eps):
+        calls.append(o.shape)
+        heads = o.shape[-1] // w.shape[0]
+        return qmodel.gdn_gate(o.reshape(*o.shape[:2], heads, w.shape[0]),
+                               z, w, eps)
+
+    loss, got = run()
+    monkeypatch.setattr("paddle_tpu.ops.gated_norm.gated_rms_norm", plain)
+    plain_loss, want = run()
+    assert len(calls) == 3 and not np.isnan(loss)    # one a linear layer
+    assert abs(loss - plain_loss) <= 1e-5 * plain_loss
+    for leaf, a, b in zip(qwen3next.leaves(cfg), got, want):
+        assert np.abs(a - b).max() <= 1e-4 * np.abs(b).max(), leaf[:2]
+
+
 def test_two_adamw_steps_through_the_compiled_step(cfg, ids):
     """``jit.to_static`` over model and AdamW (the family's trainer, whole
     blocks recomputed as the cell runs them) in float32: both steps'
