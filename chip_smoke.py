@@ -237,7 +237,7 @@ def phase_env(cache_dir: str) -> None:
     import jaxlib
 
     import paddle_tpu as paddle
-    from paddle_tpu.ops import flash_attention as fa
+    from paddle_tpu.ops.pallas_common import interpret_default
 
     dev = jax.devices()[0]
     try:
@@ -248,7 +248,7 @@ def phase_env(cache_dir: str) -> None:
         f"jax={jax.__version__} jaxlib={jaxlib.__version__} libtpu={libtpu}")
     say(f"compile_cache_dir={cache_dir}")
     say(f"paddle.get_device()={paddle.get_device()}")
-    interpret = fa._interpret_default()
+    interpret = interpret_default()
     say(f"interpret flash={interpret}")
     if PLATFORM == "tpu":
         from paddle_tpu.device.peaks import chip_peaks

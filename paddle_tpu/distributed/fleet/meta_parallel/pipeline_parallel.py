@@ -46,13 +46,6 @@ with compute, so ZB is a strictly worse trade on this runtime. (The
 reference needs ZB because its MPMD ranks idle on NCCL waits that
 nothing else can fill.)
 
-MEASURED (benchmarks/pipeline_bubble_sweep.py, 8 virtual CPU devices,
-S=4): the empirical bubble tracks the schedule model and is ≤5% at
-M·V ≥ 32
-(e.g. V=1 M=32: 0.6%; V=2 M=16: ≤1%) — an order of magnitude below
-the ~33% recompute tax ZB-H1 would charge, at every realistic
-microbatch count.
-
 Numerics are microbatch-exact w.r.t. serial execution; the bubble
 fraction is the classic (S-1)/(M+S-1). ``recompute_interval`` wraps the
 stage body in jax.checkpoint (activation recompute, ref

@@ -42,11 +42,11 @@ from dataclasses import dataclass
 from typing import Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 
 from .. import nn
 from ..base.tape import apply
 from ..nn import functional as F
+from .decoder import DecoderStack, RoutedCausalLM, SwiGLU, held_share, rope
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -92,10 +92,7 @@ class AfmoeConfig:
                                 for i in range(self.num_hidden_layers)]
         if len(self.layer_types) != self.num_hidden_layers:
             raise ValueError("layer_types names every published layer")
-        if self.held_layers is None:
-            self.held_layers = self.num_hidden_layers - self.first_layer
-        if self.held_experts is None:
-            self.held_experts = self.num_experts
+        held_share(self)
         if self.recompute not in ("none", "mlp"):
             raise ValueError(f"recompute={self.recompute!r}")
 
@@ -108,19 +105,6 @@ class AfmoeConfig:
                     num_experts=8, num_experts_per_tok=2)
         base.update(kw)
         return AfmoeConfig(**base)
-
-
-def rope(x, theta: float):
-    """Half-split rotation of every dim of x [B, S, h, d] by its
-    position's angle, in float32."""
-    s, d = x.shape[1], x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-    x1, x2 = x[..., :d // 2].astype(jnp.float32), x[..., d // 2:].astype(
-        jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
 
 
 class AfmoeAttention(nn.Layer):
@@ -166,19 +150,6 @@ class AfmoeAttention(nn.Layer):
             return self.o_proj(out * F.sigmoid(self.gate_proj(a)))
 
 
-class AfmoeMLP(nn.Layer):
-    """SwiGLU: ``(silu(m W1) * (m W3)) W2``, no bias."""
-
-    def __init__(self, hidden_size: int, width: int):
-        super().__init__()
-        self.gate_proj = nn.Linear(hidden_size, width, bias_attr=False)
-        self.up_proj = nn.Linear(hidden_size, width, bias_attr=False)
-        self.down_proj = nn.Linear(width, hidden_size, bias_attr=False)
-
-    def forward(self, m):
-        return self.down_proj(F.silu(self.gate_proj(m)) * self.up_proj(m))
-
-
 class AfmoeMoE(nn.Layer):
     """The shared expert (ungated) beside the routed ones."""
 
@@ -188,7 +159,7 @@ class AfmoeMoE(nn.Layer):
         self.router = nn.SigmoidTopKRouter(
             c.hidden_size, c.num_experts, c.num_experts_per_tok,
             c.route_scale, c.route_norm)
-        self.shared_experts = AfmoeMLP(
+        self.shared_experts = SwiGLU(
             c.hidden_size, c.moe_intermediate_size * c.num_shared_experts)
         self.experts = nn.RoutedExperts(
             c.hidden_size, c.moe_intermediate_size, c.num_experts,
@@ -249,80 +220,24 @@ class AfmoeDecoderLayer(nn.Layer):
         return out[0]
 
 
-class AfmoeModel(nn.Layer):
+class AfmoeModel(DecoderStack):
     def __init__(self, config: AfmoeConfig):
-        super().__init__()
-        c = self.config = config
-        self.embed_tokens = nn.Embedding(c.vocab_size, c.hidden_size)
-        self.layer_ids = list(range(c.first_layer,
-                                    c.first_layer + c.held_layers))
-        self.layers = nn.LayerList([
-            AfmoeDecoderLayer(
+        c = config
+
+        def block(i):
+            return AfmoeDecoderLayer(
                 c,
                 AfmoeAttention(c, c.sliding_window
                                if c.layer_types[i] == SLIDING else None),
-                AfmoeMLP(c.hidden_size, c.intermediate_size)
+                SwiGLU(c.hidden_size, c.intermediate_size)
                 if i < c.num_dense_layers else AfmoeMoE(c))
-            for i in self.layer_ids])
-        self.norm = nn.RMSNorm(c.hidden_size, c.rms_norm_eps)
 
-    def forward(self, input_ids, routing=None):
-        x = self.embed_tokens(input_ids)
-        if self.config.mup_enabled:
-            x = x * math.sqrt(self.config.hidden_size)
-        for layer in self.layers:
-            x = layer(x, routing)
-        return self.norm(x)
+        super().__init__(c, block, multiplier=(
+            math.sqrt(c.hidden_size) if c.mup_enabled else None))
 
 
-class AfmoeForCausalLM(nn.Layer):
-    """The decoder with its untied head. Training forward only: there is
-    no ``init_cache`` / ``forward_with_cache``, so no engine serves it."""
+class AfmoeForCausalLM(RoutedCausalLM):
+    """The decoder with its untied head."""
 
     def __init__(self, config: AfmoeConfig):
-        super().__init__()
-        self.config = config
-        self.model = AfmoeModel(config)
-        self.lm_head = nn.Linear(config.hidden_size, config.vocab_size,
-                                 bias_attr=False)
-
-    def forward(self, input_ids, routing=None):
-        """``routing``: a list that is given every routed block's choice,
-        expert ids [B, S, k], in order (a train step may return them)."""
-        return self.lm_head(self.model(input_ids, routing))
-
-    def loss(self, input_ids, labels):
-        from ..tensor import manipulation as M
-
-        logits = self(input_ids)
-        b, s, v = logits.shape
-        return F.cross_entropy(M.reshape(logits, [b * s, v]),
-                               M.reshape(labels, [b * s]))
-
-    def routed_layers(self):
-        return [layer for layer in self.model.layers if layer.routed]
-
-    def tokens_per_expert(self):
-        """[routed blocks, held experts] int32 on the device: rows each
-        held expert has been given since the model was built."""
-        return jnp.stack([layer.mlp.experts.tokens_per_expert._data
-                          for layer in self.routed_layers()])
-
-    def pairs_routed(self):
-        """[routed blocks] int32: every (token, choice) pair a block saw,
-        whichever expert it went to (where every expert is held, the
-        rows they got add up to them)."""
-        experts = [layer.mlp.experts for layer in self.routed_layers()]
-        return jnp.stack([e.pairs_routed._data if "pairs_routed" in e._buffers
-                          else jnp.sum(e.tokens_per_expert._data)
-                          for e in experts])
-
-    def calls_in_full(self):
-        """[routed blocks] int32: the calls in which a block that holds a
-        share was sent more pairs than its bound on the rows it computes
-        at a time (``nn.RoutedExperts``); where every expert is held
-        there is no bound to pass: zeros."""
-        experts = [layer.mlp.experts for layer in self.routed_layers()]
-        return jnp.stack([e.calls_in_full._data
-                          if "calls_in_full" in e._buffers
-                          else jnp.zeros([], jnp.int32) for e in experts])
+        super().__init__(config, AfmoeModel(config))

@@ -60,9 +60,8 @@ import jax.numpy as jnp
 from .. import nn
 from ..base.tape import apply
 from ..base.tensor import Tensor
-from ..nn import functional as F
 from ..nn import initializer as I
-from .afmoe import AfmoeMLP
+from .decoder import CausalLM, DecoderStack, SwiGLU, held_share
 
 MAMBA, ATTENTION = "mamba", "attention"
 
@@ -110,10 +109,7 @@ class GraniteHybridConfig:
                 != self.mamba_n_heads * self.mamba_d_head):
             raise ValueError("mamba_expand x hidden_size is not "
                              "mamba_n_heads x mamba_d_head")
-        if self.held_layers is None:
-            self.held_layers = self.num_hidden_layers - self.first_layer
-        if self.vocab_rows is None:
-            self.vocab_rows = self.vocab_size
+        held_share(self)
 
     @property
     def head_dim(self) -> int:
@@ -262,7 +258,7 @@ class GraniteHybridDecoderLayer(nn.Layer):
             self.mamba = Mamba2Mixer(config)
         else:
             self.self_attn = NoPEAttention(config)
-        self.shared_mlp = AfmoeMLP(h, config.shared_intermediate_size)
+        self.shared_mlp = SwiGLU(h, config.shared_intermediate_size)
         self.input_layernorm = nn.RMSNorm(h, eps)
         self.post_attention_layernorm = nn.RMSNorm(h, eps)
 
@@ -282,48 +278,26 @@ class GraniteHybridDecoderLayer(nn.Layer):
         return recompute(mlp_half, x + scale * mixed)
 
 
-class GraniteHybridModel(nn.Layer):
+class GraniteHybridModel(DecoderStack):
     def __init__(self, config: GraniteHybridConfig):
-        super().__init__()
-        c = self.config = config
-        self.embed_tokens = nn.Embedding(c.vocab_rows, c.hidden_size)
-        self.layer_ids = list(range(c.first_layer,
-                                    c.first_layer + c.held_layers))
-        self.layers = nn.LayerList([
-            GraniteHybridDecoderLayer(c, c.layer_types[i])
-            for i in self.layer_ids])
-        self.norm = nn.RMSNorm(c.hidden_size, c.rms_norm_eps)
-
-    def forward(self, input_ids):
-        x = self.embed_tokens(input_ids) * self.config.embedding_multiplier
-        for layer in self.layers:
-            x = layer(x)
-        return self.norm(x)
+        super().__init__(
+            config,
+            lambda i: GraniteHybridDecoderLayer(config, config.layer_types[i]),
+            multiplier=config.embedding_multiplier, routing=False)
 
 
-class GraniteHybridForCausalLM(nn.Layer):
+class GraniteHybridForCausalLM(CausalLM):
     """The decoder with its head TIED to ``embed_tokens`` (one leaf: its
     gradient is the sum of the embedding's and the head's) over the held
-    rows of the vocabulary. Training forward only: there is no
-    ``init_cache`` / ``forward_with_cache``, so no engine serves it."""
+    rows of the vocabulary."""
 
     def __init__(self, config: GraniteHybridConfig):
-        super().__init__()
-        self.config = config
-        self.model = GraniteHybridModel(config)
+        super().__init__(config, GraniteHybridModel(config), tied=True)
 
     def forward(self, input_ids):
         divide = self.config.logits_scaling
         return apply(lambda a, w: (a @ w.T) / divide, self.model(input_ids),
                      self.model.embed_tokens.weight, op_name="tied_lm_head")
-
-    def loss(self, input_ids, labels):
-        from ..tensor import manipulation as M
-
-        logits = self(input_ids)
-        b, s, v = logits.shape
-        return F.cross_entropy(M.reshape(logits, [b * s, v]),
-                               M.reshape(labels, [b * s]))
 
     def mamba_layers(self):
         return [layer.mamba for layer in self.model.layers
@@ -332,4 +306,4 @@ class GraniteHybridForCausalLM(nn.Layer):
     def chunk_carry(self):
         """[mamba layers] float32 on the device: the last forward's
         ``Mamba2Mixer.chunk_carry`` of each."""
-        return jnp.stack([m.chunk_carry._data for m in self.mamba_layers()])
+        return self.stacked(self.mamba_layers(), "chunk_carry")

@@ -60,7 +60,7 @@ from ..base.tape import apply
 from ..base.tensor import Tensor
 from ..nn import functional as F
 from ..nn import initializer as I
-from .afmoe import AfmoeMLP, rope
+from .decoder import CausalLM, DecoderStack, SwiGLU, held_share, rope
 
 SPARSE, LIGHTNING = "minicpm4", "lightning-attn"
 _PUBLISHED_MIXERS = tuple(
@@ -109,10 +109,7 @@ class MiniCPMSALAConfig:
         if self.lightning_nkv != self.lightning_nh:
             raise ValueError("lightning attention with grouped keys is not "
                              "built (published: 32 of each)")
-        if self.held_layers is None:
-            self.held_layers = self.num_hidden_layers - self.first_layer
-        if self.vocab_rows is None:
-            self.vocab_rows = self.vocab_size
+        held_share(self)
 
     @property
     def residual_scale(self) -> float:
@@ -286,7 +283,7 @@ class MiniCPMSALADecoderLayer(nn.Layer):
             self.self_attn = BlockSparseAttention(config)
         else:
             self.linear_attn = LightningAttention(config)
-        self.mlp = AfmoeMLP(h, config.intermediate_size)
+        self.mlp = SwiGLU(h, config.intermediate_size)
         self.input_layernorm = nn.RMSNorm(h, eps)
         self.post_attention_layernorm = nn.RMSNorm(h, eps)
 
@@ -301,36 +298,20 @@ class MiniCPMSALADecoderLayer(nn.Layer):
             return h + self.scale * self.mlp(self.post_attention_layernorm(h))
 
 
-class MiniCPMSALAModel(nn.Layer):
+class MiniCPMSALAModel(DecoderStack):
     def __init__(self, config: MiniCPMSALAConfig):
-        super().__init__()
-        c = self.config = config
-        self.embed_tokens = nn.Embedding(c.vocab_rows, c.hidden_size)
-        self.layer_ids = list(range(c.first_layer,
-                                    c.first_layer + c.held_layers))
-        self.layers = nn.LayerList([
-            MiniCPMSALADecoderLayer(c, c.mixer_types[i])
-            for i in self.layer_ids])
-        self.norm = nn.RMSNorm(c.hidden_size, c.rms_norm_eps)
-
-    def forward(self, input_ids, routing=None):
-        x = self.embed_tokens(input_ids) * self.config.scale_emb
-        for layer in self.layers:
-            x = layer(x, routing)
-        return self.norm(x)
+        super().__init__(
+            config,
+            lambda i: MiniCPMSALADecoderLayer(config, config.mixer_types[i]),
+            multiplier=config.scale_emb)
 
 
-class MiniCPMSALAForCausalLM(nn.Layer):
+class MiniCPMSALAForCausalLM(CausalLM):
     """The decoder with its untied head over the held rows of the
-    vocabulary. Training forward only: there is no ``init_cache`` /
-    ``forward_with_cache``, so no engine serves it."""
+    vocabulary."""
 
     def __init__(self, config: MiniCPMSALAConfig):
-        super().__init__()
-        self.config = config
-        self.model = MiniCPMSALAModel(config)
-        self.lm_head = nn.Linear(config.hidden_size, config.vocab_rows,
-                                 bias_attr=False)
+        super().__init__(config, MiniCPMSALAModel(config))
 
     def forward(self, input_ids, routing=None):
         """``routing``: a list that is given every sparse block's table,
@@ -340,14 +321,6 @@ class MiniCPMSALAForCausalLM(nn.Layer):
         return self.lm_head(self.model(input_ids, routing)
                             * (c.dim_model_base / c.hidden_size))
 
-    def loss(self, input_ids, labels):
-        from ..tensor import manipulation as M
-
-        logits = self(input_ids)
-        b, s, v = logits.shape
-        return F.cross_entropy(M.reshape(logits, [b * s, v]),
-                               M.reshape(labels, [b * s]))
-
     def sparse_layers(self):
         return [layer.self_attn for layer in self.model.layers
                 if layer.sparse]
@@ -355,15 +328,14 @@ class MiniCPMSALAForCausalLM(nn.Layer):
     def blocks_chosen(self):
         """[sparse blocks] int32 on the device: the blocks the last
         forward's tables named."""
-        return jnp.stack([a.blocks_chosen._data
-                          for a in self.sparse_layers()])
+        return self.stacked(self.sparse_layers(), "blocks_chosen")
 
     def query_rows(self):
         """[sparse blocks] int32: the (token, kv group) rows they named
         them for."""
-        return jnp.stack([a.query_rows._data for a in self.sparse_layers()])
+        return self.stacked(self.sparse_layers(), "query_rows")
 
     def band_blocks(self):
         """[sparse blocks] int32: the block reads of ``blocks_chosen``
         that the kernels' band pass served."""
-        return jnp.stack([a.band_blocks._data for a in self.sparse_layers()])
+        return self.stacked(self.sparse_layers(), "band_blocks")

@@ -66,8 +66,8 @@ from .. import nn
 from ..base.tape import apply
 from ..nn import functional as F
 from ..nn import initializer as I
-from .afmoe import AfmoeForCausalLM, AfmoeMLP
-from .zaya import _rope
+from .decoder import (DecoderStack, RoutedCausalLM, SwiGLU, held_share,
+                      partial_rope)
 
 LINEAR, FULL = "linear_attention", "full_attention"
 
@@ -112,10 +112,7 @@ class Qwen3NextConfig:
                                 for i in range(self.num_hidden_layers)]
         if len(self.layer_types) != self.num_hidden_layers:
             raise ValueError("layer_types names every published layer")
-        if self.held_layers is None:
-            self.held_layers = self.num_hidden_layers - self.first_layer
-        if self.held_experts is None:
-            self.held_experts = self.num_experts
+        held_share(self)
         if self.recompute not in ("none", "mlp", "layer"):
             raise ValueError(f"recompute={self.recompute!r}")
 
@@ -270,8 +267,8 @@ class Qwen3NextAttention(nn.Layer):
         width = self.nq * self.d
 
         def turned(t):
-            return apply(lambda t: _rope(t, self.theta, self.rot).astype(
-                t.dtype), t, op_name="rope")
+            return apply(lambda t: partial_rope(
+                t, self.theta, self.rot).astype(t.dtype), t, op_name="rope")
 
         with jax.named_scope("attn.project"):
             qg = self.q_proj(a)
@@ -297,8 +294,8 @@ class Qwen3NextMoE(nn.Layer):
         self.router = nn.SoftmaxTopKRouter(
             c.hidden_size, c.num_experts, c.num_experts_per_tok,
             c.norm_topk_prob)
-        self.shared_expert = AfmoeMLP(c.hidden_size,
-                                      c.shared_expert_intermediate_size)
+        self.shared_expert = SwiGLU(c.hidden_size,
+                                    c.shared_expert_intermediate_size)
         self.shared_expert_gate = nn.Linear(c.hidden_size, 1, bias_attr=False)
         self.experts = nn.RoutedExperts(
             c.hidden_size, c.moe_intermediate_size, c.num_experts,
@@ -319,7 +316,7 @@ class Qwen3NextMoE(nn.Layer):
 class Qwen3NextDecoderLayer(nn.Layer):
     """One block around the mixer its ``layer_type`` names."""
 
-    routed = True       # every MLP of the family is (AfmoeForCausalLM asks)
+    routed = True       # every MLP of the family is (RoutedCausalLM asks)
 
     def __init__(self, config: Qwen3NextConfig, layer_type: str):
         super().__init__()
@@ -364,34 +361,16 @@ class Qwen3NextDecoderLayer(nn.Layer):
         return y
 
 
-class Qwen3NextModel(nn.Layer):
+class Qwen3NextModel(DecoderStack):
     def __init__(self, config: Qwen3NextConfig):
-        super().__init__()
-        c = self.config = config
-        self.embed_tokens = nn.Embedding(c.vocab_size, c.hidden_size)
-        self.layer_ids = list(range(c.first_layer,
-                                    c.first_layer + c.held_layers))
-        self.layers = nn.LayerList([
-            Qwen3NextDecoderLayer(c, c.layer_types[i])
-            for i in self.layer_ids])
-        self.norm = Qwen3NextRMSNorm(c.hidden_size, c.rms_norm_eps)
-
-    def forward(self, input_ids, routing=None):
-        x = self.embed_tokens(input_ids)
-        for layer in self.layers:
-            x = layer(x, routing)
-        return self.norm(x)
+        super().__init__(
+            config,
+            lambda i: Qwen3NextDecoderLayer(config, config.layer_types[i]),
+            norm=Qwen3NextRMSNorm)
 
 
-class Qwen3NextForCausalLM(AfmoeForCausalLM):
-    """The decoder with its untied head. ``forward``, ``loss`` and the
-    routed blocks' counters (``tokens_per_expert``, ``pairs_routed``,
-    ``calls_in_full``) are ``AfmoeForCausalLM``'s: they ask a block only
-    for ``routed`` and ``mlp.experts``. Training forward only."""
+class Qwen3NextForCausalLM(RoutedCausalLM):
+    """The decoder with its untied head."""
 
     def __init__(self, config: Qwen3NextConfig):
-        nn.Layer.__init__(self)
-        self.config = config
-        self.model = Qwen3NextModel(config)
-        self.lm_head = nn.Linear(config.hidden_size, config.vocab_size,
-                                 bias_attr=False)
+        super().__init__(config, Qwen3NextModel(config))

@@ -39,8 +39,8 @@ import jax.numpy as jnp
 
 from .. import nn
 from ..base.tape import apply
-from ..nn import functional as F
 from ..nn import initializer as I
+from .decoder import CausalLM, DecoderStack, partial_rope
 
 
 @dataclass
@@ -100,17 +100,6 @@ def cca_conv(c, w0, b0, w1, b1):
     return out.reshape(b, s, heads * d) + b1.astype(f32)
 
 
-def _rope(x, theta: float, rot: int):
-    """Half-split rotation of the first ``rot`` dims of x [B, S, h, d]."""
-    s = x.shape[1]
-    inv = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-    x1, x2, rest = x[..., :rot // 2], x[..., rot // 2:rot], x[..., rot:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
-                           axis=-1)
-
-
 def cca_qk(qt, kt, conv, tau, *, nq: int, nkv: int, d: int, theta: float,
            rot: int):
     """The q-k mean, the per-head L2 norm with the keys' temperature, and
@@ -128,8 +117,8 @@ def cca_qk(qt, kt, conv, tau, *, nq: int, nkv: int, d: int, theta: float,
     q = q * (math.sqrt(d) * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True)))
     k = k * (math.sqrt(d) * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True))
              * tau.astype(f32)[:, None])
-    return (_rope(q, theta, rot).astype(qt.dtype),
-            _rope(k, theta, rot).astype(qt.dtype))
+    return (partial_rope(q, theta, rot).astype(qt.dtype),
+            partial_rope(k, theta, rot).astype(qt.dtype))
 
 
 class ZayaAttention(nn.Layer):
@@ -205,7 +194,7 @@ class ZayaDecoderLayer(nn.Layer):
 
     def forward(self, x, routing=None):
         """``routing``: a list that is given this block's choice, expert
-        ids [B, S, 1] (``ZayaForCausalLM.routing``)."""
+        ids [B, S, 1]."""
         x = x + self.self_attn(self.input_layernorm(x))
         w = self.post_attention_layernorm(x)
         with jax.named_scope("moe.router"):
@@ -215,33 +204,18 @@ class ZayaDecoderLayer(nn.Layer):
         return x + self.experts(w, ids, gates)
 
 
-class ZayaModel(nn.Layer):
+class ZayaModel(DecoderStack):
     def __init__(self, config: ZayaConfig):
-        super().__init__()
-        self.config = config
-        self.embed_tokens = nn.Embedding(config.vocab_size,
-                                         config.hidden_size)
-        self.layers = nn.LayerList([ZayaDecoderLayer(config)
-                                    for _ in range(config.num_hidden_layers)])
-        self.norm = nn.RMSNorm(config.hidden_size, config.rms_norm_eps)
-
-    def forward(self, input_ids, routing=None):
-        x = self.embed_tokens(input_ids)
-        for layer in self.layers:
-            x = layer(x, routing)
-        return self.norm(x)
+        super().__init__(config, lambda i: ZayaDecoderLayer(config))
 
 
-class ZayaForCausalLM(nn.Layer):
-    """The decoder with its tied head. Training forward only: there is no
-    ``init_cache`` / ``forward_with_cache``, so no engine serves it yet."""
+class ZayaForCausalLM(CausalLM):
+    """The decoder with its tied head."""
 
     def __init__(self, config: ZayaConfig):
-        super().__init__()
         if not config.tie_word_embeddings:
             raise NotImplementedError("ZAYA1 ties its head to embed_tokens")
-        self.config = config
-        self.model = ZayaModel(config)
+        super().__init__(config, ZayaModel(config), tied=True)
 
     def forward(self, input_ids, routing=None):
         """``routing``: a list that is given every block's choice, expert
@@ -252,23 +226,8 @@ class ZayaForCausalLM(nn.Layer):
         w = self.model.embed_tokens.weight
         return apply(lambda a, ww: a @ ww.T, h, w, op_name="tied_lm_head")
 
-    def loss(self, input_ids, labels):
-        from ..tensor import manipulation as M
-
-        logits = self(input_ids)
-        b, s, v = logits.shape
-        return F.cross_entropy(M.reshape(logits, [b * s, v]),
-                               M.reshape(labels, [b * s]))
-
-    def routing(self, input_ids):
-        """[blocks, B, S] int32: the expert every token of ``input_ids``
-        meets in every block, by the model as it stands."""
-        chosen = []
-        self.model(input_ids, chosen)
-        return jnp.stack([ids._data[..., 0] for ids in chosen])
-
     def tokens_per_expert(self):
         """[blocks, E] int32 on the device: rows each expert of each block
         has been given since the model was built."""
-        return jnp.stack([layer.experts.tokens_per_expert._data
-                          for layer in self.model.layers])
+        return self.stacked([layer.experts for layer in self.model.layers],
+                            "tokens_per_expert")

@@ -13,10 +13,11 @@ of ``y`` are in that type: nothing of [S, C] in float32 reaches HBM.
 The kernels are ``ops/gdn_inputs.py``'s without the split into q, k, v
 and without the l2 norms, and with the bias that file has no place for
 (its columns are whole heads of one of three outputs; here a column block
-is any ``_LANES`` lanes of one output): they share its walk (``_strips``:
-strips of 64 rows of 128 lanes, so that a strip's float32 stays in vector
-registers; the ``taps - 1`` rows before a block as a second view of the
-same array) and its constants. ``conv_silu_bwd`` keeps ``x``, ``w`` and
+is any ``_LANES`` lanes of one output): the two share their walk
+(``pallas_common.strips``: strips of 64 rows of 128 lanes, so that a
+strip's float32 stays in vector registers; the ``taps - 1`` rows before a
+block as a second view of the same array) and its constants.
+``conv_silu_bwd`` keeps ``x``, ``w`` and
 ``b`` alone from the forward pass and computes ``p`` again; it walks the
 sequence's blocks in reverse, carries each column block's first rows of
 ``dp`` in scratch, and adds ``d w`` and ``d b`` up in float32 in an
@@ -35,9 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _interpret_default
-from .gdn_inputs import (_COLUMNS, _HALO, _IN_ORDER, _PARALLEL, _ROWS, _STRIP,
-                         _TILE, _advanced, _strips)
+from .pallas_common import (COLUMNS, HALO, IN_ORDER, PARALLEL, ROWS, STRIP,
+                            TILE, advanced, interpret_default, strips)
 
 __all__ = ["conv_silu", "KERNELS"]
 
@@ -51,7 +51,7 @@ def _fwd_kernel(x_ref, before_ref, w_ref, b_ref, y_ref):
         y_ref[0, rows, lanes] = (p * jax.nn.sigmoid(p)).astype(y_ref.dtype)
         return kept
 
-    _strips(x_ref, before_ref, w_ref, pl.program_id(1) == 0, _LANES, strip)
+    strips(x_ref, before_ref, w_ref, pl.program_id(1) == 0, _LANES, strip)
 
 
 def _bwd_kernel(x_ref, before_ref, w_ref, b_ref, dy_ref, dx_ref, dwb_ref,
@@ -80,14 +80,14 @@ def _bwd_kernel(x_ref, before_ref, w_ref, b_ref, dy_ref, dx_ref, dwb_ref,
         out = []
         for tap, acc in enumerate(sums):
             part = dp * xs[taps - 1 - tap] if tap < taps else dp
-            for at in range(0, _STRIP, _TILE):
-                acc = acc + part[at:at + _TILE]
+            for at in range(0, STRIP, TILE):
+                acc = acc + part[at:at + TILE]
             out.append(acc)
         return out
 
-    zero = jnp.zeros((_TILE, _LANES), jnp.float32)
-    sums = _strips(x_ref, before_ref, w_ref, t == last, _LANES, strip,
-                   [zero] * (taps + 1))
+    zero = jnp.zeros((TILE, _LANES), jnp.float32)
+    sums = strips(x_ref, before_ref, w_ref, t == last, _LANES, strip,
+                  [zero] * (taps + 1))
     for h, group in enumerate(sums):
         for row, acc in enumerate(group):
             dwb_ref[j, row:row + 1, h * _LANES:(h + 1) * _LANES] += jnp.sum(
@@ -96,22 +96,22 @@ def _bwd_kernel(x_ref, before_ref, w_ref, b_ref, dy_ref, dx_ref, dwb_ref,
     # d x_t = sum_d w_(taps-1-d) dp_(t+d): the rows past the block are
     # the first of the block after it, visited one step ago
     dp_scr[rows_in_block:, :] = after_scr[j]
-    after_scr[j] = dp_scr[:_TILE, :]
+    after_scr[j] = dp_scr[:TILE, :]
 
     def to_dx(s, _):
-        rows = pl.ds(pl.multiple_of(s * _STRIP, _STRIP), _STRIP)
-        after = pl.ds(pl.multiple_of((s + 1) * _STRIP, _STRIP), _TILE)
+        rows = pl.ds(pl.multiple_of(s * STRIP, STRIP), STRIP)
+        after = pl.ds(pl.multiple_of((s + 1) * STRIP, STRIP), TILE)
         for h in range(x_ref.shape[2] // _LANES):
             lanes = slice(h * _LANES, (h + 1) * _LANES)
             dp, w = dp_scr[rows, lanes], w_ref[:, lanes]
             dx = dp * w[taps - 1:taps]
             for d in range(1, taps):
-                dx = dx + _advanced(dp, dp_scr[after, lanes], d) \
+                dx = dx + advanced(dp, dp_scr[after, lanes], d) \
                     * w[taps - 1 - d:taps - d]
             dx_ref[0, rows, lanes] = dx.astype(dx_ref.dtype)
         return 0
 
-    jax.lax.fori_loop(0, rows_in_block // _STRIP, to_dx, 0)
+    jax.lax.fori_loop(0, rows_in_block // STRIP, to_dx, 0)
 
 
 def _blocks(s: int, c: int, taps: int):
@@ -119,14 +119,14 @@ def _blocks(s: int, c: int, taps: int):
     registers dividing ``c``."""
     if c % _LANES:
         raise ValueError(f"{c} channels are no multiple of {_LANES} lanes")
-    if taps - 1 > _TILE:
-        raise ValueError(f"{taps} taps reach past the {_TILE} rows kept")
-    rows = next((b for b in (_ROWS, _ROWS // 2, _ROWS // 4) if s % b == 0),
+    if taps - 1 > TILE:
+        raise ValueError(f"{taps} taps reach past the {TILE} rows kept")
+    rows = next((b for b in (ROWS, ROWS // 2, ROWS // 4) if s % b == 0),
                 None)
     if rows is None:
         raise ValueError(f"sequence {s} is no multiple of a block of "
-                         f"{_ROWS // 4} rows")
-    cols = next(n * _LANES for n in range(_COLUMNS // _LANES, 0, -1)
+                         f"{ROWS // 4} rows")
+    cols = next(n * _LANES for n in range(COLUMNS // _LANES, 0, -1)
                 if c % (n * _LANES) == 0)
     return rows, cols
 
@@ -136,8 +136,8 @@ def _specs(rows, cols, taps, block_of):
     input's (and the output's), the rows before it, the taps' weights and
     the bias; ``block_of(t)``: the sequence block."""
     return (pl.BlockSpec((1, rows, cols), lambda i, t, j: (i, block_of(t), j)),
-            pl.BlockSpec((1, _HALO, cols), lambda i, t, j: (
-                i, jnp.maximum(block_of(t) * (rows // _HALO) - 1, 0), j)),
+            pl.BlockSpec((1, HALO, cols), lambda i, t, j: (
+                i, jnp.maximum(block_of(t) * (rows // HALO) - 1, 0), j)),
             pl.BlockSpec((taps, cols), lambda i, t, j: (0, j)),
             pl.BlockSpec((1, cols), lambda i, t, j: (0, j)))
 
@@ -156,7 +156,7 @@ def _conv_silu_fwd(x, w, b, interpret: bool):
         in_specs=[block, before, weights, bias],
         out_specs=block,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        compiler_params=_PARALLEL,
+        compiler_params=PARALLEL,
         interpret=interpret,
         name=KERNELS[0],
     )(x, x, w.astype(jnp.float32), b.astype(jnp.float32)[None, :])
@@ -180,9 +180,9 @@ def _conv_silu_bwd(x, w, b, dy, interpret: bool):
                                        lambda i, t, j: (0, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct((n, taps + 1, cols), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((rows + _TILE, cols), jnp.float32),
-                        pltpu.VMEM((n, _TILE, cols), jnp.float32)],
-        compiler_params=_IN_ORDER,
+        scratch_shapes=[pltpu.VMEM((rows + TILE, cols), jnp.float32),
+                        pltpu.VMEM((n, TILE, cols), jnp.float32)],
+        compiler_params=IN_ORDER,
         interpret=interpret,
         name=KERNELS[1],
     )(x, x, w.astype(jnp.float32), b.astype(jnp.float32)[None, :], dy)
@@ -202,13 +202,13 @@ def _rule_fwd(x, w, b, interpret):
         raise ValueError(f"taps {w.shape} and bias {b.shape} for "
                          f"{x.shape[2]} channels")
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     return _conv_silu_fwd(x, w, b, interpret), (x, w, b)
 
 
 def _rule_bwd(interpret, res, dy):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     x, w, b = res
     dx, dw, db = _conv_silu_bwd(x, w, b, dy, interpret)
     return dx, dw.astype(w.dtype), db.astype(b.dtype)

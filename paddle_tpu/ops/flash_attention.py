@@ -43,15 +43,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .pallas_common import LANES, fit, interpret_default
+
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() exact zero
                  # without inf-inf = nan hazards in the masked rows
 
 _SEM = pltpu.GridDimensionSemantics
-_LANES = 128
-
-
-def _interpret_default() -> bool:
-    return jax.devices()[0].platform != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -70,17 +67,6 @@ class Sweep(NamedTuple):
     sub: int
 
 
-def _fit(size: int, cap: int) -> int:
-    """Largest of cap, cap/2, ... 128 dividing ``size``; else the whole
-    axis (a block equal to the array's dimension is always legal)."""
-    b = cap
-    while b >= _LANES:
-        if size % b == 0:
-            return b
-        b //= 2
-    return size
-
-
 _FETCH_BYTES = 512 * 1024  # of one fetched block; K and V of a
                             # 2048 x 128 bf16 head each fit whole
 _UNROLL = 16                # tiles a kernel body may hold unrolled
@@ -95,10 +81,10 @@ def _sweep(resident: int, swept: int, d: int, itemsize: int) -> Sweep:
     bound is static then, and the tiles unroll into one basic block
     where the scheduler runs one tile's matmuls under the next one's
     vector work (a loop runs them in turn)."""
-    cap = max(_LANES, _FETCH_BYTES // (d * itemsize))
-    rows = _fit(resident, 512)
-    fetch = _fit(swept, cap)
-    sub = _fit(fetch, 512)
+    cap = max(LANES, _FETCH_BYTES // (d * itemsize))
+    rows = fit(resident, 512)
+    fetch = fit(swept, cap)
+    sub = fit(fetch, 512)
     whole = (fetch == swept and resident <= cap
              and (resident // rows) * (swept // sub) <= _UNROLL)
     return Sweep(resident if whole else rows, rows, fetch, sub)
@@ -300,11 +286,11 @@ def _visible(q_first, k_first, shape, off: int, q_axis: int,
 
 def _across(x, width: int):
     """A lane-replicated [rows, 128] statistic at ``width`` lanes."""
-    if width == _LANES:
+    if width == LANES:
         return x
-    if width % _LANES == 0:
-        return jnp.tile(x, (1, width // _LANES))
-    if width < _LANES:
+    if width % LANES == 0:
+        return jnp.tile(x, (1, width // LANES))
+    if width < LANES:
         return x[:, :width]
     return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
 
@@ -335,8 +321,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
     def block(r, first):  # t.rows queries from ``first``
         def init():
-            m_scr[r] = jnp.full((t.rows, _LANES), NEG_INF, jnp.float32)
-            l_scr[r] = jnp.zeros((t.rows, _LANES), jnp.float32)
+            m_scr[r] = jnp.full((t.rows, LANES), NEG_INF, jnp.float32)
+            l_scr[r] = jnp.zeros((t.rows, LANES), jnp.float32)
             acc_scr[r] = jnp.zeros((t.rows, d), jnp.float32)
 
         def tile(at, start, masked: bool):
@@ -414,8 +400,8 @@ def _flash_fwd(q, k, v, scale: float, causal: bool, interpret: bool,
             jax.ShapeDtypeStruct((batch, hq, 1, sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((t.held, _LANES), jnp.float32),  # running max
-            pltpu.VMEM((t.held, _LANES), jnp.float32),  # running denom
+            pltpu.VMEM((t.held, LANES), jnp.float32),  # running max
+            pltpu.VMEM((t.held, LANES), jnp.float32),  # running denom
             pltpu.VMEM((t.held, d), jnp.float32),       # output accumulator
         ],
         compiler_params=_PARALLEL_BUT_LAST,
@@ -616,7 +602,7 @@ def _fa_fwd(q, k, v, causal, scale, interpret, window):
         raise ValueError("a window counts back from the causal diagonal: "
                          f"causal={causal}, window={window}")
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
@@ -627,7 +613,7 @@ def _fa_fwd(q, k, v, causal, scale, interpret, window):
 
 def _fa_bwd(causal, scale, interpret, window, res, g):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     q, k, v, out_t, lse = res
     s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     hq, hkv = q.shape[2], k.shape[2]

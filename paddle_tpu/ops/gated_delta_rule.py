@@ -77,7 +77,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _interpret_default
+from .pallas_common import (NN, NT, TN, column, interpret_default, mm,
+                            put_column)
 
 __all__ = ["gated_delta_rule", "CHUNK", "KERNELS"]
 
@@ -90,39 +91,9 @@ KERNELS = ("gdn_fwd", "gdn_bwd")
 _BLOCK = 128
 _BODIES = 8
 
-_NN = (((1,), (0,)), ((), ()))   # a @ b
-_NT = (((1,), (1,)), ((), ()))   # a @ b.T
-_TN = (((0,), (0,)), ((), ()))   # a.T @ b
-
 _SEM = pltpu.GridDimensionSemantics
 _PARAMS = pltpu.CompilerParams(
     dimension_semantics=(_SEM.PARALLEL, _SEM.ARBITRARY, _SEM.ARBITRARY))
-
-
-def _mm(a, b, dims, dtype):
-    """A matmul with its operands in ``dtype``, added up in float32."""
-    return jax.lax.dot_general(
-        a.astype(dtype), b.astype(dtype), dims,
-        precision=(jax.lax.Precision.HIGHEST if dtype == jnp.float32
-                   else None),
-        preferred_element_type=jnp.float32)
-
-
-def _column(block, head):
-    """Column ``head`` (a grid index) of block [rows, heads] as [rows, 1]."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
-    return jnp.sum(jnp.where(lane == head, block, 0.0), axis=1, keepdims=True)
-
-
-def _put_column(ref, head, columns, first):
-    """Write ``columns`` (each [rows, 1]) into the columns from ``head`` on
-    of the resident block ``ref`` [1, rows, heads]; the first grid step to
-    visit clears it."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, ref.shape[1:], 1)
-    held = jnp.where(first, 0.0, ref[0])
-    for j, column in enumerate(columns):
-        held = jnp.where(lane == head + j, column, held)
-    ref[0] = held
 
 
 def _inverses(mats, dtype):
@@ -139,13 +110,13 @@ def _inverses(mats, dtype):
     outs = [jnp.where(eye, 1.0, 0.0) + p for p in powers]
     if n <= 2:
         return outs
-    powers, reach = [_mm(p, p, _NN, dtype) for p in powers], 2
+    powers, reach = [mm(p, p, NN, dtype) for p in powers], 2
     while 2 * reach < n:            # outs lack the factor (I + b^reach)
-        both = [_mm(jnp.concatenate([o, p], axis=0), p, _NN, dtype)
+        both = [mm(jnp.concatenate([o, p], axis=0), p, NN, dtype)
                 for o, p in zip(outs, powers)]
         outs = [o + x[:n] for o, x in zip(outs, both)]
         powers, reach = [x[n:] for x in both], 2 * reach
-    return [o + _mm(o, p, _NN, dtype) for o, p in zip(outs, powers)]
+    return [o + mm(o, p, NN, dtype) for o, p in zip(outs, powers)]
 
 
 def _inverse(a, dtype):
@@ -165,7 +136,7 @@ class _Keys:
         self.k = k
         self.qf, self.kf = q.astype(jnp.float32), k.astype(jnp.float32)
         self.qk = jnp.concatenate([q, k], axis=0)                  # [2C, dk]
-        both = _mm(self.qk, k, _NT, q.dtype)                       # [2C, C]
+        both = mm(self.qk, k, NT, q.dtype)                       # [2C, C]
         self.qkt, self.kk = both[:c], both[c:]
 
     def to_row(self, column):
@@ -201,9 +172,9 @@ class _Chunk:
     def finish(self, t):
         keys, dk = self.keys, self.keys.k.shape[1]
         self.t = t
-        self.wu = _mm(t, jnp.concatenate(
+        self.wu = mm(t, jnp.concatenate(
             [keys.kf * (self.beta * self.e), self.vf * self.beta], axis=1),
-            _NN, keys.k.dtype)
+            NN, keys.k.dtype)
         self.w, self.u = self.wu[:, :dk], self.wu[:, dk:]  # [C, dk], [C, dv]
         self.p = jnp.where(keys.lower, keys.qkt * self.decay, 0.0)
         self.qe = keys.qf * self.e
@@ -223,8 +194,8 @@ def _chunks(q_ref, k_ref, v_ref, g_ref, b_ref, group):
         for j in range(group):
             lanes = slice(j * dv, (j + 1) * dv)
             out[c, j] = _Chunk(keys, v_ref[0, at, lanes],
-                               _column(g_ref[0, at, :], first + j),
-                               _column(b_ref[0, at, :], first + j), at, lanes)
+                               column(g_ref[0, at, :], first + j),
+                               column(b_ref[0, at, :], first + j), at, lanes)
     for ch, t in zip(out.values(), _inverses(
             [ch.a for ch in out.values()], q_ref.dtype)):
         ch.finish(t)
@@ -245,10 +216,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, h_ref, s_scr, *,
     for (c, j), ch in _chunks(q_ref, k_ref, v_ref, g_ref, b_ref,
                               group).items():
         h_ref[0, j, c] = state[j].astype(h_ref.dtype)
-        new = ch.u - _mm(ch.w, state[j], _NN, dt)                      # V'
-        o = _mm(ch.qe, state[j], _NN, dt) + _mm(ch.p, new, _NN, dt)
+        new = ch.u - mm(ch.w, state[j], NN, dt)                      # V'
+        o = mm(ch.qe, state[j], NN, dt) + mm(ch.p, new, NN, dt)
         o_ref[0, ch.at, ch.lanes] = o.astype(o_ref.dtype)
-        state[j] = ch.e_last * state[j] + _mm(ch.kf_f, new, _TN, dt)
+        state[j] = ch.e_last * state[j] + mm(ch.kf_f, new, TN, dt)
     for j in range(group):
         s_scr[first + j] = state[j]
 
@@ -269,33 +240,33 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, h_ref, do_ref,
     do = {i: do_ref[0, chunks[i].at, chunks[i].lanes].astype(jnp.float32)
           for i in ids}
     # what needs no dS.  O = (Q e) S + P V';  V' = U - W S
-    new = {i: chunks[i].u - _mm(chunks[i].w, state[i], _NN, dt) for i in ids}
-    p_do = {i: _mm(chunks[i].p, do[i], _TN, dt) for i in ids}
-    d_p = {i: jnp.where(chunks[i].keys.lower, _mm(do[i], new[i], _NT, dt),
+    new = {i: chunks[i].u - mm(chunks[i].w, state[i], NN, dt) for i in ids}
+    p_do = {i: mm(chunks[i].p, do[i], TN, dt) for i in ids}
+    d_p = {i: jnp.where(chunks[i].keys.lower, mm(do[i], new[i], NT, dt),
                         0.0) for i in ids}
     # the chain.  S' = e_last S + (K f)^T V'
     d_state = [ds_scr[first + j] for j in range(group)]
     d_new, d_kf, d_last = {}, {}, {}
     for i in ids:
         ch, j = chunks[i], i[1]
-        d_new[i] = p_do[i] + _mm(ch.kf_f, d_state[j], _NN, dt)     # [C, dv]
-        d_kf[i] = _mm(new[i], d_state[j], _NT, dt)                 # [C, dk]
+        d_new[i] = p_do[i] + mm(ch.kf_f, d_state[j], NN, dt)     # [C, dv]
+        d_kf[i] = mm(new[i], d_state[j], NT, dt)                 # [C, dk]
         d_last[i] = jnp.sum(d_state[j] * state[i].astype(jnp.float32),
                             keepdims=True)
-        d_state[j] = ch.e_last * d_state[j] + _mm(
-            ch.qe_w, jnp.concatenate([do[i], -d_new[i]], axis=0), _TN, dt)
+        d_state[j] = ch.e_last * d_state[j] + mm(
+            ch.qe_w, jnp.concatenate([do[i], -d_new[i]], axis=0), TN, dt)
     for j in range(group):
         ds_scr[first + j] = d_state[j]
     # what hangs off it.  W = T (K beta e), U = T (V beta), T = (I + A)^-1
-    both = {i: _mm(jnp.concatenate([do[i], d_new[i]], axis=0), state[i], _NT,
-                   dt) for i in ids}
+    both = {i: mm(jnp.concatenate([do[i], d_new[i]], axis=0), state[i], NT,
+                  dt) for i in ids}
     d_qe = {i: both[i][:CHUNK] for i in ids}                       # [C, dk]
     d_w = {i: -both[i][CHUNK:] for i in ids}
-    d_kb_vb = {i: _mm(chunks[i].t, jnp.concatenate([d_w[i], d_new[i]],
-                                                   axis=1), _TN, dt)
+    d_kb_vb = {i: mm(chunks[i].t, jnp.concatenate([d_w[i], d_new[i]],
+                                                  axis=1), TN, dt)
                for i in ids}
     d_a = {i: jnp.where(chunks[i].keys.strict,
-                        -_mm(d_kb_vb[i], chunks[i].wu, _NT, dt), 0.0)
+                        -mm(d_kb_vb[i], chunks[i].wu, NT, dt), 0.0)
            for i in ids}
     d_gam, d_beta = [[] for _ in range(group)], [[] for _ in range(group)]
     # summed over a key head's value heads: dq, dk without their products
@@ -329,9 +300,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, h_ref, do_ref,
         sums[c] = (dq + e * d_qe[i], dk + beta * e * d_kb + f * d_kf[i],
                    d_both + jnp.concatenate([d_qk, d_kk], axis=0))
     # dq += d_qk k;  dk += d_kk k + d_kk^T k + d_qk^T q
-    both = {c: _mm(d_both, chunks[c, 0].keys.k, _NN, dt)           # [2C, dk]
+    both = {c: mm(d_both, chunks[c, 0].keys.k, NN, dt)           # [2C, dk]
             for c, (_, _, d_both) in sums.items()}
-    across = {c: _mm(d_both, chunks[c, 0].keys.qk, _TN, dt)
+    across = {c: mm(d_both, chunks[c, 0].keys.qk, TN, dt)
               for c, (_, _, d_both) in sums.items()}
     for c, (dq, dk, _) in sums.items():
         at = chunks[c, 0].at
@@ -339,10 +310,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, h_ref, do_ref,
         dk_ref[0, at, :] = (dk + both[c][CHUNK:] + across[c]).astype(
             dk_ref.dtype)
     clear = pl.program_id(2) == 0
-    _put_column(dg_ref, first,
-                [jnp.concatenate(d[::-1], axis=0) for d in d_gam], clear)
-    _put_column(db_ref, first,
-                [jnp.concatenate(d[::-1], axis=0) for d in d_beta], clear)
+    put_column(dg_ref, first,
+               [jnp.concatenate(d[::-1], axis=0) for d in d_gam], clear)
+    put_column(db_ref, first,
+               [jnp.concatenate(d[::-1], axis=0) for d in d_beta], clear)
 
 
 def _block(s: int, group: int) -> int:
@@ -463,14 +434,14 @@ def _rule_fwd(q, k, v, g, beta, interpret):
     # a ValueError where S is no multiple of the chunk
     _block(q.shape[1], v.shape[2] // q.shape[2])
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     o, states, gam = _gdn_fwd(q, k, v, g, beta, interpret)
     return o, (q, k, v, g, gam, beta, states)
 
 
 def _rule_bwd(interpret, res, do):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     q, k, v, g, gam, beta, states = res
     dq, dk, dv, dg, db = _gdn_bwd(q, k, v, gam, beta, states, do, interpret)
     return dq, dk, dv, dg.astype(g.dtype), db.astype(beta.dtype)

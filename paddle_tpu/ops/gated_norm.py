@@ -15,7 +15,7 @@ float32 reaches HBM.
 ``gated_norm_fwd`` (grid: batch, blocks of the sequence, blocks of the
 columns) reads a block [rows, columns] of ``o`` and of ``z``. A column
 block is whole heads, so a head's mean is a lane reduction inside it.
-Inside a block the kernels walk strips of ``_STRIP`` rows of one head,
+Inside a block the kernels walk strips of ``STRIP`` rows of one head,
 as ``ops/gdn_inputs.py``'s do, so that a strip's float32 stays in vector
 registers.
 
@@ -36,8 +36,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .flash_attention import _interpret_default
-from .gdn_inputs import _COLUMNS, _IN_ORDER, _PARALLEL, _ROWS, _STRIP, _TILE
+from .pallas_common import (COLUMNS, IN_ORDER, PARALLEL, ROWS, STRIP, TILE,
+                            interpret_default)
 
 __all__ = ["gated_rms_norm", "KERNELS"]
 
@@ -47,18 +47,18 @@ KERNELS = ("gated_norm_fwd", "gated_norm_bwd")
 
 
 def _strips(ref, d, strip, carried):
-    """Walk the block a strip of ``_STRIP`` rows and a head of ``d`` lanes
+    """Walk the block a strip of ``STRIP`` rows and a head of ``d`` lanes
     at a time: ``strip(rows, lanes, carried)`` returns what to carry to
     the next call. -> ``carried`` after the last strip."""
     heads = [slice(h * d, (h + 1) * d) for h in range(ref.shape[2] // d)]
 
     def body(s, kept):
-        rows = pl.ds(pl.multiple_of(s * _STRIP, _STRIP), _STRIP)
+        rows = pl.ds(pl.multiple_of(s * STRIP, STRIP), STRIP)
         for lanes in heads:
             kept = strip(rows, lanes, kept)
         return kept
 
-    return jax.lax.fori_loop(0, ref.shape[1] // _STRIP, body, carried)
+    return jax.lax.fori_loop(0, ref.shape[1] // STRIP, body, carried)
 
 
 def _normed(ref, rows, lanes, eps):
@@ -109,11 +109,11 @@ def _bwd_kernel(o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref, *, eps):
         dz_ref[0, rows, lanes] = (
             along * w * (sig * (1.0 + z * (1.0 - sig)))).astype(dz_ref.dtype)
         part = along * g
-        for at in range(0, _STRIP, _TILE):
-            acc = acc + part[at:at + _TILE]
+        for at in range(0, STRIP, TILE):
+            acc = acc + part[at:at + TILE]
         return acc
 
-    zero = jnp.zeros((_TILE, w.shape[1]), jnp.float32)
+    zero = jnp.zeros((TILE, w.shape[1]), jnp.float32)
     dw_ref[...] += _strips(o_ref, w.shape[1], strip, zero)
 
 
@@ -124,12 +124,12 @@ def _blocks(s: int, c: int, d: int):
         raise ValueError(f"a head of {d} is no multiple of 128 lanes")
     if c % d:
         raise ValueError(f"{c} columns are not whole heads of {d}")
-    rows = next((b for b in (_ROWS, _ROWS // 2, _ROWS // 4) if s % b == 0),
+    rows = next((b for b in (ROWS, ROWS // 2, ROWS // 4) if s % b == 0),
                 None)
     if rows is None:
         raise ValueError(f"sequence {s} is no multiple of a block of "
-                         f"{_ROWS // 4} rows")
-    heads = next(n for n in range(max(_COLUMNS // d, 1), 0, -1)
+                         f"{ROWS // 4} rows")
+    heads = next(n for n in range(max(COLUMNS // d, 1), 0, -1)
                  if (c // d) % n == 0)
     return rows, heads * d
 
@@ -155,7 +155,7 @@ def _gated_norm_fwd(o, z, w, eps: float, interpret: bool):
         in_specs=[block, block, gain],
         out_specs=block,
         out_shape=jax.ShapeDtypeStruct(o.shape, o.dtype),
-        compiler_params=_PARALLEL,
+        compiler_params=PARALLEL,
         interpret=interpret,
         name=KERNELS[0],
     )(o, z, w.astype(jnp.float32)[None, :])
@@ -174,11 +174,11 @@ def _gated_norm_bwd(o, z, w, dy, eps: float, interpret: bool):
         grid=(b, s // rows, c // cols),
         in_specs=[block, block, gain, block],
         out_specs=[block, block,
-                   pl.BlockSpec((_TILE, d), lambda i, t, j: (0, 0))],
+                   pl.BlockSpec((TILE, d), lambda i, t, j: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct(o.shape, o.dtype),
                    jax.ShapeDtypeStruct(z.shape, z.dtype),
-                   jax.ShapeDtypeStruct((_TILE, d), jnp.float32)],
-        compiler_params=_IN_ORDER,
+                   jax.ShapeDtypeStruct((TILE, d), jnp.float32)],
+        compiler_params=IN_ORDER,
         interpret=interpret,
         name=KERNELS[1],
     )(o, z, w.astype(jnp.float32)[None, :], dy)
@@ -198,13 +198,13 @@ def _rule_fwd(o, z, w, eps, interpret):
     # a ValueError where the kernels cannot take the shape
     _blocks(o.shape[1], o.shape[2], w.shape[0])
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     return _gated_norm_fwd(o, z, w, float(eps), interpret), (o, z, w)
 
 
 def _rule_bwd(eps, interpret, res, dy):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     o, z, w = res
     do, dz, dw = _gated_norm_bwd(o, z, w, dy, float(eps), interpret)
     return do, dz, dw.astype(w.dtype)

@@ -16,12 +16,12 @@ read of ``qkv`` and the writes of q, k, v are in that type: nothing of
 
 ``gdn_inputs_fwd`` (grid: batch, blocks of the sequence, blocks of the
 columns) reads a block [rows, columns] of ``qkv`` and, as a second view
-of the same array, the ``_HALO`` rows before it (a block's first
+of the same array, the ``HALO`` rows before it (a block's first
 ``taps - 1`` rows are convolved with them). A column block is whole
 heads, so a head's norm is a lane reduction inside it; it lies in q, in
 k or in v, and the kernel writes the one of its three outputs it lies
 in (the other two keep the block they hold: their index does not move).
-Inside a block the kernels walk strips of ``_STRIP`` rows of one head,
+Inside a block the kernels walk strips of ``STRIP`` rows of one head,
 carrying a strip's last rows to the next, so that a strip's float32
 stays in vector registers (a whole block at a time, every step of the
 chain stores and loads the block).
@@ -48,7 +48,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _interpret_default
+from .pallas_common import (COLUMNS, HALO, IN_ORDER, PARALLEL, ROWS, STRIP,
+                            TILE, advanced, interpret_default, strips)
 
 __all__ = ["conv_silu_l2norm", "KERNELS"]
 
@@ -56,36 +57,6 @@ __all__ = ["conv_silu_l2norm", "KERNELS"]
 # readers time every kernel whose name does
 KERNELS = ("gdn_inputs_fwd", "gdn_inputs_bwd")
 EPS = 1e-6                       # inside the norm's root
-_ROWS = 256                      # tokens a grid step holds
-_COLUMNS = 512                   # channels a grid step holds, at most
-_HALO = 16                       # rows of the view before a block: one
-#                                  tile of a 16-bit type, >= taps - 1
-_TILE = 8                        # float32 rows a vector register holds
-_STRIP = 64                      # rows a kernel's inner step computes
-
-_SEM = pltpu.GridDimensionSemantics
-_PARALLEL = pltpu.CompilerParams(
-    dimension_semantics=(_SEM.PARALLEL, _SEM.PARALLEL, _SEM.ARBITRARY))
-_IN_ORDER = pltpu.CompilerParams(
-    dimension_semantics=(_SEM.ARBITRARY, _SEM.ARBITRARY, _SEM.ARBITRARY))
-
-
-def _delayed(x, before, d):
-    """Row r of the result is row r - d of ``x`` [strip, lanes], and of
-    ``before`` (the ``_TILE`` rows before ``x``) where r < d."""
-    if d == 0:
-        return x
-    whole = jnp.concatenate([before, x], axis=0)
-    return pltpu.roll(whole, d, 0)[_TILE:]
-
-
-def _advanced(x, after, d):
-    """Row r of the result is row r + d of ``x`` [strip, lanes], and of
-    ``after`` (the ``_TILE`` rows after ``x``) where that is past it."""
-    if d == 0:
-        return x
-    whole = jnp.concatenate([x, after], axis=0)
-    return pltpu.roll(whole, whole.shape[0] - d, 0)[:x.shape[0]]
 
 
 def _unit(a, scale):
@@ -93,38 +64,6 @@ def _unit(a, scale):
     the lanes: ``a`` is one head's."""
     r = scale * jax.lax.rsqrt(jnp.sum(a * a, axis=1, keepdims=True) + EPS)
     return a * r, r
-
-
-def _strips(x_ref, before_ref, w_ref, first, dk, strip, carried=()):
-    """Walk the block a strip of ``_STRIP`` rows and a head of ``dk``
-    lanes at a time, in the sequence's order, so that what a strip
-    computes stays in vector registers: ``strip(rows, lanes, xs, p,
-    carried)`` gets the taps' inputs ``xs`` (newest first) and ``p``
-    (float32 [_STRIP, dk]) and returns what to carry to the next strip's
-    call for the same head. -> ``carried`` after the last strip, a list
-    over the block's heads."""
-    cols = x_ref.shape[2]
-    taps = w_ref.shape[0]
-    heads = [slice(h * dk, (h + 1) * dk) for h in range(cols // dk)]
-    halo = before_ref[0].astype(jnp.float32)[_HALO - _TILE:]
-    halo = jnp.where(first, 0.0, halo)
-
-    def body(s, state):
-        rows = pl.ds(pl.multiple_of(s * _STRIP, _STRIP), _STRIP)
-        out = []
-        for lanes, (before, kept) in zip(heads, state):
-            x = x_ref[0, rows, lanes].astype(jnp.float32)
-            w = w_ref[:, lanes]
-            xs = [_delayed(x, before, d) for d in range(taps)]
-            p = xs[0] * w[taps - 1:taps]
-            for d in range(1, taps):
-                p = p + xs[d] * w[taps - 1 - d:taps - d]
-            out.append((x[_STRIP - _TILE:], strip(rows, lanes, xs, p, kept)))
-        return out
-
-    state = [(halo[:, lanes], carried) for lanes in heads]
-    state = jax.lax.fori_loop(0, x_ref.shape[1] // _STRIP, body, state)
-    return [kept for _, kept in state]
 
 
 def _in_its_part(j, nq, dk, refs, run):
@@ -148,7 +87,7 @@ def _fwd_kernel(x_ref, before_ref, w_ref, q_ref, k_ref, v_ref, *, nq, dk):
                 a = _unit(a, scale)[0]
             ref[0, rows, lanes] = a.astype(ref.dtype)
             return kept
-        _strips(x_ref, before_ref, w_ref, first, dk, strip)
+        strips(x_ref, before_ref, w_ref, first, dk, strip)
 
     _in_its_part(j, nq, dk, (q_ref, k_ref, v_ref), write)
 
@@ -185,14 +124,14 @@ def _bwd_kernel(x_ref, before_ref, w_ref, dq_ref, dk_ref, dv_ref,
             out = []
             for tap, acc in enumerate(sums):
                 part = dp * xs[taps - 1 - tap]
-                for at in range(0, _STRIP, _TILE):
-                    acc = acc + part[at:at + _TILE]
+                for at in range(0, STRIP, TILE):
+                    acc = acc + part[at:at + TILE]
                 out.append(acc)
             return out
 
-        zero = jnp.zeros((_TILE, dk), jnp.float32)
-        return _strips(x_ref, before_ref, w_ref, t == last, dk, strip,
-                       [zero] * taps)
+        zero = jnp.zeros((TILE, dk), jnp.float32)
+        return strips(x_ref, before_ref, w_ref, t == last, dk, strip,
+                      [zero] * taps)
 
     def add_up(sums):
         for h, head in enumerate(sums):
@@ -206,22 +145,22 @@ def _bwd_kernel(x_ref, before_ref, w_ref, dq_ref, dk_ref, dv_ref,
     # d x_t = sum_d w_(taps-1-d) dp_(t+d): the rows past the block are
     # the first of the block after it, visited one step ago
     dp_scr[rows_in_block:, :] = after_scr[j]
-    after_scr[j] = dp_scr[:_TILE, :]
+    after_scr[j] = dp_scr[:TILE, :]
 
     def to_dx(s, _):
-        rows = pl.ds(pl.multiple_of(s * _STRIP, _STRIP), _STRIP)
-        after = pl.ds(pl.multiple_of((s + 1) * _STRIP, _STRIP), _TILE)
+        rows = pl.ds(pl.multiple_of(s * STRIP, STRIP), STRIP)
+        after = pl.ds(pl.multiple_of((s + 1) * STRIP, STRIP), TILE)
         for h in range(x_ref.shape[2] // dk):
             lanes = slice(h * dk, (h + 1) * dk)
             dp, w = dp_scr[rows, lanes], w_ref[:, lanes]
             dx = dp * w[taps - 1:taps]
             for d in range(1, taps):
-                dx = dx + _advanced(dp, dp_scr[after, lanes], d) \
+                dx = dx + advanced(dp, dp_scr[after, lanes], d) \
                     * w[taps - 1 - d:taps - d]
             dx_ref[0, rows, lanes] = dx.astype(dx_ref.dtype)
         return 0
 
-    jax.lax.fori_loop(0, rows_in_block // _STRIP, to_dx, 0)
+    jax.lax.fori_loop(0, rows_in_block // STRIP, to_dx, 0)
 
 
 def _blocks(s: int, c: int, key: int, dk: int, taps: int):
@@ -229,14 +168,14 @@ def _blocks(s: int, c: int, key: int, dk: int, taps: int):
     heads dividing the width of q (= k's) and v's."""
     if dk % 128:
         raise ValueError(f"a head of {dk} is no multiple of 128 lanes")
-    if taps - 1 > _TILE:
-        raise ValueError(f"{taps} taps reach past the {_TILE} rows kept")
-    rows = next((b for b in (_ROWS, _ROWS // 2, _ROWS // 4) if s % b == 0),
+    if taps - 1 > TILE:
+        raise ValueError(f"{taps} taps reach past the {TILE} rows kept")
+    rows = next((b for b in (ROWS, ROWS // 2, ROWS // 4) if s % b == 0),
                 None)
     if rows is None:
         raise ValueError(f"sequence {s} is no multiple of a block of "
-                         f"{_ROWS // 4} rows")
-    heads = max(_COLUMNS // dk, 1)
+                         f"{ROWS // 4} rows")
+    heads = max(COLUMNS // dk, 1)
     while key % (heads * dk) or (c - 2 * key) % (heads * dk):
         heads -= 1
         if not heads:
@@ -255,8 +194,8 @@ def _specs(rows, cols, nq, nv, taps, block_of):
                                 jnp.clip(j - first, 0, n - 1))
 
     return (pl.BlockSpec((1, rows, cols), lambda i, t, j: (i, block_of(t), j)),
-            pl.BlockSpec((1, _HALO, cols), lambda i, t, j: (
-                i, jnp.maximum(block_of(t) * (rows // _HALO) - 1, 0), j)),
+            pl.BlockSpec((1, HALO, cols), lambda i, t, j: (
+                i, jnp.maximum(block_of(t) * (rows // HALO) - 1, 0), j)),
             pl.BlockSpec((taps, cols), lambda i, t, j: (0, j)),
             pl.BlockSpec((1, rows, cols), clipped(0, nq)),
             pl.BlockSpec((1, rows, cols), clipped(nq, nq)),
@@ -282,7 +221,7 @@ def _gdn_inputs_fwd(qkv, w, hk, hv, dk, dv, interpret: bool):
         out_shape=[jax.ShapeDtypeStruct((b, s, key), qkv.dtype),
                    jax.ShapeDtypeStruct((b, s, key), qkv.dtype),
                    jax.ShapeDtypeStruct((b, s, hv * dv), qkv.dtype)],
-        compiler_params=_PARALLEL,
+        compiler_params=PARALLEL,
         interpret=interpret,
         name=KERNELS[0],
     )(qkv, qkv, w.astype(jnp.float32))
@@ -306,9 +245,9 @@ def _gdn_inputs_bwd(qkv, w, dq, dk_, dv_, hk, hv, dk, dv, interpret: bool):
         out_specs=[x, pl.BlockSpec((n, taps, cols), lambda i, t, j: (0, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((b, s, c), qkv.dtype),
                    jax.ShapeDtypeStruct((n, taps, cols), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((rows + _TILE, cols), jnp.float32),
-                        pltpu.VMEM((n, _TILE, cols), jnp.float32)],
-        compiler_params=_IN_ORDER,
+        scratch_shapes=[pltpu.VMEM((rows + TILE, cols), jnp.float32),
+                        pltpu.VMEM((n, TILE, cols), jnp.float32)],
+        compiler_params=IN_ORDER,
         interpret=interpret,
         name=KERNELS[1],
     )(qkv, qkv, w.astype(jnp.float32), dq, dk_, dv_)
@@ -330,7 +269,7 @@ def _inputs_fwd(qkv, w, hk, hv, dk, dv, interpret):
         raise ValueError(f"{c} columns (the taps' {w.shape[1]}) for "
                          f"{hk} + {hk} heads of {dk} and {hv} of {dv}")
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     q, k, v = _gdn_inputs_fwd(qkv, w, hk, hv, dk, dv, interpret)
     return ((q.reshape(b, s, hk, dk), k.reshape(b, s, hk, dk),
              v.reshape(b, s, hv, dv)), (qkv, w))
@@ -338,7 +277,7 @@ def _inputs_fwd(qkv, w, hk, hv, dk, dv, interpret):
 
 def _inputs_bwd(hk, hv, dk, dv, interpret, res, cotangents):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     qkv, w = res
     b, s, _ = qkv.shape
     dq, dk_, dv_ = (d.reshape(b, s, -1) for d in cotangents)

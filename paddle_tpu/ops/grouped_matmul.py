@@ -56,7 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _fit, _interpret_default
+from .pallas_common import fit, interpret_default
 
 _NN = (((1,), (0,)), ((), ()))  # a @ b
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
@@ -102,7 +102,7 @@ def gmm_tiling(t: int, k: int, n: int, itemsize: int) -> Tiling:
     for 0.3 of pipeline); at 3072 x 6144 a third (2048), and the walk
     over the groups is made once a column block."""
     tn = _widest(n, max(128, _W_BLOCK_BYTES // (k * itemsize)))
-    return Tiling(_fit(t, 128), k, tn)
+    return Tiling(fit(t, 128), k, tn)
 
 
 def tgmm_tiling(t: int, k: int, n: int, itemsize: int) -> Tiling:
@@ -115,9 +115,9 @@ def tgmm_tiling(t: int, k: int, n: int, itemsize: int) -> Tiling:
     of 256 aligned to the array, always 31 (PERF.md §6, PR 27). The
     accumulator [tk, tn] is as large as ``_DW_BLOCK_BYTES`` allows: the
     larger, the fewer times the rows are read again."""
-    tile = _fit(t, 128)
-    tk = _fit(k, 2048)
-    tn = _fit(n, max(128, _DW_BLOCK_BYTES // (itemsize * tk)))
+    tile = fit(t, 128)
+    tk = fit(k, 2048)
+    tn = fit(n, max(128, _DW_BLOCK_BYTES // (itemsize * tk)))
     return Tiling(tile * min(2, t // tile), tk, tn)
 
 
@@ -319,7 +319,7 @@ def _tgmm(x, dy, group_sizes, tiling: Optional[Tiling], interpret: bool):
     n = dy.shape[1]
     e = group_sizes.shape[0]
     t = tiling or tgmm_tiling(rows, k, n, x.dtype.itemsize)
-    tile = _fit(rows, 128)
+    tile = fit(rows, 128)
     parts = t.tm // tile
     assert (t.tm == parts * tile and k % t.tk == 0 and n % t.tn == 0
             ), (x.shape, t)
@@ -374,14 +374,14 @@ def grouped_matmul(x, w, group_sizes, interpret: Optional[bool] = None):
 
 def _gm_fwd(x, w, group_sizes, interpret):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     out = _gmm(x, w, group_sizes, False, None, interpret)
     return out, (x, w, group_sizes)
 
 
 def _gm_bwd(interpret, res, dy):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     x, w, group_sizes = res
     dy = dy.astype(x.dtype)
     dx = _gmm(dy, w, group_sizes, True, None, interpret)

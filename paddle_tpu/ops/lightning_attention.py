@@ -44,8 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _interpret_default
-from .gated_delta_rule import _NN, _NT, _TN, _mm
+from .pallas_common import NN, NT, TN, interpret_default, mm
 
 __all__ = ["lightning_attention", "alibi_slopes", "CHUNK", "KERNELS"]
 
@@ -81,8 +80,8 @@ class _Decay:
         self.whole = jnp.exp(-slope * c * jnp.ones((1, 1), jnp.float32))
 
     def next_state(self, state, k, v, dt):
-        return self.whole * state + _mm(k.astype(jnp.float32) * self.b, v,
-                                        _TN, dt)
+        return self.whole * state + mm(k.astype(jnp.float32) * self.b, v,
+                                       TN, dt)
 
 
 def _chunks(ref):
@@ -102,9 +101,9 @@ def _fwd_kernel(slopes_ref, q_ref, k_ref, v_ref, o_ref, s_ref, s_scr, *,
     s_ref[0, 0, 0] = state
     for at in _chunks(q_ref):
         q, k, v = q_ref[0, at, :], k_ref[0, at, :], v_ref[0, at, :]
-        p = jnp.where(dec.lower, _mm(q, k, _NT, dt) * dec.d, 0.0)
-        o = (_mm(p, v, _NN, dt)
-             + _mm(q.astype(jnp.float32) * dec.a, state, _NN, dt))
+        p = jnp.where(dec.lower, mm(q, k, NT, dt) * dec.d, 0.0)
+        o = (mm(p, v, NN, dt)
+             + mm(q.astype(jnp.float32) * dec.a, state, NN, dt))
         o_ref[0, at, :] = (scale * o).astype(o_ref.dtype)
         state = dec.next_state(state, k, v, dt)
     s_scr[...] = state
@@ -129,13 +128,13 @@ def _bwd_kernel(slopes_ref, q_ref, k_ref, v_ref, s_ref, do_ref,
         do = do_ref[0, at, :]
         qa = q.astype(jnp.float32) * dec.a
         kb = k.astype(jnp.float32) * dec.b
-        p = jnp.where(dec.lower, _mm(q, k, _NT, dt) * dec.d, 0.0)
-        d_qk = jnp.where(dec.lower, scale * _mm(do, v, _NT, dt) * dec.d, 0.0)
-        dv = scale * _mm(p, do, _TN, dt) + _mm(kb, d_state, _NN, dt)
-        dq = (scale * dec.a * _mm(do, state, _NT, dt)
-              + _mm(d_qk, k, _NN, dt))
-        dk = _mm(d_qk, q, _TN, dt) + dec.b * _mm(v, d_state, _NT, dt)
-        d_state = scale * _mm(qa, do, _TN, dt) + dec.whole * d_state
+        p = jnp.where(dec.lower, mm(q, k, NT, dt) * dec.d, 0.0)
+        d_qk = jnp.where(dec.lower, scale * mm(do, v, NT, dt) * dec.d, 0.0)
+        dv = scale * mm(p, do, TN, dt) + mm(kb, d_state, NN, dt)
+        dq = (scale * dec.a * mm(do, state, NT, dt)
+              + mm(d_qk, k, NN, dt))
+        dk = mm(d_qk, q, TN, dt) + dec.b * mm(v, d_state, NT, dt)
+        d_state = scale * mm(qa, do, TN, dt) + dec.whole * d_state
         dq_ref[0, at, :] = dq.astype(dq_ref.dtype)
         dk_ref[0, at, :] = dk.astype(dk_ref.dtype)
         dv_ref[0, at, :] = dv.astype(dv_ref.dtype)
@@ -226,7 +225,7 @@ def _settled(q, scale, interpret):
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     return float(scale), interpret
 
 

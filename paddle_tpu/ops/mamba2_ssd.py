@@ -64,8 +64,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _interpret_default
-from .gated_delta_rule import _NN, _NT, _TN, _column, _mm, _put_column
+from .pallas_common import (NN, NT, TN, column, interpret_default, mm,
+                            put_column)
 
 __all__ = ["ssd", "CHUNK", "KERNELS"]
 
@@ -119,13 +119,13 @@ class _Chunk:
         self.x = x_ref[0, at, :].astype(jnp.float32)             # [Q, L]
         self.b, self.c = b_ref[0, at, :], c_ref[0, at, :]        # [Q, N]
         dts, css = dt_ref[0, at, :], cs_ref[0, at, :]            # [Q, H]
-        self.dt = [_column(dts, first + j) for j in range(heads)]
-        self.cs = [_column(css, first + j) for j in range(heads)]
+        self.dt = [column(dts, first + j) for j in range(heads)]
+        self.cs = [column(css, first + j) for j in range(heads)]
         self.rows = [cst_ref[0, 0, j:j + 1, at] for j in range(heads)]
         self.lower = (jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
                       >= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1))
         self.g = jnp.where(self.lower,
-                           _mm(self.c, self.b, _NT, self.dtype), 0.0)
+                           mm(self.c, self.b, NT, self.dtype), 0.0)
         last = [cs[q - 1:q, :] for cs in self.cs]                # [1, 1]
         self.e = _lanes([jnp.exp(cs) for cs in self.cs])
         self.f = _lanes([jnp.exp(l - cs) for l, cs in zip(last, self.cs)])
@@ -138,8 +138,8 @@ class _Chunk:
         return jnp.where(self.lower, jnp.exp(gap), 0.0)
 
     def next_state(self, state):
-        return self.whole * state + _mm(self.b, self.f * self.xd, _TN,
-                                        self.dtype)
+        return self.whole * state + mm(self.b, self.f * self.xd, TN,
+                                       self.dtype)
 
 
 def _pairs(width: int):
@@ -171,10 +171,10 @@ def _fwd_kernel(x_ref, dt_ref, cs_ref, cst_ref, b_ref, c_ref, d_ref,
             xd = ch.xd[:, lanes]
             within.append(jnp.where(
                 half,
-                _mm(ch.decay(2 * p) * ch.g, xd, _NN, ch.dtype),
-                _mm(ch.decay(2 * p + 1) * ch.g, xd, _NN, ch.dtype)))
+                mm(ch.decay(2 * p) * ch.g, xd, NN, ch.dtype),
+                mm(ch.decay(2 * p + 1) * ch.g, xd, NN, ch.dtype)))
         y = (jnp.concatenate(within, axis=1)
-             + ch.e * _mm(ch.c, state, _NN, ch.dtype) + d_ref[...] * ch.x)
+             + ch.e * mm(ch.c, state, NN, ch.dtype) + d_ref[...] * ch.x)
         y_ref[0, at, :] = y.astype(y_ref.dtype)
         state = ch.next_state(state)
     s_scr[hb] = state
@@ -207,7 +207,7 @@ def _bwd_kernel(x_ref, dt_ref, cs_ref, cst_ref, b_ref, c_ref, d_ref, s_ref,
         dtype = ch.dtype
         dy = dy_ref[0, at, :].astype(jnp.float32)
         edy = ch.e * dy
-        from_next = ch.f * _mm(ch.b, d_state, _NN, dtype)       # f (B dT')
+        from_next = ch.f * mm(ch.b, d_state, NN, dtype)       # f (B dT')
         d_g = jnp.zeros((q, q), jnp.float32)
         within, by_row = [], []
         for p, lanes in _pairs(x_ref.shape[2]):
@@ -216,22 +216,22 @@ def _bwd_kernel(x_ref, dt_ref, cs_ref, cst_ref, b_ref, c_ref, d_ref, s_ref,
             for k, mine in enumerate((half, ~half)):
                 j = 2 * p + k
                 decay = ch.decay(j)
-                d_m = jnp.where(ch.lower, _mm(jnp.where(mine, dyp, 0.0), xd,
-                                              _NT, dtype), 0.0)
+                d_m = jnp.where(ch.lower, mm(jnp.where(mine, dyp, 0.0), xd,
+                                             NT, dtype), 0.0)
                 d_g = d_g + decay * d_m
                 m = decay * ch.g
                 z = m * d_m
                 by_row.append(jnp.sum(z, axis=1, keepdims=True))
                 dcst_ref[0, 0, j:j + 1, at] = -jnp.sum(z, axis=0,
                                                        keepdims=True)
-                both.append(_mm(m, dyp, _TN, dtype))
+                both.append(mm(m, dyp, TN, dtype))
             within.append(jnp.where(half, *both))
         d_xd = jnp.concatenate(within, axis=1) + from_next
         dx_ref[0, at, :] = (d_xd * _lanes(ch.dt)
                             + d_ref[...] * dy).astype(dx_ref.dtype)
         # d cs: a row of L, the carry's e, the next state's f; the chunk's
         # last row also takes every f of the chunk and exp(cs_last)
-        carried = _head_sums(edy * _mm(ch.c, state, _NN, dtype))
+        carried = _head_sums(edy * mm(ch.c, state, NN, dtype))
         passed = _head_sums(from_next * ch.xd)
         kept = _head_sums(jnp.sum(ch.whole * d_state * state, axis=0,
                                   keepdims=True))
@@ -241,22 +241,22 @@ def _bwd_kernel(x_ref, dt_ref, cs_ref, cst_ref, b_ref, c_ref, d_ref, s_ref,
                 by_row[j] + carried[j] - passed[j]
                 + jnp.where(at_last, jnp.sum(passed[j], axis=0,
                                              keepdims=True) + kept[j], 0.0))
-        d_c = _mm(d_g, ch.b, _NN, dtype) + _mm(edy, state, _NT, dtype)
-        d_b = (_mm(d_g, ch.c, _TN, dtype)
-               + _mm(ch.f * ch.xd, d_state, _NT, dtype))
+        d_c = mm(d_g, ch.b, NN, dtype) + mm(edy, state, NT, dtype)
+        d_b = (mm(d_g, ch.c, TN, dtype)
+               + mm(ch.f * ch.xd, d_state, NT, dtype))
         for ref, d in ((dc_ref, d_c), (db_ref, d_b)):
             ref[0, at, :] = jnp.where(hb == 0, 0.0, ref[0, at, :]) + d
         part = dy * ch.x
         for r in range(0, q, _TILE):
             skipped = skipped + part[r:r + _TILE]
-        d_state = ch.whole * d_state + _mm(ch.c, edy, _TN, dtype)
+        d_state = ch.whole * d_state + mm(ch.c, edy, TN, dtype)
     ds_scr[hb] = d_state
     dd_ref[0, hb] += skipped
     clear = hb == 0
-    _put_column(ddt_ref, first,
-                [jnp.concatenate(d[::-1], axis=0) for d in d_dt], clear)
-    _put_column(dcs_ref, first,
-                [jnp.concatenate(d[::-1], axis=0) for d in d_cs], clear)
+    put_column(ddt_ref, first,
+               [jnp.concatenate(d[::-1], axis=0) for d in d_dt], clear)
+    put_column(dcs_ref, first,
+               [jnp.concatenate(d[::-1], axis=0) for d in d_cs], clear)
 
 
 def _block(s: int) -> int:
@@ -391,7 +391,7 @@ def _rule_fwd(x, dt, a, b, c, d, interpret):
     if b.shape != c.shape or b.shape[:2] != (bsz, s) or b.ndim != 3:
         raise ValueError(f"b {b.shape} and c {c.shape}: one group, [B, S, N]")
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     y, states = _ssd_fwd(x, dt, _running(dt, a), b, c, d, interpret)
     return y, (x, dt, a, b, c, d, states)
 
@@ -399,7 +399,7 @@ def _rule_fwd(x, dt, a, b, c, d, interpret):
 def _rule_bwd(interpret, res, dy):
     x, dt, a, b, c, d, states = res
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     bsz, s, h = dt.shape
     dx, ddt, dcs, db, dc, dd = _ssd_bwd(x, dt, _running(dt, a), b, c, d,
                                         states, dy, interpret)

@@ -91,8 +91,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _interpret_default
-from .gated_delta_rule import _NN, _NT, _TN, _mm
+from .pallas_common import NN, NT, TN, interpret_default, mm
 
 __all__ = ["select_blocks", "block_sparse_attention", "band_engages",
            "band_blocks", "BLOCK", "KERNELS", "BAND_KERNELS"]
@@ -325,15 +324,15 @@ def _fwd_kernel(tab_ref, q_ref, k_ref, v_ref, *rest, scale, band):
         for c in each:
             _gather(tab_ref, ts[c], ((k_ref, ksel.at[c]), (v_ref, vsel.at[c])))
         seen = [lane < tab_ref[0, 0, t, tab_ref.shape[3] - 1] for t in ts]
-        s = [jnp.where(seen[c], scale * _mm(q_ref[0, 0, ts[c]], ksel[c], _NT,
-                                            dt), _NEG) for c in each]
+        s = [jnp.where(seen[c], scale * mm(q_ref[0, 0, ts[c]], ksel[c], NT,
+                                           dt), _NEG) for c in each]
         top = [jnp.max(x, axis=1, keepdims=True) for x in s]
         if band:
             base = [_at_lane(before, t) for t in ts]
             top = [jnp.maximum(a, b) for a, b in zip(top, base)]
         p = [jnp.where(seen[c], jnp.exp(s[c] - top[c]), 0.0) for c in each]
         total = [jnp.sum(x, axis=1, keepdims=True) for x in p]
-        o = [_mm(p[c], vsel[c], _NN, dt) for c in each]
+        o = [mm(p[c], vsel[c], NN, dt) for c in each]
         if band:
             share = [jnp.exp(b - a) for a, b in zip(top, base)]
             total = [a + w for a, w in zip(total, share)]
@@ -395,12 +394,12 @@ def _bwd_kernel(tab_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, *rest,
         delta = [jnp.sum(do[c].astype(jnp.float32)
                          * o_ref[0, 0, ts[c]].astype(jnp.float32), axis=1,
                          keepdims=True) for c in each]
-        s = [scale * _mm(q[c], ksel[c], _NT, dt) for c in each]
+        s = [scale * mm(q[c], ksel[c], NT, dt) for c in each]
         p = [jnp.where(seen[c], jnp.exp(jnp.where(seen[c], s[c] - lse[c],
                                                   0.0)), 0.0) for c in each]
-        dp = [_mm(do[c], vsel[c], _NT, dt) for c in each]
+        dp = [mm(do[c], vsel[c], NT, dt) for c in each]
         ds = [scale * p[c] * (dp[c] - delta[c]) for c in each]
-        dq = [_mm(ds[c], ksel[c], _NN, dt) for c in each]
+        dq = [mm(ds[c], ksel[c], NN, dt) for c in each]
         for c in each:
             if band:
                 dq[c] = dq[c] + dqb_ref[0, 0, ts[c]]
@@ -414,8 +413,8 @@ def _bwd_kernel(tab_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, *rest,
             for first in range(0, picks, 2):
                 blocks = min(2, picks - first)
                 lanes = slice(first * BLOCK, (first + blocks) * BLOCK)
-                dk = _mm(ds[c][:, lanes], q[c], _TN, dt)
-                dv = _mm(p[c][:, lanes], do[c], _TN, dt)
+                dk = mm(ds[c][:, lanes], q[c], TN, dt)
+                dv = mm(p[c][:, lanes], do[c], TN, dt)
                 for j in range(blocks):
                     start = pl.multiple_of(
                         tab_ref[0, 0, ts[c], first + j] * BLOCK, BLOCK)
@@ -501,8 +500,8 @@ def _band_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
         def visit(start, keys, limit):
-            s = scale * _mm(q_ref[0, 0, at, :],
-                            k_ref[0, 0, pl.ds(start, keys), :], _NT, dt)
+            s = scale * mm(q_ref[0, 0, at, :],
+                           k_ref[0, 0, pl.ds(start, keys), :], NT, dt)
             if limit is not None:
                 s = jnp.where(_iota((1, keys), 1) < limit, s, _NEG)
             was = m_scr[...]
@@ -510,8 +509,8 @@ def _band_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             p = jnp.exp(s - top)         # a visit's rows each see a key
             fade = jnp.exp(was - top)
             l_scr[...] = fade * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-            acc_scr[...] = fade * acc_scr[...] + _mm(
-                p, v_ref[0, 0, pl.ds(start, keys), :], _NN, dt)
+            acc_scr[...] = fade * acc_scr[...] + mm(
+                p, v_ref[0, 0, pl.ds(start, keys), :], NN, dt)
             m_scr[...] = top
 
         _band_walk(before + j, heads, band, visit)
@@ -549,15 +548,15 @@ def _band_bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref,
         def visit(start, keys, limit):
             q, do = q_ref[0, 0, at, :], do_ref[0, 0, at, :]
             k = k_ref[0, 0, pl.ds(start, keys), :]
-            s = scale * _mm(q, k, _NT, dt) - lse
+            s = scale * mm(q, k, NT, dt) - lse
             if limit is not None:
                 s = jnp.where(_iota((1, keys), 1) < limit, s, _NEG)
             p = jnp.exp(s)
-            ds = scale * p * (_mm(
-                do, v_ref[0, 0, pl.ds(start, keys), :], _NT, dt) - delta)
-            dq_scr[...] += _mm(ds, k, _NN, dt)
-            dk_ref[0, 0, pl.ds(start, keys), :] += _mm(ds, q, _TN, dt)
-            dv_ref[0, 0, pl.ds(start, keys), :] += _mm(p, do, _TN, dt)
+            ds = scale * p * (mm(
+                do, v_ref[0, 0, pl.ds(start, keys), :], NT, dt) - delta)
+            dq_scr[...] += mm(ds, k, NN, dt)
+            dk_ref[0, 0, pl.ds(start, keys), :] += mm(ds, q, TN, dt)
+            dv_ref[0, 0, pl.ds(start, keys), :] += mm(p, do, TN, dt)
 
         _band_walk(before + j, heads, band, visit)
         dq_ref[0, 0, at, :] = dq_scr[...]
@@ -737,7 +736,7 @@ def _attn_fwd(q, k, v, table, interpret, band):
     if h % g or table.shape[:3] != (b, g, s):
         raise ValueError(f"table {table.shape} for q {q.shape}, k {k.shape}")
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     ids = _canonical(table, s, band)
     q5, k4, v4 = _by_group(q, g), k.transpose(0, 2, 1, 3), \
         v.transpose(0, 2, 1, 3)
@@ -748,7 +747,7 @@ def _attn_fwd(q, k, v, table, interpret, band):
 def _attn_bwd(interpret, band, res, do):
     q5, k4, v4, ids, o5, lse = res
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     dq5, dk4, dv4 = _sparse_bwd(q5, k4, v4, ids, o5, lse,
                                 _by_group(do, k4.shape[1]), band, interpret)
     width = ids.shape[3] - 1 + (sum(band) if band else 0)

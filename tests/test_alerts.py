@@ -596,6 +596,7 @@ class TestLoadgenParity:
 
 
 class TestE2EFleet:
+    @pytest.mark.slow   # 130-340 s of tier-1, on no cell's path (ROADMAP D14 a)
     def test_burn_alert_full_lifecycle_over_chaos_fleet(self, tmp_path):
         from paddle_tpu.distributed.store import MemKVStore
         from paddle_tpu.inference.cluster import (ClusterRouter,

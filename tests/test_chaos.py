@@ -485,6 +485,7 @@ class TestEndToEndRelaunch:
                 return float(line.split("final_loss=")[1])
         return None
 
+    @pytest.mark.slow   # 50-100 s of tier-1, on no cell's path (ROADMAP D14 a)
     def test_kill_relaunch_resume_loss_parity(self, tmp_path):
         total, kill_step = 14, 10
 

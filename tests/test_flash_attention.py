@@ -364,7 +364,7 @@ class TestKernelOverFleetMesh:
 
         from paddle_tpu.ops import flash_attention as kernel_module
 
-        monkeypatch.setattr(kernel_module, "_interpret_default",
+        monkeypatch.setattr(kernel_module, "interpret_default",
                             lambda: False)
         q = jax.ShapeDtypeStruct(
             (1, 256, 4, 128), jnp.bfloat16,

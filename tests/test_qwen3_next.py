@@ -339,7 +339,7 @@ def test_positions_turn_a_quarter_of_the_head():
     attn = qmodel.Qwen3NextAttention(c)
     assert (attn.d, attn.rot) == (32, 8)
     q = jax.random.normal(jax.random.key(4), (1, 8, 2, 32))
-    turned = qmodel._rope(q, 1e7, 8)
+    turned = qmodel.partial_rope(q, 1e7, 8)
     assert float(jnp.abs(turned[..., 8:] - q[..., 8:]).max()) == 0.0
     assert float(jnp.abs(turned[:, 0] - q[:, 0]).max()) == 0.0   # angle 0
     assert float(jnp.abs(turned[:, 1:, :, :8] - q[:, 1:, :, :8]).max()) > 0.1

@@ -214,7 +214,7 @@ def test_the_qk_mean_under_four_query_heads_a_kv_head():
 
 def test_rope_turns_the_first_half_of_a_head_and_nothing_else():
     x = jax.random.normal(jax.random.key(3), (1, 9, 2, 128))
-    out = zmodel._rope(x, 5e6, 64)
+    out = zmodel.partial_rope(x, 5e6, 64)
     assert float(jnp.abs(out[..., 64:] - x[..., 64:]).max()) == 0.0
     assert float(jnp.abs(out[:, 1:, :, :64] - x[:, 1:, :, :64]).max()) > 0.1
     assert float(jnp.abs(out[:, 0] - x[:, 0]).max()) == 0.0   # angle 0
