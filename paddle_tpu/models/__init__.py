@@ -13,7 +13,10 @@ SwiGLU, muP scalings, a held share of layers and vocabulary, training path
 only; granite_hybrid: Mamba-2 state-space layers and position-free
 grouped-query attention mixed by a published list, dense SwiGLU, Granite's
 four multipliers, a tied head, a held share of layers and vocabulary,
-training path only; vision models live in paddle_tpu.vision
+training path only; smallthinker: a softmax top-k router that reads the
+block's input BEFORE attention, ReGLU experts with no shared one, window
+(RoPE) and position-free full attention mixed by two published lists, a
+held share, training path only; vision models live in paddle_tpu.vision
 (config #1).
 """
 from .llama import (  # noqa: F401
@@ -58,6 +61,12 @@ from .granite_hybrid import (  # noqa: F401
     GraniteHybridDecoderLayer,
     GraniteHybridForCausalLM,
     GraniteHybridModel,
+)
+from .smallthinker import (  # noqa: F401
+    SmallThinkerConfig,
+    SmallThinkerDecoderLayer,
+    SmallThinkerForCausalLM,
+    SmallThinkerModel,
 )
 from .unet import UNet2DConditionModel, UNetConfig  # noqa: F401
 from .generation import generate  # noqa: F401

@@ -1,6 +1,6 @@
 """What the training-path decoders share — ``zaya.py``, ``afmoe.py``,
-``qwen3_next.py``, ``minicpm_sala.py``, ``granite_hybrid.py`` — in ONE
-place: no decoder file imports another decoder's file, every one imports
+``qwen3_next.py``, ``minicpm_sala.py``, ``granite_hybrid.py``,
+``smallthinker.py`` — in ONE place: no decoder file imports another decoder's file, every one imports
 this (``tests/test_layering.py``). A new family writes its mixers, its
 block and its config, and DECLARES the rest:
 

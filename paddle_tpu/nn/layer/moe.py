@@ -1,12 +1,15 @@
 """Routed experts a model can hold without ``paddle_tpu.distributed``.
 
-``RoutedExperts``: E gated (SwiGLU) experts stacked ``[E, ...]``, run
-DROPLESS — every (token, choice) pair is computed, whatever the routing:
-the tokens are sorted by expert (stable), the sizes of the groups are a
-``bincount``, both expert matmuls are grouped matmuls over the sorted
-rows (``ops.grouped_matmul``: no capacity, no ``[E, C, H]`` padding
-buffer, no dispatch mode), and the inverse permutation puts the rows
-back. The layer takes the router's choice — expert ids and gate values
+``RoutedExperts``: E gated experts stacked ``[E, ...]``, their
+activation a value the family DECLARES (``activation``: a name of
+``ACTIVATIONS`` — SwiGLU the default, ReGLU — or a function from the
+gate-up product and the width), run DROPLESS — every (token, choice)
+pair is computed, whatever the routing: the tokens are sorted by expert
+(stable), the sizes of the groups are a ``bincount``, both expert
+matmuls are grouped matmuls over the sorted rows
+(``ops.grouped_matmul``: no capacity, no ``[E, C, H]`` padding buffer,
+no dispatch mode), and the inverse permutation puts the rows back. The
+layer takes the router's choice — expert ids and gate values
 ``[T, k]`` — and returns the combined output ``[T, H]``. Told that it
 holds a SHARE of the experts the router chooses among (``held`` of
 ``num_experts`` from ``first`` on: one chip's part of an expert-parallel
@@ -26,7 +29,7 @@ pairs where 8 of 256 experts are held, 42,240 (2.06) for 163,840 where
 as hold its pairs (one, unless a routing sends it more than its bound;
 then two, three, ...: no pair is dropped at any routing): a
 window gathers its rows straight from ``x``, runs the grouped matmuls and
-SwiGLU over them and adds each row times its pair's gate into its
+the activation over them and adds each row times its pair's gate into its
 token's row in float32. The trip count is data, so the loop is the
 share's own custom VJP: the backward walks the same windows, runs each
 one's forward again and adds up the gradients; a step holds no value of
@@ -62,7 +65,8 @@ from ...base.tensor import Tensor
 from .. import initializer as I
 from .layers import Layer
 
-__all__ = ["RoutedExperts", "MLPRouter", "SigmoidTopKRouter"]
+__all__ = ["RoutedExperts", "MLPRouter", "SigmoidTopKRouter",
+           "SoftmaxTopKRouter"]
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -150,7 +154,19 @@ def _swiglu(gu, f: int):
             * gu[:, f:].astype(jnp.float32)).astype(gu.dtype)
 
 
-def _every_pair(x, w_gu, w_dn, gates, order, inverse, sizes, share: bool):
+def _reglu(gu, f: int):
+    return (jax.nn.relu(gu[:, :f].astype(jnp.float32))
+            * gu[:, f:].astype(jnp.float32)).astype(gu.dtype)
+
+
+# gu [rows, 2F] (gate | up), F -> the hidden rows [rows, F]. Whatever is
+# declared maps a zero row to a zero row: the rows a share's kernels left
+# unwritten are read as zero before it and must stay zero after it.
+ACTIVATIONS = {"swiglu": _swiglu, "reglu": _reglu}
+
+
+def _every_pair(x, w_gu, w_dn, gates, order, inverse, sizes, share: bool,
+                act):
     """A row for every (token, choice) pair, whatever the routing."""
     from ...ops.grouped_matmul import grouped_matmul
 
@@ -170,7 +186,7 @@ def _every_pair(x, w_gu, w_dn, gates, order, inverse, sizes, share: bool):
         result = lambda a: a
     with jax.named_scope("moe.experts"):
         gu = result(grouped_matmul(rows, w_gu, sizes))
-        y = result(grouped_matmul(_swiglu(gu, w_dn.shape[1]), w_dn, sizes))
+        y = result(grouped_matmul(act(gu, w_dn.shape[1]), w_dn, sizes))
     with jax.named_scope("moe.combine"):
         y = _permute_rows(y, inverse, order).reshape(t, k, -1)
         out = jnp.sum(y.astype(jnp.float32)
@@ -188,7 +204,7 @@ def _window(order, sizes, cap: int, i):
     return jax.lax.dynamic_slice(order, (lo,), (cap,)), inside, jnp.sum(inside)
 
 
-def _window_rows(rows, w_gu, w_dn, gates, pairs, sizes, total):
+def _window_rows(rows, w_gu, w_dn, gates, pairs, sizes, total, act):
     """A window's rows (the tokens' own, gathered straight from ``x``) to
     what each adds to its token's output row [cap, H] float32: the two
     grouped matmuls with their tails (a select costs a pass over the rows,
@@ -199,7 +215,7 @@ def _window_rows(rows, w_gu, w_dn, gates, pairs, sizes, total):
     with jax.named_scope("moe.experts"):
         gu = _result_tail(grouped_matmul(rows, w_gu, sizes), total)
         y = _result_tail(grouped_matmul(
-            _swiglu(gu, w_dn.shape[1]), w_dn, sizes), total)
+            act(gu, w_dn.shape[1]), w_dn, sizes), total)
     with jax.named_scope("moe.combine"):
         of_row = gates.reshape(-1)[pairs].astype(jnp.float32)
         return y.astype(jnp.float32) * of_row[:, None]
@@ -214,8 +230,8 @@ def _windows(cap: int, order, sizes):
 
 # Jitted, as the flash kernels' launchers are, so that a model's layers
 # share ONE trace and ONE lowering of the two loops.
-@functools.partial(jax.jit, static_argnums=0)
-def _share_forward(cap, x, w_gu, w_dn, gates, order, sizes):
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _share_forward(cap, act, x, w_gu, w_dn, gates, order, sizes):
     k = gates.shape[1]
     count, order = _windows(cap, order, sizes)
 
@@ -223,7 +239,7 @@ def _share_forward(cap, x, w_gu, w_dn, gates, order, sizes):
         pairs, inside, total = _window(order, sizes, cap, i)
         with jax.named_scope("moe.permute"):
             rows = x[pairs // k]
-        h = _window_rows(rows, w_gu, w_dn, gates, pairs, inside, total)
+        h = _window_rows(rows, w_gu, w_dn, gates, pairs, inside, total, act)
         with jax.named_scope("moe.combine"):
             return out.at[pairs // k].add(h)
 
@@ -232,8 +248,8 @@ def _share_forward(cap, x, w_gu, w_dn, gates, order, sizes):
     return out.astype(x.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _share_backward(cap, x, w_gu, w_dn, gates, order, sizes, g):
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _share_backward(cap, act, x, w_gu, w_dn, gates, order, sizes, g):
     k = gates.shape[1]
     count, order = _windows(cap, order, sizes)
 
@@ -242,7 +258,7 @@ def _share_backward(cap, x, w_gu, w_dn, gates, order, sizes, g):
         with jax.named_scope("moe.permute"):
             rows = x[pairs // k]
         _, pull = jax.vjp(                     # the window's forward again
-            lambda *a: _window_rows(*a, pairs, inside, total),
+            lambda *a: _window_rows(*a, pairs, inside, total, act),
             rows, w_gu, w_dn, gates)
         with jax.named_scope("moe.combine"):
             d, *new = pull(g[pairs // k].astype(jnp.float32))
@@ -260,26 +276,29 @@ def _share_backward(cap, x, w_gu, w_dn, gates, order, sizes, g):
     return (dx.astype(x.dtype), *rest)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _bounded_share(cap, x, w_gu, w_dn, gates, order, sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _bounded_share(cap, act, x, w_gu, w_dn, gates, order, sizes):
     """A share's output, window by window of ``cap`` pairs of the sorted
     order for as many windows as hold the held experts' pairs — one,
     unless a routing sends the share more than its bound. The trip count
     is on the device, so the loop has a backward of its own: the same
     windows again, each running its forward once more (the residuals are
     the inputs) and adding its part of every gradient."""
-    return _share_forward(cap, x, w_gu, w_dn, gates, order, sizes)
+    return _share_forward(cap, act, x, w_gu, w_dn, gates, order, sizes)
 
 
 _bounded_share.defvjp(
-    lambda cap, *args: (_share_forward(cap, *args), args),
-    lambda cap, args, g: (*_share_backward(cap, *args, g), None, None))
+    lambda cap, act, *args: (_share_forward(cap, act, *args), args),
+    lambda cap, act, args, g: (
+        *_share_backward(cap, act, *args, g), None, None))
 
 
-def routed_experts(x, w_gu, w_dn, ids, gates, num_experts: int, first: int):
+def routed_experts(x, w_gu, w_dn, ids, gates, num_experts: int, first: int,
+                   act=_swiglu):
     """x [T, H], w_gu [E, H, 2F] (gate | up), w_dn [E, F, H], ids / gates
     [T, k] -> (out [T, H], tokens per expert [E] int32). ``ids`` run over
-    ``num_experts``; the E held ones are ``first .. first + E``."""
+    ``num_experts``; the E held ones are ``first .. first + E``; ``act``
+    is the experts' activation (``ACTIVATIONS``)."""
     t, k = ids.shape
     held = w_gu.shape[0]
     share = held < num_experts
@@ -296,9 +315,10 @@ def routed_experts(x, w_gu, w_dn, ids, gates, num_experts: int, first: int):
         sizes = jnp.bincount(flat, length=held + 1 if share else held)
         sizes = (sizes[:held] if share else sizes).astype(jnp.int32)
     if cap is None:
-        out = _every_pair(x, w_gu, w_dn, gates, order, inverse, sizes, share)
+        out = _every_pair(x, w_gu, w_dn, gates, order, inverse, sizes, share,
+                          act)
     else:
-        out = _bounded_share(cap, x, w_gu, w_dn, gates, order, sizes)
+        out = _bounded_share(cap, act, x, w_gu, w_dn, gates, order, sizes)
     return out, sizes
 
 
@@ -306,23 +326,32 @@ class RoutedExperts(Layer):
     """Stacked gated experts, dropless. ``forward(x [.., H], ids [.., k],
     gates [.., k])`` -> [.., H]. ``held`` of the ``num_experts`` the ids
     run over live here, from ``first`` on (default: all of them).
+    ``activation``: a name of ``ACTIVATIONS`` or a function ``(gu [rows,
+    2F], F) -> [rows, F]`` that keeps a zero row zero.
     ``tokens_per_expert`` (int32 [held], a buffer on the device) adds up
     how many rows each held expert was given, call by call; a layer that
     holds a share also adds up ``pairs_routed``, every (token, choice)
     pair it saw (its rows no longer add up to them), and
     ``calls_in_full``, the calls in which the held experts' pairs passed
     ``row_bound`` and took more than one window of rows (all of its calls
-    where the shapes allow no bound: a row for every pair). Nothing reads
-    them back but whoever asks (``numpy()``)."""
+    where the shapes allow no bound: a row for every pair), and keeps
+    ``rows_a_window``, the rows ONE pass of its last traced call ran over
+    (the permute, both grouped matmuls' operands, the activation, the
+    combine: ``row_bound``'s window, or a row for every pair) — a Python
+    int, known when the call is traced, no op on the device. Nothing
+    reads them back but whoever asks (``numpy()``)."""
 
     def __init__(self, hidden_size: int, intermediate_size: int,
-                 num_experts: int, held: int = None, first: int = 0):
+                 num_experts: int, held: int = None, first: int = 0,
+                 activation="swiglu"):
         super().__init__()
         held = num_experts if held is None else held
         if not 0 <= first <= first + held <= num_experts:
             raise ValueError(f"experts {first}..{first + held} of "
                              f"{num_experts}")
         self.num_experts, self.first = num_experts, first
+        self.activation = (activation if callable(activation)
+                           else ACTIVATIONS[activation])
         init = I.Normal(0.0, 0.02)
         self.w_gu = self.create_parameter(
             [held, hidden_size, 2 * intermediate_size],
@@ -333,6 +362,7 @@ class RoutedExperts(Layer):
         self.register_buffer("tokens_per_expert", Tensor(
             jnp.zeros([held], jnp.int32), _internal=True))
         if held < num_experts:
+            self.rows_a_window = None
             for name in ("pairs_routed", "calls_in_full"):
                 self.register_buffer(name, Tensor(
                     jnp.zeros([], jnp.int32), _internal=True))
@@ -347,7 +377,8 @@ class RoutedExperts(Layer):
         def run(x, w_gu, w_dn, ids, gates):
             out, sizes = routed_experts(
                 x.reshape(-1, h), w_gu, w_dn, ids.reshape(-1, k),
-                gates.reshape(-1, k), self.num_experts, self.first)
+                gates.reshape(-1, k), self.num_experts, self.first,
+                self.activation)
             return out.reshape(*lead, h), sizes
 
         return apply(run, x, self.w_gu, self.w_dn, ids, gates,
@@ -359,6 +390,7 @@ class RoutedExperts(Layer):
         if "pairs_routed" in self._buffers:
             self.pairs_routed.set_value(self.pairs_routed._data + pairs)
             cap = row_bound(pairs, sizes.shape[0], self.num_experts)
+            self.rows_a_window = pairs if cap is None else cap
             in_full = 1 if cap is None else jnp.sum(sizes._data) > cap
             self.calls_in_full.set_value(self.calls_in_full._data + in_full)
 
@@ -488,6 +520,3 @@ class SoftmaxTopKRouter(Layer):
             lambda m, w: softmax_topk_router(
                 m, w, top_k=self.top_k, norm=self.norm),
             m, self.weight, op_name="softmax_topk_router")
-
-
-__all__ += ["SoftmaxTopKRouter"]

@@ -215,6 +215,20 @@ _OUTDATED = {
     "test_the_cells_before_it_are_as_their_prs_left_them":
         "PR 40 appended its cell to the two .gqa flash lists after PR 34's "
         "(PERF.md section 7)",
+    # and four PRs on: PR 40's test takes ITS entries out and then runs
+    # PR 38's bodies down the same chain; issue 44 has a cell appended to
+    # seven lists that hold PR 32's or PR 34's cell (the two .window and
+    # the two .gqa flash lists, moe_gmm_roofline.held, moe_held_rows_ratio,
+    # moe_expert_load_peak) and to train_tokens_per_s's.
+    # tests/chipbench/test_chipbench_smallthinker.py::
+    # test_the_cells_before_it_are_as_their_prs_left_them calls its body
+    # and PR 40's own ``gains`` on the benchmark without PR 44's entries;
+    # that file's own tests are written by membership, so the next PR
+    # need not mark it.
+    "test_chipbench_granite.py::"
+    "test_the_cells_before_it_are_as_their_prs_left_them":
+        "PR 44 appended its cell to seven per-layer lists after PR 32's and "
+        "PR 34's (PERF.md section 7)",
 }
 
 

@@ -84,12 +84,19 @@ SHAPES = {
     # train-granite4h-10l-16k's one attention layer: GQA 32/8 at d 64 (half
     # the MXU's depth a contraction) and 16,384 keys, the loop
     "cell-granite4h-full": (16384, 16384, 32, 8, 64, True, jnp.bfloat16),
+    # train-smallthinker-4l-16k's one full layer: GQA 28/4 (seven query
+    # heads a kv head: the backward repeats k and v seven-fold) at 16,384
+    "cell-smallthinker-full": (16384, 16384, 28, 4, 128, True, jnp.bfloat16),
 }
 
 # (sq, sk, q heads, kv heads, d_head, window, dtype): a sliding window
 WINDOWS = {
     # train-trinity-5l-8k's four window layers: 4096 of 8192, the loop
     "cell-trinity-window": (8192, 8192, 48, 8, 128, 4096, jnp.bfloat16),
+    # train-smallthinker-4l-16k's three window layers: 4096 of 16,384 at
+    # GQA 28/4, the loop
+    "cell-smallthinker-window": (16384, 16384, 28, 4, 128, 4096,
+                                 jnp.bfloat16),
     # held whole: the window's edge settled when the kernel is traced
     "whole-2k": (2048, 2048, 4, 2, 128, 600, jnp.bfloat16),
     "float32-loop": (4096, 4096, 2, 2, 128, 1000, jnp.float32),
@@ -162,6 +169,11 @@ GROUPED = {
     # past the bound runs the same shapes once more
     "cell-qwen3next-gate-up-window": (42240, 2048, 1024, 64, jnp.bfloat16),
     "cell-qwen3next-down-window": (42240, 512, 2048, 64, jnp.bfloat16),
+    # train-smallthinker-4l-16k: 16 held experts of width 768 on a row for
+    # every one of 98,304 (token, choice) pairs (a quarter share has no
+    # bound), ~1,536 live a group
+    "cell-smallthinker-gate-up": (98304, 2560, 1536, 16, jnp.bfloat16),
+    "cell-smallthinker-down": (98304, 768, 2560, 16, jnp.bfloat16),
 }
 
 
@@ -431,3 +443,17 @@ def test_the_selection_compiles_for_a_v5e_without_its_scores_in_hbm(one_chip):
     compiled = jax.jit(lambda q, k: select_blocks(q, k)).lower(q, k).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
     assert not re.search(rf"\[(\d+,)*{s},(\d+,)*1023\]", compiled.as_text())
+
+
+def test_the_expected_change_keeps_its_rounding_on_a_v5e(one_chip):
+    """``families/smallthinker.py::_rounded_change_norms`` at the cell's
+    embedding: the first write's stored value is a ``reduce-precision`` in
+    the program the TPU's compiler leaves (a pair of converts it elides
+    inside the fusion, which read ``embed/wte`` 16% off on the chip)."""
+    from chipbench.families import smallthinker
+
+    leaf = {"wte": jax.ShapeDtypeStruct((37984, 2560), jnp.float32,
+                                        sharding=one_chip)}
+    text = smallthinker._rounded_change_norms.lower(
+        leaf, leaf, leaf).compile().as_text()
+    assert "reduce-precision(" in text
